@@ -78,6 +78,8 @@ def run():
     driver = textwrap.dedent("""
         import os, json, time, sys
         P = int(sys.argv[1])
+        # forced host devices: stay off any chip the parent holds
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             "--xla_force_host_platform_device_count=%d" % max(2*P, 2))
         import jax

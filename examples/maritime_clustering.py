@@ -8,6 +8,8 @@ the same program the dry-run lowers for the production pod.
     PYTHONPATH=src python examples/maritime_clustering.py
 """
 import os
+# the mesh is 8 forced host devices, on the CPU even where a chip exists
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 

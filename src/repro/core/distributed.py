@@ -61,7 +61,6 @@ from repro.core.voting import normalized_voting
 from repro.core.types import (ClusteringResult, DSCParams, JoinResult,
                               SubtrajTable, TopKSim)
 from repro.core.windows import pack_bits
-from repro.utils.compat import shard_map as shard_map_compat
 from repro.utils.tree import pytree_dataclass
 
 # stage-state donation is best-effort: when a stage's outputs can't alias
@@ -314,7 +313,8 @@ class _DSCProgramBuilder:
                 ref_ids.astype(jnp.int32), pv.reshape(-1),
                 kx, ky, kt, cid, kv,
                 params.eps_sp, params.eps_t,
-                bp=_pick_block(T * Mp, 256), bc=_pick_block(Tc, 8),
+                bp=_pick_block(T * Mp, 256),
+                bc=Tc if Tc <= 128 else _pick_block(Tc, 128),
                 bm=_pick_block(Mc, 128),
                 interpret=default_interpret())
         from repro.kernels.stjoin.ref import stjoin_ref
@@ -505,6 +505,8 @@ class _DSCProgramBuilder:
         gid_cand = sl(gid_cat)                             # [Tc, 3Mp]
         S_loc = Tc * maxS
         c0s = c0 * maxS
+        # a slot id is row * maxS + sub-slot (S = unsegmented)
+        sub_of = lambda gid: jnp.where(gid < S, gid % maxS, -1)
         if self.mode != "fused":
             idx = jnp.clip(join.best_idx, 0, 3 * Mp - 1)
             dst = jnp.where(
@@ -525,11 +527,10 @@ class _DSCProgramBuilder:
                 # pass 2: re-sweep the halo slab, scatter refined weights
                 # into this rank's column block in-kernel
                 from repro.kernels.stjoin.ops import stjoin_sim_fused_arrays
-                gidc_l = jnp.where(gid_cand < S, gid_cand - c0s, S_loc)
                 return stjoin_sim_fused_arrays(
-                    px, py, pt, pv, traj_id, gid_own,
-                    sl(cx), sl(cy), sl(ct), sl(cv), cid, gidc_l,
-                    S, S_loc, params.eps_sp, params.eps_t, params.delta_t,
+                    px, py, pt, pv, traj_id, sub_of(gid_own),
+                    sl(cx), sl(cy), sl(ct), sl(cv), cid, sub_of(gid_cand),
+                    maxS, params.eps_sp, params.eps_t, params.delta_t,
                     **self.tile_kw)
             dst_l = jnp.where(dst < S, dst - c0s, S_loc)
             raw = jnp.zeros((S + 1, S_loc + 1), jnp.float32)
@@ -638,11 +639,13 @@ class _DSCProgramBuilder:
             if self.mode == "fused":
                 from repro.kernels.stjoin.ops import \
                     stjoin_sim_fused_arrays
-                raw = stjoin_sim_fused_arrays(
-                    px, py, pt, pv, traj_id, gid_own,
-                    sl(cx), sl(cy), sl(ct), sl(cv), cid, gid_cat,
-                    S, S, params.eps_sp, params.eps_t, params.delta_t,
-                    **self.tile_kw)
+                blk = stjoin_sim_fused_arrays(
+                    px, py, pt, pv, traj_id, sub_of(gid_own),
+                    sl(cx), sl(cy), sl(ct), sl(cv), cid, sub_of(gid_cand),
+                    maxS, params.eps_sp, params.eps_t, params.delta_t,
+                    **self.tile_kw)                        # [S, S_loc]
+                raw = lax.dynamic_update_slice_in_dim(
+                    jnp.zeros((S, S), jnp.float32), blk, c0s, axis=1)
             else:
                 raw = jnp.zeros((S + 1, S + 1), jnp.float32)
                 raw = raw.at[src.reshape(-1), dst.reshape(-1)].add(
@@ -837,8 +840,9 @@ def build_dsc_program(
     out_specs = (P(), P(), P(part_axis, None, None),
                  P(part_axis, None), P(part_axis, None))
 
-    return shard_map_compat(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)
 
 
 def refine_stage(member_of, member_sim, is_rep, active, alpha, k):
@@ -908,8 +912,9 @@ def build_dsc_stage_programs(
 
     join_out = ((part2, part3) if plan.mode == "fused" else
                 (part2, part3, part3, part3))
-    join_fn = jax.jit(shard_map_compat(
-        join_body, mesh=mesh, in_specs=pts_specs, out_specs=join_out))
+    join_fn = jax.jit(jax.shard_map(
+        join_body, mesh=mesh, in_specs=pts_specs, out_specs=join_out,
+        check_vma=False))
 
     def segment_body(pt, pv, vote, masks):
         table, labels = b.phase_segment(pt[0], pv[0], vote[0], masks[0])
@@ -918,10 +923,10 @@ def build_dsc_stage_programs(
     # the TSA2 mask cube is dead after segmentation — donating it keeps
     # checkpoint-restored state single-resident (the resilient loop holds
     # host copies, so donation never aliases a checkpoint reference)
-    segment_fn = jax.jit(shard_map_compat(
+    segment_fn = jax.jit(jax.shard_map(
         segment_body, mesh=mesh,
         in_specs=(part2, part2, part2, part3),
-        out_specs=(P(), part2)), donate_argnums=(3,))
+        out_specs=(P(), part2), check_vma=False), donate_argnums=(3,))
 
     def similarity_body(px, py, pt, pv, traj_id, ranges, labels, table,
                         *cube):
@@ -958,8 +963,9 @@ def build_dsc_stage_programs(
     # the join cube (the largest inter-stage buffer) is dead once the
     # similarity stage has re-sliced it — donate both halves
     sim_donate = () if plan.mode == "fused" else (8, 9)
-    similarity_fn = jax.jit(shard_map_compat(
-        similarity_body, mesh=mesh, in_specs=sim_in, out_specs=sim_out),
+    similarity_fn = jax.jit(jax.shard_map(
+        similarity_body, mesh=mesh, in_specs=sim_in, out_specs=sim_out,
+        check_vma=False),
         donate_argnums=sim_donate)
 
     def cluster_body(table, active, *state):
@@ -985,8 +991,9 @@ def build_dsc_stage_programs(
     # the similarity state (dense [P, S, S] matrix or the top-K lists) is
     # dead once clustered — donate all of it
     clu_donate = tuple(range(2, len(clu_in)))
-    cluster_fn = jax.jit(shard_map_compat(
-        cluster_body, mesh=mesh, in_specs=clu_in, out_specs=clu_out),
+    cluster_fn = jax.jit(jax.shard_map(
+        cluster_body, mesh=mesh, in_specs=clu_in, out_specs=clu_out,
+        check_vma=False),
         donate_argnums=clu_donate)
 
     return {"join": join_fn, "segment": segment_fn,

@@ -86,10 +86,18 @@ def _segment_body(batch, params, vote, masks, plan: EnginePlan):
 
 def _similarity_body(batch, params, join, seg, table, plan: EnginePlan,
                      tile_ids=None):
-    """SP relation: returns ``(sim, topk)`` — exactly one is non-None."""
+    """SP relation: returns ``(sim, topk)`` — exactly one is non-None.
+
+    The Pallas pass-2 sweep (``kernels.stjoin``) builds the raw scatter
+    whenever the join runs on the kernels — fused mode, or the kernel
+    join in materialize mode — instead of an XLA scatter of all ``T*M*C``
+    cube entries, which a TPU serializes and compiles for minutes at
+    deployment sizes.  Both accumulate every cell in the same order.
+    """
+    sweep = join is None or plan.use_kernel
     if plan.sim_mode == "topk":
         # sparse SP relation: panel-streamed top-K lists, never [S, S]
-        if join is None:
+        if sweep:
             from repro.kernels.stjoin import ops as stjoin_ops
             Sb = similarity.plan_panel(table.num_slots, plan.sim_panel)
 
@@ -109,7 +117,7 @@ def _similarity_body(batch, params, join, seg, table, plan: EnginePlan,
                 panel=plan.sim_panel)
         return None, topk
 
-    if join is None:
+    if sweep:
         from repro.kernels.stjoin import ops as stjoin_ops
         raw = stjoin_ops.stjoin_sim_fused(
             batch, batch, seg.sub_local, seg.sub_local,
@@ -187,11 +195,12 @@ def _run_dsc_materialize(batch: TrajectoryBatch, params: DSCParams,
     return _finish(batch, params, join, vote, masks, plan)
 
 
-@functools.partial(jax.jit, static_argnames=("plan",))
+@functools.partial(jax.jit, static_argnames=("plan",), donate_argnums=(2,))
 def _run_dsc_from_join(batch: TrajectoryBatch, params: DSCParams,
                        join: JoinResult, plan: EnginePlan) -> DSCOutput:
     """Materializing tail for a join produced outside the jit boundary
-    (the host-planned index-pruned Pallas join)."""
+    (the host-planned index-pruned Pallas join).  The cube is donated:
+    the output hands it back without a second ``[T, M, C]`` copy."""
     vote, masks = _vote_from_join_body(params, join)
     return _finish(batch, params, join, vote, masks, plan)
 

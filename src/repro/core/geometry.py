@@ -104,6 +104,24 @@ def filter_delta_t(join: JoinResult, ref_t: jnp.ndarray,
     )
 
 
+def refine_join(join: JoinResult, ref_t: jnp.ndarray,
+                delta_t) -> JoinResult:
+    """``filter_delta_t`` when ``delta_t > 0``, else the join unchanged.
+
+    A concrete ``delta_t`` decides in Python: a ``lax.cond`` would compile
+    (and hold memory for) the filter branch even at ``delta_t == 0``, and
+    on a ``[T, M, C]`` cube that branch alone can exceed a chip's HBM.
+    """
+    if isinstance(delta_t, jax.core.Tracer):
+        dt = jnp.asarray(delta_t, jnp.float32)
+        return jax.lax.cond(dt > 0.0,
+                            lambda jj: filter_delta_t(jj, ref_t, dt),
+                            lambda jj: jj, join)
+    if delta_t > 0:
+        return filter_delta_t(join, ref_t, jnp.float32(delta_t))
+    return join
+
+
 def subtrajectory_join(ref: TrajectoryBatch, cand: TrajectoryBatch,
                        eps_sp, eps_t, delta_t=0.0, *,
                        use_index: bool = False) -> JoinResult:
@@ -120,6 +138,4 @@ def subtrajectory_join(ref: TrajectoryBatch, cand: TrajectoryBatch,
             ref.x, ref.y, ref.t, ref.valid,
             cand.x, cand.y, cand.t, cand.valid, eps_sp, eps_t)
     j = best_match_join(ref, cand, eps_sp, eps_t, prune_mask=prune_mask)
-    dt = jnp.asarray(delta_t, jnp.float32)
-    return jax.lax.cond(
-        dt > 0.0, lambda jj: filter_delta_t(jj, ref.t, dt), lambda jj: jj, j)
+    return refine_join(j, ref.t, delta_t)

@@ -67,10 +67,10 @@ class EnginePlan:
 
     # ---- join (Problem 1) -------------------------------------------------
     mode: str = "materialize"          # "materialize" | "fused"
-    use_kernel: bool = False           # Pallas join kernel (materialize mode)
+    use_kernel: bool = False           # Pallas join + SP sweep (materialize)
     use_index: bool = False            # grid candidate-tile pruning
     fused_rows: int | None = None      # fused ref-block rows (None = auto)
-    fused_bc: int = 16                 # fused candidate rows per block
+    fused_bc: int = 128                # fused candidate rows per block
     fused_bm: int = 128                # fused candidate point chunk
     # ---- segmentation (Problem 2) ----------------------------------------
     seg_use_kernel: bool = False       # Pallas TSA2 Jaccard kernel
@@ -133,7 +133,7 @@ class EnginePlan:
         overrides, which keeps jit cache keys (and therefore traces)
         identical to the pre-plan flag surface."""
         t = (self.fused_rows, self.fused_bc, self.fused_bm)
-        return None if t == (None, 16, 128) else t
+        return None if t == (None, 128, 128) else t
 
     @property
     def cluster_tiles(self) -> tuple[int, int]:
@@ -196,7 +196,7 @@ class EnginePlan:
         identical to the pre-plan entry points (pinned by
         ``tests/test_plan.py``).
         """
-        rows, bc, bm = (None, 16, 128) if fused_tiles is None else fused_tiles
+        rows, bc, bm = (None, 128, 128) if fused_tiles is None else fused_tiles
         return cls(mode=mode, use_kernel=use_kernel, use_index=use_index,
                    fused_rows=rows, fused_bc=bc, fused_bm=bm,
                    seg_use_kernel=seg_use_kernel,

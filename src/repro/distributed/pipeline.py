@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map as shard_map_compat
 
 
 def pipeline_apply(stage_fn: Callable, mesh, *, stage_axis: str = "pod",
@@ -90,11 +89,11 @@ def pipeline_apply(stage_fn: Callable, mesh, *, stage_axis: str = "pod",
     out_specs = P(dspec)
 
     def wrapped(stage_params, x):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: in_specs[0], stage_params),
                       in_specs[1]),
-            out_specs=out_specs)
+            out_specs=out_specs, check_vma=False)
         return fn(stage_params, x)
 
     return wrapped

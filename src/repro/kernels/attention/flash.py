@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 NEG = -1e30
 
 
@@ -46,12 +48,9 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, meta_ref, o_ref, *,
 
     def body(i, carry):
         m, l, acc = carry
-        # jnp scalar (not python int) index: pallas' dynamic-index check
-        # requires every non-slice index to carry a shape
-        zero = jnp.int32(0)
-        k = pl.load(k_ref, (zero, pl.ds(i * bk, bk), slice(None)))  # [bk, hd]
-        v = pl.load(v_ref, (zero, pl.ds(i * bk, bk), slice(None)))
-        kpos = pl.load(kpos_ref, (pl.ds(i * bk, bk),))
+        k = k_ref[0, pl.ds(i * bk, bk), :]                      # [bk, hd]
+        v = v_ref[0, pl.ds(i * bk, bk), :]
+        kpos = kpos_ref[pl.ds(i * bk, bk)]
 
         s = jnp.einsum("qgh,kh->qgk", q, k)                    # [bq, G, bk]
         if softcap is not None:
@@ -83,10 +82,12 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, meta_ref, o_ref, *,
 def flash_attention_fwd(q, k, v, q_positions, kv_positions, window, prefix,
                         max_kv, *, bq: int = 256, bk: int = 512,
                         softcap: float | None = None,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q: [B, Lq, KV, G, hd]; k/v: [B, M, KV, hd].  Returns [B, Lq, KV, G,
     hd].  Positions are int32 vectors; window/prefix/max_kv int32 scalars
     (use huge values to disable)."""
+    if interpret is None:
+        interpret = default_interpret()
     B, Lq, KV, G, hd = q.shape
     M = k.shape[1]
     bq = min(bq, Lq)

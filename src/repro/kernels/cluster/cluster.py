@@ -34,8 +34,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 _BIG_RANK = 2**31 - 1          # python int: kernels may not capture arrays
+
+# Layout: row-side state is a [S, 1] column (block (bu, 1)), column-side
+# state and every per-slot output a [1, S] row (block (1, bs)); flags are
+# int32 0/1.  Mosaic then needs bu % 8 == 0 and bs % 128 == 0 (or the
+# whole padded axis); the threshold rides in SMEM.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _round_scan_kernel(sim, rank_r, rank_c, unresolved, is_rep, thr,
@@ -49,10 +58,11 @@ def _round_scan_kernel(sim, rank_r, rank_c, unresolved, is_rep, thr,
 
     alpha = thr[0]
     s = sim[...]                                   # [bu, bs]
-    pred = ((s > 0.0) & (s >= alpha)
-            & (rank_r[...][:, None] < rank_c[...][None, :]))
-    out_blocked[...] |= jnp.any(pred & unresolved[...][:, None], axis=0)
-    out_claimed[...] |= jnp.any(pred & is_rep[...][:, None], axis=0)
+    pred = (s > 0.0) & (s >= alpha) & (rank_r[...] < rank_c[...])
+    hit = lambda flag: jnp.max(jnp.where(pred & (flag[...] != 0), 1, 0),
+                               axis=0, keepdims=True)
+    out_blocked[...] = jnp.maximum(out_blocked[...], hit(unresolved))
+    out_claimed[...] = jnp.maximum(out_claimed[...], hit(is_rep))
 
 
 def _assign_kernel(sim, rank_r, is_rep, valid_c, thr,
@@ -67,14 +77,17 @@ def _assign_kernel(sim, rank_r, is_rep, valid_c, thr,
 
     alpha = thr[0]
     s = sim[...]                                   # [bu, bs]
-    claim = (is_rep[...][:, None] & valid_c[...][None, :]
+    claim = ((is_rep[...] != 0) & (valid_c[...] != 0)
              & (s > 0.0) & (s >= alpha))
     w = jnp.where(claim, s, 0.0)
-    loc_w = jnp.max(w, axis=0)                     # [bs]
-    cand = claim & (w == loc_w[None, :]) & (loc_w[None, :] > 0.0)
-    r = jnp.where(cand, rank_r[...][:, None], _BIG_RANK)
-    loc_rank = jnp.min(r, axis=0)
-    loc_slot = i * bu + jnp.argmin(r, axis=0).astype(jnp.int32)
+    loc_w = jnp.max(w, axis=0, keepdims=True)      # [1, bs]
+    cand = claim & (w == loc_w) & (loc_w > 0.0)
+    r = jnp.where(cand, rank_r[...], _BIG_RANK)
+    loc_rank = jnp.min(r, axis=0, keepdims=True)
+    # ranks are distinct, so the min-rank row is unique
+    row = i * bu + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    loc_slot = jnp.min(jnp.where(cand & (r == loc_rank), row, _BIG_RANK),
+                       axis=0, keepdims=True)
 
     acc_w = out_w[...]
     acc_rank = out_rank[...]
@@ -88,54 +101,67 @@ def _assign_kernel(sim, rank_r, is_rep, valid_c, thr,
 
 def _specs(bu: int, bs: int):
     sim_spec = pl.BlockSpec((bu, bs), lambda j, i: (i, j))
-    row_spec = pl.BlockSpec((bu,), lambda j, i: (i,))
-    col_spec = pl.BlockSpec((bs,), lambda j, i: (j,))
-    thr_spec = pl.BlockSpec((1,), lambda j, i: (0,))
-    out_spec = pl.BlockSpec((bs,), lambda j, i: (j,))
-    return sim_spec, row_spec, col_spec, thr_spec, out_spec
+    row_spec = pl.BlockSpec((bu, 1), lambda j, i: (i, 0))
+    col_spec = pl.BlockSpec((1, bs), lambda j, i: (0, j))
+    return sim_spec, row_spec, col_spec
+
+
+def _row(v):
+    return v.astype(jnp.int32).reshape(1, -1)
+
+
+def _col(v):
+    return v.astype(jnp.int32).reshape(-1, 1)
+
+
+def _thr(alpha):
+    return jnp.asarray(alpha, jnp.float32).reshape(1)
 
 
 @functools.partial(jax.jit, static_argnames=("bu", "bs", "interpret"))
 def round_scan_pallas(sim, rank, unresolved, is_rep, alpha, *,
-                      bu: int = 8, bs: int = 128, interpret: bool = True):
+                      bu: int = 8, bs: int = 128,
+                      interpret: bool | None = None):
     """(blocked [S], claimed [S]) for one round; S divisible by bu and bs."""
+    if interpret is None:
+        interpret = default_interpret()
     S = sim.shape[0]
     assert sim.shape == (S, S) and S % bu == 0 and S % bs == 0, \
         (sim.shape, bu, bs)
-    thr = jnp.asarray(alpha, jnp.float32).reshape(1)
-    sim_spec, row_spec, col_spec, thr_spec, out_spec = _specs(bu, bs)
-    return pl.pallas_call(
+    sim_spec, row_spec, col_spec = _specs(bu, bs)
+    blocked, claimed = pl.pallas_call(
         _round_scan_kernel,
         grid=(S // bs, S // bu),
-        in_specs=[sim_spec, row_spec, col_spec, row_spec, row_spec,
-                  thr_spec],
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((S,), jnp.bool_)] * 2,
+        in_specs=[sim_spec, row_spec, col_spec, row_spec, row_spec, _SMEM],
+        out_specs=[col_spec, col_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, S), jnp.int32)] * 2,
         interpret=interpret,
-    )(sim, rank.astype(jnp.int32), rank.astype(jnp.int32),
-      unresolved.astype(jnp.bool_), is_rep.astype(jnp.bool_), thr)
+    )(sim, _col(rank), _row(rank), _col(unresolved), _col(is_rep),
+      _thr(alpha))
+    return blocked[0] != 0, claimed[0] != 0
 
 
 @functools.partial(jax.jit, static_argnames=("bu", "bs", "interpret"))
 def assign_pallas(sim, rank, is_rep, valid, alpha, *,
-                  bu: int = 8, bs: int = 128, interpret: bool = True):
+                  bu: int = 8, bs: int = 128, interpret: bool | None = None):
     """(best_w [S], best_slot [S]) claim-max over representative rows."""
+    if interpret is None:
+        interpret = default_interpret()
     S = sim.shape[0]
     assert sim.shape == (S, S) and S % bu == 0 and S % bs == 0, \
         (sim.shape, bu, bs)
-    thr = jnp.asarray(alpha, jnp.float32).reshape(1)
-    sim_spec, row_spec, col_spec, thr_spec, out_spec = _specs(bu, bs)
+    sim_spec, row_spec, col_spec = _specs(bu, bs)
     w, _, slot = pl.pallas_call(
         functools.partial(_assign_kernel, bu=bu),
         grid=(S // bs, S // bu),
-        in_specs=[sim_spec, row_spec, row_spec, col_spec, thr_spec],
-        out_specs=[out_spec, out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((S,), jnp.float32),
-                   jax.ShapeDtypeStruct((S,), jnp.int32),
-                   jax.ShapeDtypeStruct((S,), jnp.int32)],
+        in_specs=[sim_spec, row_spec, row_spec, col_spec, _SMEM],
+        out_specs=[col_spec, col_spec, col_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, S), jnp.float32),
+                   jax.ShapeDtypeStruct((1, S), jnp.int32),
+                   jax.ShapeDtypeStruct((1, S), jnp.int32)],
         interpret=interpret,
-    )(sim, rank.astype(jnp.int32), is_rep.astype(jnp.bool_),
-      valid.astype(jnp.bool_), thr)
+    )(sim, _col(rank), _col(is_rep), _row(valid), _thr(alpha))
+    w, slot = w[0], slot[0]
     return w, jnp.where(w > 0.0, slot, -1)
 
 
@@ -143,92 +169,93 @@ def assign_pallas(sim, rank, is_rep, valid, alpha, *,
 # Neighbor-list (top-K) kernels: the same two reductions on the sparse
 # ``TopKSim`` rows.  The [S, S] matrix sweep becomes a [S, K] list sweep —
 # one row-tile grid, no contraction axis (a slot's whole adjacency fits its
-# K-entry list row), with the rank/state vectors resident per instance and
-# read through an in-tile gather.  O(S*K) HBM traffic per round.
+# K-entry list row).  The wrappers gather each neighbor's rank and round
+# state into [S, K] operands with one XLA gather per round, so the kernels
+# are pure row reductions.  O(S*K) HBM traffic per round.
 # ---------------------------------------------------------------------------
 
 
-def _topk_round_scan_kernel(ids, sims, rank_rows, rank_full, unresolved,
-                            is_rep, thr, out_blocked, out_claimed):
+def _topk_round_scan_kernel(ids, sims, nbr_rank, nbr_unres, nbr_rep,
+                            rank_rows, thr, out_blocked, out_claimed):
     alpha = thr[0]
-    uid = ids[...]                                 # [bs, K]
-    v = sims[...]
-    rk = rank_full[...]                            # [Sp]
-    S = rk.shape[0]
-    safe = jnp.clip(uid, 0, S - 1)
-    edge = (uid >= 0) & (v > 0.0) & (v >= alpha)
-    pred = edge & (rk[safe] < rank_rows[...][:, None])
-    out_blocked[...] = jnp.any(pred & unresolved[...][safe], axis=1)
-    out_claimed[...] = jnp.any(pred & is_rep[...][safe], axis=1)
+    v = sims[...]                                  # [bs, K]
+    pred = ((ids[...] >= 0) & (v > 0.0) & (v >= alpha)
+            & (nbr_rank[...] < rank_rows[...]))
+    hit = lambda flag: jnp.max(jnp.where(pred & (flag[...] != 0), 1, 0),
+                               axis=1, keepdims=True)
+    out_blocked[...] = hit(nbr_unres)
+    out_claimed[...] = hit(nbr_rep)
 
 
-def _topk_assign_kernel(ids, sims, rank_full, is_rep, valid_rows, thr,
+def _topk_assign_kernel(ids, sims, nbr_rank, nbr_rep, valid_rows, thr,
                         out_w, out_slot):
     alpha = thr[0]
     uid = ids[...]                                 # [bs, K]
     v = sims[...]
-    rk = rank_full[...]
-    S = rk.shape[0]
-    bs = uid.shape[0]
-    safe = jnp.clip(uid, 0, S - 1)
-    claim = ((uid >= 0) & valid_rows[...][:, None] & (v > 0.0)
-             & (v >= alpha) & is_rep[...][safe])
+    claim = ((uid >= 0) & (valid_rows[...] != 0) & (v > 0.0)
+             & (v >= alpha) & (nbr_rep[...] != 0))
     w = jnp.where(claim, v, 0.0)
-    best_w = jnp.max(w, axis=1)                    # [bs]
-    cand = claim & (w == best_w[:, None]) & (best_w[:, None] > 0.0)
-    r = jnp.where(cand, rk[safe], _BIG_RANK)
-    e = jnp.argmin(r, axis=1).astype(jnp.int32)
-    slot = safe[jnp.arange(bs), e]
+    best_w = jnp.max(w, axis=1, keepdims=True)     # [bs, 1]
+    cand = claim & (w == best_w) & (best_w > 0.0)
+    r = jnp.where(cand, nbr_rank[...], _BIG_RANK)
+    best_r = jnp.min(r, axis=1, keepdims=True)
+    # ranks are distinct per slot, so the min-rank neighbor is unique
+    slot = jnp.min(jnp.where(cand & (r == best_r), uid, _BIG_RANK), axis=1,
+                   keepdims=True)
     out_w[...] = best_w
     out_slot[...] = jnp.where(best_w > 0.0, slot, -1)
 
 
-def _topk_specs(bs: int, K: int, Sp: int):
+def _topk_specs(bs: int, K: int):
     list_spec = pl.BlockSpec((bs, K), lambda i: (i, 0))
-    row_spec = pl.BlockSpec((bs,), lambda i: (i,))
-    full_spec = pl.BlockSpec((Sp,), lambda i: (0,))
-    thr_spec = pl.BlockSpec((1,), lambda i: (0,))
-    return list_spec, row_spec, full_spec, thr_spec
+    row_spec = pl.BlockSpec((bs, 1), lambda i: (i, 0))
+    return list_spec, row_spec
+
+
+def _gather_rows(ids, *vals):
+    """Each neighbor's entry of the per-slot vectors ``vals``: [S, K]."""
+    safe = jnp.clip(ids, 0, ids.shape[0] - 1)
+    return [v.astype(jnp.int32)[safe] for v in vals]
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
 def topk_round_scan_pallas(ids, sims, rank, unresolved, is_rep, alpha, *,
-                           bs: int = 8, interpret: bool = True):
+                           bs: int = 8, interpret: bool | None = None):
     """(blocked [S], claimed [S]) for one round; S divisible by bs."""
+    if interpret is None:
+        interpret = default_interpret()
     S, K = ids.shape
     assert S % bs == 0, (S, bs)
-    thr = jnp.asarray(alpha, jnp.float32).reshape(1)
-    list_spec, row_spec, full_spec, thr_spec = _topk_specs(bs, K, S)
-    return pl.pallas_call(
+    list_spec, row_spec = _topk_specs(bs, K)
+    blocked, claimed = pl.pallas_call(
         _topk_round_scan_kernel,
         grid=(S // bs,),
-        in_specs=[list_spec, list_spec, row_spec, full_spec, full_spec,
-                  full_spec, thr_spec],
+        in_specs=[list_spec] * 5 + [row_spec, _SMEM],
         out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((S,), jnp.bool_)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((S, 1), jnp.int32)] * 2,
         interpret=interpret,
-    )(ids.astype(jnp.int32), sims, rank.astype(jnp.int32),
-      rank.astype(jnp.int32), unresolved.astype(jnp.bool_),
-      is_rep.astype(jnp.bool_), thr)
+    )(ids.astype(jnp.int32), sims,
+      *_gather_rows(ids, rank, unresolved, is_rep), _col(rank), _thr(alpha))
+    return blocked[:, 0] != 0, claimed[:, 0] != 0
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
 def topk_assign_pallas(ids, sims, rank, is_rep, valid, alpha, *,
-                       bs: int = 8, interpret: bool = True):
+                       bs: int = 8, interpret: bool | None = None):
     """(best_w [S], best_slot [S]) claim-max over neighbor lists."""
+    if interpret is None:
+        interpret = default_interpret()
     S, K = ids.shape
     assert S % bs == 0, (S, bs)
-    thr = jnp.asarray(alpha, jnp.float32).reshape(1)
-    list_spec, row_spec, full_spec, thr_spec = _topk_specs(bs, K, S)
+    list_spec, row_spec = _topk_specs(bs, K)
     w, slot = pl.pallas_call(
         _topk_assign_kernel,
         grid=(S // bs,),
-        in_specs=[list_spec, list_spec, full_spec, full_spec, row_spec,
-                  thr_spec],
+        in_specs=[list_spec] * 4 + [row_spec, _SMEM],
         out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((S,), jnp.float32),
-                   jax.ShapeDtypeStruct((S,), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((S, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((S, 1), jnp.int32)],
         interpret=interpret,
-    )(ids.astype(jnp.int32), sims, rank.astype(jnp.int32),
-      is_rep.astype(jnp.bool_), valid.astype(jnp.bool_), thr)
-    return w, slot
+    )(ids.astype(jnp.int32), sims, *_gather_rows(ids, rank, is_rep),
+      _col(valid), _thr(alpha))
+    return w[:, 0], slot[:, 0]

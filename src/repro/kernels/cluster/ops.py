@@ -54,7 +54,7 @@ def _padded(sim, rank, vecs, bu: int, bs: int):
 
 
 def cluster_round_scan(sim, rank, unresolved, is_rep, alpha, *,
-                       bu: int = 8, bs: int = 128, interpret: bool = True):
+                       bu: int = 8, bs: int = 128, interpret: bool | None = None):
     """(blocked [S], claimed [S]) — one fused round scan."""
     S = sim.shape[0]
     sim_p, rank_p, (unres_p, rep_p) = _padded(
@@ -66,7 +66,7 @@ def cluster_round_scan(sim, rank, unresolved, is_rep, alpha, *,
 
 
 def cluster_assign(sim, rank, is_rep, valid, alpha, *,
-                   bu: int = 8, bs: int = 128, interpret: bool = True):
+                   bu: int = 8, bs: int = 128, interpret: bool | None = None):
     """(best_w [S], best_slot [S]) — final claim-max over rep rows."""
     S = sim.shape[0]
     sim_p, rank_p, (rep_p, valid_p) = _padded(
@@ -97,7 +97,7 @@ def _padded_topk(ids, sims, rank, vecs, bs: int):
 
 
 def topk_cluster_round_scan(ids, sims, rank, unresolved, is_rep, alpha, *,
-                            bs: int = 8, interpret: bool = True):
+                            bs: int = 8, interpret: bool | None = None):
     """(blocked [S], claimed [S]) — one round scan over [S, K] lists."""
     from repro.kernels.cluster.cluster import topk_round_scan_pallas
     S = ids.shape[0]
@@ -110,7 +110,7 @@ def topk_cluster_round_scan(ids, sims, rank, unresolved, is_rep, alpha, *,
 
 
 def topk_cluster_assign(ids, sims, rank, is_rep, valid, alpha, *,
-                        bs: int = 8, interpret: bool = True):
+                        bs: int = 8, interpret: bool | None = None):
     """(best_w [S], best_slot [S]) — claim-max over [S, K] lists."""
     from repro.kernels.cluster.cluster import topk_assign_pallas
     S = ids.shape[0]

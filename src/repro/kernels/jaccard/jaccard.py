@@ -14,11 +14,12 @@ line 7).  The window OR uses the same idempotent-monoid decomposition as
 ``repro.core.windows`` (DESIGN.md §7), in its in-register doubling form:
 a trailing window of length ``c`` doubles to ``c + min(c, w - c)`` with a
 single static shift+OR, so the full window costs ``ceil(log2 w)``
-shift+OR steps over the resident ``[bt, M + w - 1, W]`` slab — pure
+shift+OR steps over the resident ``[bt, M, W]`` slab — pure
 integer VPU work (no MXU, no gathers), ``O(M * log(w) * W)`` ops per
 trajectory where the bit-expanded reference spends ``O(M * w * W * 32)``.
-Both windows fall out of ONE trailing-window array: ``l1[n] = incl[n-1]``
-and ``l2[n] = incl[n+w-1]`` — two more static shifts.
+``l1`` is the trailing window shifted by one; ``l2`` is the same
+doubling run with the shifts reversed (a leading window).  Every shift is
+a roll along the point axis plus an iota mask.
 
 Block layout (stjoin tile conventions): the grid walks blocks of ``bt``
 whole trajectories; the whole point axis is resident per program instance
@@ -33,33 +34,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret, shift_points as _shift
+
+
+def _window_or(masks, w: int, sign: int):
+    """OR over ``w`` consecutive positions ending (``sign=+1``) or
+    starting (``sign=-1``) at each position, by doubling: a window of
+    length ``c`` grows to ``c + min(c, w - c)`` with one shift+OR."""
+    acc, c = masks, 1
+    while c < w:
+        step = min(c, w - c)
+        acc = acc | _shift(acc, sign * step)
+        c += step
+    return acc
+
 
 def _kernel(masks_ref, out_d_ref, *, w: int):
-    masks = masks_ref[...]                         # [bt, M, W] uint32
-    bt, M, W = masks.shape
-
-    def shifted_right(a, k):
-        """``a`` shifted so position m reads ``a[m - k]`` (zeros off-edge)."""
-        if k == 0:
-            return a
-        Ma = a.shape[1]
-        kk = min(k, Ma)
-        pad = jnp.zeros((bt, kk, W), a.dtype)
-        return jnp.concatenate([pad, a[:, :Ma - kk]], axis=1)
-
-    # trailing-window union incl[m] = OR(masks[max(m-w+1, 0) .. m]) on the
-    # slab extended by w-1 zero columns (zero is the OR identity, so the
-    # extension exactly models the off-end positions l2 reads)
-    x = masks if w <= 1 else jnp.concatenate(
-        [masks, jnp.zeros((bt, w - 1, W), masks.dtype)], axis=1)
-    incl, c = x, 1
-    while c < w:                                   # doubling windowed OR
-        step = min(c, w - c)
-        incl = incl | shifted_right(incl, step)
-        c += step
-
-    l1 = shifted_right(incl, 1)[:, :M]             # W1 = [n-w, n-1]
-    l2 = incl[:, w - 1:w - 1 + M]                  # W2 = [n, n+w-1]
+    masks = masks_ref[...]                         # [bt, M, W] int32 words
+    l1 = _shift(_window_or(masks, w, 1), 1)        # W1 = [n-w, n-1]
+    l2 = _window_or(masks, w, -1)                  # W2 = [n, n+w-1]
 
     pc = jax.lax.population_count
     inter = jnp.sum(pc(l1 & l2), axis=-1).astype(jnp.float32)
@@ -70,8 +63,14 @@ def _kernel(masks_ref, out_d_ref, *, w: int):
 
 @functools.partial(jax.jit, static_argnames=("w", "bt", "interpret"))
 def jaccard_pallas(masks: jnp.ndarray, *, w: int, bt: int = 8,
-                   interpret: bool = True) -> jnp.ndarray:
-    """[T, M, W] packed masks -> [T, M] window Jaccard dissimilarity."""
+                   interpret: bool | None = None) -> jnp.ndarray:
+    """[T, M, W] packed masks -> [T, M] window Jaccard dissimilarity.
+
+    The words are carried as int32 (Mosaic reduces no unsigned type);
+    OR, shifts and popcount are bit-for-bit the same on either.
+    """
+    if interpret is None:
+        interpret = default_interpret()
     T, M, W = masks.shape
     padT = (-T) % bt
     if padT:
@@ -85,5 +84,5 @@ def jaccard_pallas(masks: jnp.ndarray, *, w: int, bt: int = 8,
         out_specs=pl.BlockSpec((bt, M), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, M), jnp.float32),
         interpret=interpret,
-    )(masks)
+    )(jax.lax.bitcast_convert_type(masks, jnp.int32))
     return out[:T]

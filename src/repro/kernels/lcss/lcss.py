@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 NEG = -1e30
 
 
@@ -54,9 +56,12 @@ def _kernel(ws_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lcss_pallas(ws: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def lcss_pallas(ws: jnp.ndarray, *,
+                interpret: bool | None = None) -> jnp.ndarray:
     """``ws``: [B, 2, N, D] sheared weights (channel 0 weighted, 1 unit).
     Returns scores [B, 2]."""
+    if interpret is None:
+        interpret = default_interpret()
     B, ch, N, D = ws.shape
     assert ch == 2
     return pl.pallas_call(

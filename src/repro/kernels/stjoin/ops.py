@@ -1,14 +1,17 @@
-"""Public jit'd wrapper: TrajectoryBatch-level subtrajectory join via Pallas.
+"""Public jit'd wrappers: TrajectoryBatch-level subtrajectory join via Pallas.
 
-Two entry points:
+These wrappers own the padding and the tile geometry, so the kernels in
+``stjoin.py`` only ever see whole tiles.
 
-* ``best_match_join_kernel``  — the dense join: every (ref block, cand
-  block) tile is visited.  Fallback and parity oracle.
+* ``best_match_join_kernel``  — the dense materializing join: every (ref
+  block, cand block) tile is visited.  Fallback and parity oracle.
 * ``best_match_join_pruned``  — index-accelerated join: a spatiotemporal
   grid over tile bounding boxes (``repro.index.grid``) first emits, per
   reference block, the compacted list of candidate tiles that can contain
-  a match; only those tiles enter the Pallas kernel.  Output is
-  bit-identical to the dense join (pruning is conservative).
+  a match; only those tiles are swept.  Output is bit-identical to the
+  dense join (pruning is conservative).
+* the fused streaming passes (``stjoin_vote_fused`` / ``stjoin_sim_fused``
+  / ``stjoin_sim_panel_fused``), which never build the ``[T, M, C]`` cube.
 """
 from __future__ import annotations
 
@@ -20,19 +23,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.geometry import filter_delta_t
+from repro.core.geometry import refine_join
 from repro.core.types import JoinResult, TrajectoryBatch
 from repro.index import grid as gridx
-from repro.kernels import default_interpret
 from repro.kernels.stjoin.stjoin import (
     stjoin_pallas,
-    stjoin_pallas_pruned,
     stjoin_sim_fused_flat,
-    stjoin_sim_fused_pruned_flat,
-    stjoin_sim_panel_fused_flat,
-    stjoin_sim_panel_fused_pruned_flat,
+    stjoin_sim_panel_flat,
     stjoin_vote_fused_flat,
-    stjoin_vote_fused_pruned_flat,
 )
 
 
@@ -46,39 +44,15 @@ def _pad_to(x: jnp.ndarray, mult: int, axis: int, fill):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-@functools.partial(jax.jit, static_argnames=("bp", "bc", "bm", "interpret"))
-def best_match_join_kernel(ref: TrajectoryBatch, cand: TrajectoryBatch,
-                           eps_sp, eps_t, *, bp=256, bc=8, bm=128,
-                           interpret: bool | None = None) -> JoinResult:
-    if interpret is None:
-        interpret = default_interpret()
-    T, M = ref.x.shape
-    C, Mc = cand.x.shape
-
-    rx = _pad_to(ref.x.reshape(-1), bp, 0, 0.0)
-    ry = _pad_to(ref.y.reshape(-1), bp, 0, 0.0)
-    rt = _pad_to(ref.t.reshape(-1), bp, 0, 0.0)
-    rok = _pad_to(ref.valid.reshape(-1), bp, 0, False)
-    rid = _pad_to(
-        jnp.broadcast_to(ref.traj_id[:, None], (T, M)).reshape(-1), bp, 0, -1)
-
-    cx = _pad_to(_pad_to(cand.x, bm, 1, 0.0), bc, 0, 0.0)
-    cy = _pad_to(_pad_to(cand.y, bm, 1, 0.0), bc, 0, 0.0)
-    ct = _pad_to(_pad_to(cand.t, bm, 1, 0.0), bc, 0, 0.0)
-    cok = _pad_to(_pad_to(cand.valid, bm, 1, False), bc, 0, False)
-    cid = _pad_to(cand.traj_id, bc, 0, -2)
-
-    w, idx = stjoin_pallas(rx, ry, rt, rid, rok, cx, cy, ct, cid, cok,
-                           eps_sp, eps_t, bp=bp, bc=bc, bm=bm,
-                           interpret=interpret)
-    w = w[:T * M, :C].reshape(T, M, C)
-    idx = idx[:T * M, :C].reshape(T, M, C)
-    return JoinResult(best_w=w, best_idx=idx)
+def _join_geometry(C: int, Mc: int, bc: int, bm: int):
+    """Candidate tile ``bc`` and point chunk ``bm``, clamped to the data
+    (a tile that spans the whole padded axis is always legal on TPU)."""
+    return min(bc, C), min(bm, Mc)
 
 
 def _padded_operands(ref: TrajectoryBatch, cand: TrajectoryBatch,
                      bp: int, bc: int, bm: int):
-    """The dense wrapper's padding, shared with the pruned path."""
+    """The materializing join's padding, shared with its index planner."""
     T, M = ref.x.shape
     rx = _pad_to(ref.x.reshape(-1), bp, 0, 0.0)
     ry = _pad_to(ref.y.reshape(-1), bp, 0, 0.0)
@@ -95,13 +69,35 @@ def _padded_operands(ref: TrajectoryBatch, cand: TrajectoryBatch,
     return (rx, ry, rt, rid, rok), (cx, cy, ct, cid, cok)
 
 
+@functools.partial(jax.jit, static_argnames=("bp", "bc", "bm", "interpret"))
+def _join_sweep(ref, cand, eps_sp, eps_t, tile_ids, *, bp, bc, bm,
+                interpret):
+    T, M = ref.x.shape
+    C = cand.x.shape[0]
+    ref_ops, cand_ops = _padded_operands(ref, cand, bp, bc, bm)
+    w, idx = stjoin_pallas(*ref_ops, *cand_ops, eps_sp, eps_t, tile_ids,
+                           bp=bp, bc=bc, bm=bm, interpret=interpret)
+    return JoinResult(best_w=w[:T * M, :C].reshape(T, M, C),
+                      best_idx=idx[:T * M, :C].reshape(T, M, C))
+
+
+def best_match_join_kernel(ref: TrajectoryBatch, cand: TrajectoryBatch,
+                           eps_sp, eps_t, *, bp=256, bc=128, bm=128,
+                           interpret: bool | None = None) -> JoinResult:
+    """Dense Pallas join: ``JoinResult [T, M, C]``."""
+    bc, bm = _join_geometry(cand.x.shape[0], cand.x.shape[1], bc, bm)
+    return _join_sweep(ref, cand, eps_sp, eps_t, None, bp=bp, bc=bc, bm=bm,
+                       interpret=interpret)
+
+
 def plan_join_index(ref: TrajectoryBatch, cand: TrajectoryBatch,
-                    eps_sp, eps_t, *, bp=256, bc=8, use_cells: bool = True):
+                    eps_sp, eps_t, *, bp=256, bc=128, use_cells: bool = True):
     """Candidate-tile mask + per-ref-block survivor counts.
 
     Host-driven (not jitted as a whole: the grid geometry is fitted from
     the concrete data, and baking it in as a static jit argument would
     retrace on every new batch).  The array math inside is plain jnp.
+    ``bc`` is the resolved candidate tile.
     Returns ``(mask [nRb, nCb] bool, counts [nRb] i32, spec | None)``.
     """
     (rx, ry, rt, _, rok), (cx, cy, ct, _, cok) = _padded_operands(
@@ -121,7 +117,7 @@ def plan_join_index(ref: TrajectoryBatch, cand: TrajectoryBatch,
 
 
 def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
-                           eps_sp, eps_t, *, bp=256, bc=8, bm=128,
+                           eps_sp, eps_t, *, bp=256, bc=128, bm=128,
                            max_tiles: int | None = None,
                            use_cells: bool = True,
                            interpret: bool | None = None,
@@ -130,16 +126,11 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
 
     Host-driven planning (concrete inputs required): fits the eps-derived
     grid, compacts the surviving candidate-tile lists to a static width
-    ``K`` (``max_tiles`` or the observed maximum), then runs the sparse
-    Pallas kernel over only those tiles.  Raises if ``max_tiles`` is too
-    small to keep every survivor, since dropping one would break parity.
+    ``K`` (``max_tiles`` or the observed maximum), then sweeps only those
+    tiles.  Raises if ``max_tiles`` is too small to keep every survivor,
+    since dropping one would break parity.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    T, M = ref.x.shape
-    C, _ = cand.x.shape
-
-    # planning pass: bboxes only, bm-independent
+    bc, bm = _join_geometry(cand.x.shape[0], cand.x.shape[1], bc, bm)
     mask, counts, _ = plan_join_index(
         ref, cand, eps_sp, eps_t, bp=bp, bc=bc, use_cells=use_cells)
 
@@ -150,14 +141,8 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
             f"max_tiles={K} drops candidate tiles (need {need}); "
             "the pruned join would no longer match the dense join")
     tile_ids, counts = gridx.compact_candidates(mask, K)
-
-    (rx, ry, rt, rid, rok), (cx, cy, ct, cid, cok) = _padded_operands(
-        ref, cand, bp, bc, bm)
-    w, idx = stjoin_pallas_pruned(
-        rx, ry, rt, rid, rok, cx, cy, ct, cid, cok, tile_ids,
-        eps_sp, eps_t, bp=bp, bc=bc, bm=bm, interpret=interpret)
-    out = JoinResult(best_w=w[:T * M, :C].reshape(T, M, C),
-                     best_idx=idx[:T * M, :C].reshape(T, M, C))
+    out = _join_sweep(ref, cand, eps_sp, eps_t, tile_ids, bp=bp, bc=bc,
+                      bm=bm, interpret=interpret)
     if return_stats:
         return out, gridx.prune_stats(counts, mask.shape[1])
     return out
@@ -168,31 +153,31 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
 # never materialized.  Pass 1 (``stjoin_vote_fused``) returns the per-point
 # vote sums and the bit-packed TSA2 neighbor words; pass 2
 # (``stjoin_sim_fused``) re-sweeps the same tiles after segmentation and
-# scatter-adds refined weights straight into the raw similarity accumulator.
+# accumulates refined weights straight into the raw similarity blocks.
 # Both accept an optional pre-computed ``tile_ids`` plan (``plan_fused_tiles``)
 # to sweep only the index-surviving candidate tiles.
 # ---------------------------------------------------------------------------
 
 
-def _fused_geometry(T: int, M: int, Mc: int, rows: int | None, bc: int,
-                    bm: int):
+def _fused_geometry(T: int, M: int, C: int, Mc: int, rows: int | None,
+                    bc: int, bm: int):
     """Resolve the fused kernels' tile geometry for raw [T, M]/[C, Mc] data.
 
-    Ref blocks must hold whole trajectory rows (in-kernel delta_t refine),
-    so the block is ``rows`` rows of ``M`` points; ``bc`` is clamped to a
-    divisor of 32 (a candidate block must stay inside one packed word);
-    ``bm`` is clamped to the candidate row length.  Defaults favor fat
-    tiles (~2048 ref points per block, capped at the whole batch): the
-    fused kernels write no per-tile output blocks, so fewer grid steps is
-    pure win; the per-chunk working set ``[bp, bc, bm]`` stays VMEM-sized
-    via the inner ``bm`` loop.
+    Ref blocks hold whole trajectory rows (in-kernel delta_t refine), so a
+    block is ``rows`` rows of ``M`` points, about 256 points by default.
+    ``bc`` divides 32 or is a multiple of 32 (a block's bits pack into
+    whole words) and is clamped to the candidates padded to 32; ``bm``
+    (the candidate-point padding granule) is clamped to the row length.
+    Returns ``(rows, bc, bm, padC, mc_pad)``.
     """
-    rows = rows if rows is not None else max(1, 2048 // max(M, 1))
+    rows = rows if rows is not None else max(1, 256 // max(M, 1))
     rows = min(rows, max(T, 1))
-    bc = max(d for d in (1, 2, 4, 8, 16, 32) if d <= max(bc, 1))
+    c32 = -(-max(C, 1) // 32) * 32
+    bc = (max(d for d in (1, 2, 4, 8, 16) if d <= max(bc, 1)) if bc < 32
+          else min(bc // 32 * 32, c32))
+    padC = (-C) % max(32, bc)
     bm = min(bm, Mc)
-    mc_pad = (-Mc) % bm
-    return rows, bc, bm, mc_pad
+    return rows, bc, bm, padC, (-Mc) % bm
 
 
 def _fused_ref_operands(rx, ry, rt, rvalid, rid, rows: int):
@@ -207,13 +192,9 @@ def _fused_ref_operands(rx, ry, rt, rvalid, rid, rows: int):
             pad2(rvalid, False).reshape(-1))
 
 
-def _fused_cand_operands(cx, cy, ct, cvalid, cid, bm: int, mc_pad: int):
-    """Pad candidates to whole words (C -> multiple of 32) and bm chunks.
-
-    Returned in kernel operand order: ``(x, y, t, id, ok)``.
-    """
-    C, _ = cx.shape
-    padC = (-C) % 32
+def _fused_cand_operands(cx, cy, ct, cvalid, cid, padC: int, mc_pad: int):
+    """Pad candidates to whole tiles and point granules, in kernel operand
+    order ``(x, y, t, id, ok)``."""
     pad = lambda a, f: jnp.pad(a, ((0, padC), (0, mc_pad)), constant_values=f)
     return (pad(cx, 0.0), pad(cy, 0.0), pad(ct, 0.0),
             jnp.pad(cid.astype(jnp.int32), (0, padC), constant_values=-2),
@@ -251,36 +232,38 @@ def _resolve_plan(tile_ids, rows: int, bc: int, bm: int):
 
 
 def plan_fused_tiles(rx, ry, rt, rvalid, cx, cy, ct, cvalid, eps_sp, eps_t,
-                     *, rows: int | None = None, bc: int = 16, bm: int = 128,
+                     *, rows: int | None = None, bc: int = 128, bm: int = 128,
                      use_cells: bool = True, max_tiles: int | None = None):
     """Host-driven candidate-tile plan for the fused kernels.
 
     Same two-stage conservative pruning as ``plan_join_index`` but on raw
     ``[T, M]`` / ``[C, Mc]`` arrays with the fused row-aligned block
     geometry.  Returns a ``FusedTilePlan`` (tile ids -1 padded, ascending,
-    plus the resolved geometry) ready for the ``*_pruned`` fused entry
-    points, which reject a plan whose geometry differs from their own.
-    Raises if ``max_tiles`` would drop a survivor.
+    plus the resolved geometry) ready for the fused entry points, which
+    reject a plan whose geometry differs from their own.  Raises if
+    ``max_tiles`` would drop a survivor.
 
     Geometry knobs (``rows``, ``bc``, ``bm``) are the fused tile plan of
     ``EnginePlan.fused_tiles`` (DESIGN.md §9): ``rows`` reference-trajectory
-    rows per block (``None`` = the fat-tile default ``max(1, 2048 // M)``),
-    ``bc`` candidate trajectories per block, ``bm`` candidate points per
-    chunk.  Pruning quality depends on them — smaller blocks give the grid
-    tighter boxes to reject, larger blocks amortize sweep overhead — which
-    is why the dispatcher re-binds the *resolved* geometry into the plan
-    before tracing: the sweep must run the exact tiling the tile ids were
-    built for.  The autotuner (``repro.tune.autotune.tune_join``) sweeps
-    this lattice rather than guessing.
+    rows per block (``None`` = about 256 points per block), ``bc``
+    candidate trajectories per block (the lane tile: on TPU a multiple of
+    128 or every candidate), ``bm`` the candidate-point padding granule.
+    Pruning quality depends on them — smaller blocks give the grid tighter
+    boxes to reject, larger blocks amortize sweep overhead — which is why
+    the dispatcher re-binds the *resolved* geometry into the plan before
+    tracing: the sweep must run the exact tiling the tile ids were built
+    for.  The autotuner (``repro.tune.autotune.tune_join``) sweeps this
+    lattice rather than guessing.
     """
     M = rx.shape[1]
-    rows, bc, bm, mc_pad = _fused_geometry(
-        rx.shape[0], M, cx.shape[1], rows, bc, bm)
+    rows, bc, bm, padC, mc_pad = _fused_geometry(
+        rx.shape[0], M, cx.shape[0], cx.shape[1], rows, bc, bm)
     bp = rows * M
     frx, fry, frt, _, frok = _fused_ref_operands(
         rx, ry, rt, rvalid, jnp.zeros((rx.shape[0],), jnp.int32), rows)
     fcx, fcy, fct, _, fcok = _fused_cand_operands(
-        cx, cy, ct, cvalid, jnp.zeros((cx.shape[0],), jnp.int32), bm, mc_pad)
+        cx, cy, ct, cvalid, jnp.zeros((cx.shape[0],), jnp.int32), padC,
+        mc_pad)
 
     rboxes = gridx.point_block_boxes(frx, fry, frt, frok, bp)
     cboxes = gridx.traj_block_boxes(fcx, fcy, fct, fcok, bc)
@@ -294,8 +277,7 @@ def plan_fused_tiles(rx, ry, rt, rvalid, cx, cy, ct, cvalid, eps_sp, eps_t,
     counts = jnp.sum(mask, axis=1).astype(jnp.int32)
     need = gridx.plan_max_tiles(counts)
     # K >= 1 even when nothing survives: a zero-width slot axis would give
-    # the pruned kernels an empty grid and leave their accumulators
-    # uninitialized
+    # the kernels an empty grid and leave their accumulators uninitialized
     K = max(max_tiles, 1) if max_tiles is not None else need
     if int(np.max(np.asarray(counts), initial=0)) > K:
         raise ValueError(
@@ -307,7 +289,7 @@ def plan_fused_tiles(rx, ry, rt, rvalid, cx, cy, ct, cvalid, eps_sp, eps_t,
 
 def stjoin_vote_fused_arrays(rx, ry, rt, rvalid, rid, cx, cy, ct, cvalid,
                              cid, eps_sp, eps_t, delta_t=0.0, *,
-                             rows: int | None = None, bc: int = 16,
+                             rows: int | None = None, bc: int = 128,
                              bm: int = 128, tile_ids=None,
                              with_masks: bool = True,
                              interpret: bool | None = None):
@@ -320,24 +302,15 @@ def stjoin_vote_fused_arrays(rx, ry, rt, rvalid, rid, cx, cy, ct, cvalid,
     (segmentation won't consume neighbor sets, i.e. TSA1) returns
     ``(vote, None)`` and skips the packed-word accumulator entirely.
     """
-    if interpret is None:
-        interpret = default_interpret()
     T, M = rx.shape
     C, Mc = cx.shape
-    rows, bc, bm, mc_pad = _fused_geometry(T, M, Mc, rows, bc, bm)
+    rows, bc, bm, padC, mc_pad = _fused_geometry(T, M, C, Mc, rows, bc, bm)
     tile_ids = _resolve_plan(tile_ids, rows, bc, bm)
-    ref_ops = _fused_ref_operands(rx, ry, rt, rvalid, rid, rows)
-    cand_ops = _fused_cand_operands(cx, cy, ct, cvalid, cid, bm, mc_pad)
-
-    if tile_ids is None:
-        vote, words = stjoin_vote_fused_flat(
-            *ref_ops, *cand_ops, eps_sp, eps_t, delta_t, rows=rows, M=M,
-            bc=bc, bm=bm, with_words=with_masks, interpret=interpret)
-    else:
-        vote, words = stjoin_vote_fused_pruned_flat(
-            *ref_ops, *cand_ops, tile_ids, eps_sp, eps_t, delta_t,
-            rows=rows, M=M, bc=bc, bm=bm, with_words=with_masks,
-            interpret=interpret)
+    vote, words = stjoin_vote_fused_flat(
+        *_fused_ref_operands(rx, ry, rt, rvalid, rid, rows),
+        *_fused_cand_operands(cx, cy, ct, cvalid, cid, padC, mc_pad),
+        eps_sp, eps_t, delta_t, tile_ids, rows=rows, M=M, bc=bc,
+        with_words=with_masks, interpret=interpret)
     vote = vote[:T * M].reshape(T, M)
     if words is None:
         return vote, None
@@ -348,7 +321,7 @@ def stjoin_vote_fused_arrays(rx, ry, rt, rvalid, rid, cx, cy, ct, cvalid,
 def stjoin_vote_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
                       eps_sp, eps_t, delta_t=0.0, *, use_index: bool = False,
                       use_cells: bool = True, max_tiles: int | None = None,
-                      rows: int | None = None, bc: int = 16, bm: int = 128,
+                      rows: int | None = None, bc: int = 128, bm: int = 128,
                       with_masks: bool = True,
                       interpret: bool | None = None):
     """Batch-level fused pass 1 (vote sums + packed neighbor words)."""
@@ -365,50 +338,59 @@ def stjoin_vote_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
         with_masks=with_masks, interpret=interpret)
 
 
-def stjoin_sim_fused_arrays(rx, ry, rt, rvalid, rid, ref_gid, cx, cy, ct,
-                            cvalid, cid, cand_gid, n_src: int, n_dst: int,
-                            eps_sp, eps_t, delta_t=0.0, *,
-                            rows: int | None = None, bc: int = 16,
-                            bm: int = 128, tile_ids=None,
-                            interpret: bool | None = None):
-    """Fused pass 2 on raw arrays: raw similarity scatter ``[n_src, n_dst]``.
+def _sim_operands(rx, ry, rt, rvalid, rid, ref_sub, cx, cy, ct, cvalid, cid,
+                  cand_sub, rows, bc, bm):
+    """Padded kernel operands plus the resolved geometry for pass 2.
 
-    ``ref_gid [T, M]``: destination row of each ref point (``n_src`` =
-    sentinel).  ``cand_gid [C, Mc]``: destination column of each candidate
-    point (``n_dst`` = sentinel).  Subsumes the materializing
-    ``similarity_matrix`` gather/scatter over T*M*C elements; normalization
-    is left to ``similarity.finalize_sim`` so both paths share the math.
+    Pass 2 packs no words, so its candidate axis is padded to whole
+    ``bc`` tiles only, not to 32 like pass 1's.
     """
-    if interpret is None:
-        interpret = default_interpret()
     T, M = rx.shape
     C, Mc = cx.shape
-    rows, bc, bm, mc_pad = _fused_geometry(T, M, Mc, rows, bc, bm)
-    tile_ids = _resolve_plan(tile_ids, rows, bc, bm)
-    ref_ops = _fused_ref_operands(rx, ry, rt, rvalid, rid, rows)
+    rows, bc, bm, _, mc_pad = _fused_geometry(T, M, C, Mc, rows, bc, bm)
+    padC = (-C) % bc
     padT = (-T) % rows
-    gid_flat = jnp.pad(ref_gid.astype(jnp.int32), ((0, padT), (0, 0)),
-                       constant_values=n_src).reshape(-1)
-    cand_ops = _fused_cand_operands(cx, cy, ct, cvalid, cid, bm, mc_pad)
-    padC = (-C) % 32
-    cgid = jnp.pad(cand_gid.astype(jnp.int32), ((0, padC), (0, mc_pad)),
-                   constant_values=n_dst)
+    rsub = jnp.pad(ref_sub.astype(jnp.int32), ((0, padT), (0, 0)),
+                   constant_values=-1).reshape(-1)
+    csub = jnp.pad(cand_sub.astype(jnp.int32), ((0, padC), (0, mc_pad)),
+                   constant_values=-1)
+    return ((_fused_ref_operands(rx, ry, rt, rvalid, rid, rows), rsub,
+             _fused_cand_operands(cx, cy, ct, cvalid, cid, padC, mc_pad),
+             csub), (rows, bc, bm))
 
-    if tile_ids is None:
-        return stjoin_sim_fused_flat(
-            *ref_ops, gid_flat, *cand_ops, cgid, eps_sp, eps_t, delta_t,
-            rows=rows, M=M, n_src=n_src, n_dst=n_dst, bc=bc, bm=bm,
-            interpret=interpret)
-    return stjoin_sim_fused_pruned_flat(
-        *ref_ops, gid_flat, *cand_ops, cgid, tile_ids, eps_sp, eps_t,
-        delta_t, rows=rows, M=M, n_src=n_src, n_dst=n_dst, bc=bc, bm=bm,
-        interpret=interpret)
+
+def stjoin_sim_fused_arrays(rx, ry, rt, rvalid, rid, ref_sub, cx, cy, ct,
+                            cvalid, cid, cand_sub, max_subs: int,
+                            eps_sp, eps_t, delta_t=0.0, *,
+                            rows: int | None = None, bc: int = 128,
+                            bm: int = 128, tile_ids=None,
+                            interpret: bool | None = None):
+    """Fused pass 2 on raw arrays: raw similarity scatter
+    ``[T * max_subs, C * max_subs]``.
+
+    ``ref_sub [T, M]`` / ``cand_sub [C, Mc]``: each point's local sub-slot
+    (-1 = unsegmented).  Row ``r * max_subs + a`` / column ``c * max_subs
+    + k`` is slot ``a`` of ref row ``r`` / slot ``k`` of candidate ``c``.
+    Subsumes the materializing ``similarity_matrix`` gather/scatter over
+    T*M*C elements; normalization is left to ``similarity.finalize_sim``
+    so both paths share the math.
+    """
+    T, M = rx.shape
+    C = cx.shape[0]
+    (ref_ops, rsub, cand_ops, csub), (rows, bc, bm) = _sim_operands(
+        rx, ry, rt, rvalid, rid, ref_sub, cx, cy, ct, cvalid, cid, cand_sub,
+        rows, bc, bm)
+    raw = stjoin_sim_fused_flat(
+        ref_ops, rsub, cand_ops, csub, eps_sp, eps_t, delta_t,
+        _resolve_plan(tile_ids, rows, bc, bm), rows=rows, M=M, ms=max_subs,
+        bc=bc, interpret=interpret)
+    return raw[:T * max_subs, :C * max_subs]
 
 
 def stjoin_sim_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
                      ref_sub_local, cand_sub_local, max_subs: int,
                      eps_sp, eps_t, delta_t=0.0, *, tile_ids=None,
-                     rows: int | None = None, bc: int = 16, bm: int = 128,
+                     rows: int | None = None, bc: int = 128, bm: int = 128,
                      interpret: bool | None = None):
     """Batch-level fused pass 2: un-normalized ``raw [S_ref, S_cand]``.
 
@@ -416,103 +398,43 @@ def stjoin_sim_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
     row ``r * max_subs + sub_local[r, m]``; the matched candidate point
     (c, best_idx) into column ``c * max_subs + cand_sub_local[c, idx]``.
     """
-    T, M = ref.x.shape
-    C, Mc = cand.x.shape
-    n_src = T * max_subs
-    n_dst = C * max_subs
-    ref_gid = jnp.where(
-        ref_sub_local >= 0,
-        jnp.arange(T, dtype=jnp.int32)[:, None] * max_subs
-        + ref_sub_local, n_src)
-    cand_gid = jnp.where(
-        cand_sub_local >= 0,
-        jnp.arange(C, dtype=jnp.int32)[:, None] * max_subs
-        + cand_sub_local, n_dst)
     return stjoin_sim_fused_arrays(
-        ref.x, ref.y, ref.t, ref.valid, ref.traj_id, ref_gid,
-        cand.x, cand.y, cand.t, cand.valid, cand.traj_id, cand_gid,
-        n_src, n_dst, eps_sp, eps_t, delta_t, rows=rows, bc=bc, bm=bm,
+        ref.x, ref.y, ref.t, ref.valid, ref.traj_id, ref_sub_local,
+        cand.x, cand.y, cand.t, cand.valid, cand.traj_id, cand_sub_local,
+        max_subs, eps_sp, eps_t, delta_t, rows=rows, bc=bc, bm=bm,
         tile_ids=tile_ids, interpret=interpret)
-
-
-def stjoin_sim_panel_fused_arrays(rx, ry, rt, rvalid, rid, ref_gid, cx, cy,
-                                  ct, cvalid, cid, cand_gid, n_src: int,
-                                  n_dst: int, eps_sp, eps_t, delta_t, p0,
-                                  *, panel: int, tile_ids=None,
-                                  rows: int | None = None,
-                                  bc: int = 16, bm: int = 128,
-                                  interpret: bool | None = None):
-    """Fused pass 2 on raw arrays, panel-streamed: one ``Sb``-row panel of
-    the raw similarity scatter in both orientations.
-
-    Returns ``(fwd [panel, n_dst], rev [panel, n_src])`` where
-    ``fwd[i, j] = raw[p0 + i, j]`` and ``rev[i, j] = raw[j, p0 + i]`` of
-    the dense accumulator ``stjoin_sim_fused_arrays`` would build —
-    bit-equal cell sums, panel rows only.  ``p0`` may be traced (the
-    panel loop re-invokes one trace); ``panel`` is static.  ``tile_ids``
-    (from ``plan_fused_tiles`` with identical geometry) sweeps only the
-    index-surviving candidate tiles per panel; identical output either
-    way (pruned tiles contribute exactly 0).
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    T, M = rx.shape
-    C, Mc = cx.shape
-    rows, bc, bm, mc_pad = _fused_geometry(T, M, Mc, rows, bc, bm)
-    tile_ids = _resolve_plan(tile_ids, rows, bc, bm)
-    ref_ops = _fused_ref_operands(rx, ry, rt, rvalid, rid, rows)
-    padT = (-T) % rows
-    gid_flat = jnp.pad(ref_gid.astype(jnp.int32), ((0, padT), (0, 0)),
-                       constant_values=n_src).reshape(-1)
-    cand_ops = _fused_cand_operands(cx, cy, ct, cvalid, cid, bm, mc_pad)
-    padC = (-C) % 32
-    cgid = jnp.pad(cand_gid.astype(jnp.int32), ((0, padC), (0, mc_pad)),
-                   constant_values=n_dst)
-
-    p0 = jnp.asarray(p0, jnp.int32)
-    lgid = jnp.where((gid_flat >= p0) & (gid_flat < p0 + panel),
-                     gid_flat - p0, panel)
-    clgid = jnp.where((cgid >= p0) & (cgid < p0 + panel), cgid - p0, panel)
-
-    if tile_ids is None:
-        return stjoin_sim_panel_fused_flat(
-            *ref_ops, gid_flat, lgid, *cand_ops, cgid, clgid, eps_sp,
-            eps_t, delta_t, rows=rows, M=M, n_src=n_src, n_dst=n_dst,
-            panel=panel, bc=bc, bm=bm, interpret=interpret)
-    return stjoin_sim_panel_fused_pruned_flat(
-        *ref_ops, gid_flat, lgid, *cand_ops, cgid, clgid, tile_ids,
-        eps_sp, eps_t, delta_t, rows=rows, M=M, n_src=n_src, n_dst=n_dst,
-        panel=panel, bc=bc, bm=bm, interpret=interpret)
 
 
 def stjoin_sim_panel_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
                            ref_sub_local, cand_sub_local, max_subs: int,
                            eps_sp, eps_t, delta_t=0.0, *, p0, panel: int,
                            tile_ids=None, rows: int | None = None,
-                           bc: int = 16, bm: int = 128,
+                           bc: int = 128, bm: int = 128,
                            interpret: bool | None = None):
-    """Batch-level panel-streamed fused pass 2 (cf. ``stjoin_sim_fused``).
+    """Panel-streamed fused pass 2: one ``Sb``-row panel of the raw
+    similarity scatter in both orientations.
 
-    Slot maps mirror ``similarity_matrix``; the returned orientations feed
-    ``repro.core.similarity.topk_stream``'s panel finalization.
+    Returns ``(fwd [panel, S_cand], rev [panel, S_ref])`` where
+    ``fwd[i, j] = raw[p0 + i, j]`` and ``rev[i, j] = raw[j, p0 + i]`` of
+    the accumulator ``stjoin_sim_fused`` would build — bit-equal cell
+    sums, panel rows only.  ``p0`` may be traced (the panel loop re-invokes
+    one trace); ``panel`` is static.  ``tile_ids`` (from
+    ``plan_fused_tiles`` with identical geometry) restricts the forward
+    sweep to the index-surviving tiles; identical output either way.
+    The slabs feed ``repro.core.similarity.topk_stream``'s panel
+    finalization.
     """
     T, M = ref.x.shape
-    C, Mc = cand.x.shape
-    n_src = T * max_subs
-    n_dst = C * max_subs
-    ref_gid = jnp.where(
-        ref_sub_local >= 0,
-        jnp.arange(T, dtype=jnp.int32)[:, None] * max_subs
-        + ref_sub_local, n_src)
-    cand_gid = jnp.where(
-        cand_sub_local >= 0,
-        jnp.arange(C, dtype=jnp.int32)[:, None] * max_subs
-        + cand_sub_local, n_dst)
-    return stjoin_sim_panel_fused_arrays(
-        ref.x, ref.y, ref.t, ref.valid, ref.traj_id, ref_gid,
-        cand.x, cand.y, cand.t, cand.valid, cand.traj_id, cand_gid,
-        n_src, n_dst, eps_sp, eps_t, delta_t, p0, panel=panel,
-        tile_ids=tile_ids, rows=rows, bc=bc, bm=bm, interpret=interpret)
+    C = cand.x.shape[0]
+    (ref_ops, rsub, cand_ops, csub), (rows, bc, bm) = _sim_operands(
+        ref.x, ref.y, ref.t, ref.valid, ref.traj_id, ref_sub_local,
+        cand.x, cand.y, cand.t, cand.valid, cand.traj_id, cand_sub_local,
+        rows, bc, bm)
+    fwd, rev = stjoin_sim_panel_flat(
+        ref_ops, rsub, cand_ops, csub, eps_sp, eps_t, delta_t, p0,
+        _resolve_plan(tile_ids, rows, bc, bm), rows=rows, M=M, ms=max_subs,
+        panel=panel, bc=bc, interpret=interpret)
+    return fwd[:, :C * max_subs], rev[:, :T * max_subs]
 
 
 def subtrajectory_join(ref: TrajectoryBatch, cand: TrajectoryBatch,
@@ -527,6 +449,4 @@ def subtrajectory_join(ref: TrajectoryBatch, cand: TrajectoryBatch,
         j = best_match_join_pruned(ref, cand, eps_sp, eps_t, **kw)
     else:
         j = best_match_join_kernel(ref, cand, eps_sp, eps_t, **kw)
-    dt = jnp.asarray(delta_t, jnp.float32)
-    return jax.lax.cond(
-        dt > 0.0, lambda jj: filter_delta_t(jj, ref.t, dt), lambda jj: jj, j)
+    return refine_join(j, ref.t, delta_t)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: best-match spatiotemporal join (DTJ's Join step).
+"""Pallas TPU kernels: best-match spatiotemporal join (DTJ's Join step).
 
 Contract
 --------
@@ -10,29 +10,51 @@ Given ``P`` reference points (flattened, with per-point trajectory ids) and
                      (1 - d_sp(p, (c,m)) / eps_sp)
                      subject to d_sp <= eps_sp, |dt| <= eps_t,
                      validity, and traj_id[p] != cand_id[c]
-    best_idx[p, c] = argmax m (or -1)
+    best_idx[p, c] = argmax m (first on ties), or -1 when nothing matches
 
-Tiling
+Layout
 ------
-grid = (P/bp, C/bc, Mc/bm); the (i, j) output tile [bp, bc] is revisited
-across the k (candidate-point) grid axis and accumulated with a running
-max/argmax in VMEM — the classic "contraction last axis" Pallas pattern.
+Every kernel works on a ``[bp, bc]`` tile with reference points on
+sublanes and candidate trajectories on lanes:
 
-Per-tile working set (defaults bp=256, bc=8, bm=128):
-    ref slabs        4 * bp * 4B               =   4 KiB
-    cand slabs       4 * bc * bm * 4B          =  16 KiB
-    pairwise temps   ~4 * bp * bc * bm * 4B    =   4 MiB
-    accumulators     2 * bp * bc * 4B          =  16 KiB
-well under the ~16 MiB v5e VMEM budget; bp/bm are multiples of the f32
-(8, 128) tile so the VPU operates on full registers.
+* reference operands are ``[P, 1]`` columns (block ``(bp, 1)``), broadcast
+  along lanes;
+* candidate operands are transposed to ``[Mc, C]`` (block ``(., bc)``), and
+  the sweep walks the candidate-point axis one row at a time: a dynamic
+  sublane-row load broadcast along sublanes.  The max/argmax contraction
+  is then an elementwise running carry on the VPU with a strict ``>``
+  update, which keeps the first maximum exactly like ``jnp.argmax``;
+* scalars (eps, the tile plan, reference sub-slots) live in SMEM.
 
-Distance is computed with a broadcast subtract on the VPU: the contraction
-depth is 2 (x, y), far too shallow for the MXU to pay off — this kernel is
-HBM-bandwidth- and VPU-bound by design, which is exactly why minimizing
-bytes (best-match streaming instead of materializing [P, C, Mc]) matters.
-A tile whose time range is provably farther than eps_t from the ref tile's
-range contributes nothing; time-sorted inputs make those tiles cheap
-(mask-all-zero), and the grid dimension ordering keeps the accumulator hot.
+Mosaic therefore needs ``bc`` to be a multiple of 128 or the whole padded
+candidate axis, and ``bp`` a multiple of 8; interpret mode takes any tile.
+The distance is a broadcast subtract on the VPU: the contraction depth is
+2 (x, y), far too shallow for the MXU, so the join is VPU-bound and the
+point of the fused epilogues is to avoid writing the ``[P, C]`` cube.
+
+Tile plans
+----------
+Every kernel runs over a (ref block i, slot s) grid.  Slot ``s`` names a
+candidate block through a scalar-prefetched code table
+(:func:`slot_codes`): the dense sweep is the plan that lists every block,
+and the index-pruned sweep (``repro.index.grid``) lists only the blocks
+that can hold a match.  Pruning is conservative, so both give the same
+result; a dead slot computes nothing.
+
+Kernels
+-------
+* ``stjoin_pallas``          the materializing join: ``(best_w, best_idx)
+                             [P, C]``; the grid's last axis walks ``bm``
+                             candidate-point chunks.
+* ``stjoin_vote_fused_flat`` fused pass 1: per-point vote sums (Eq. 4) and
+                             bit-packed neighbor words (TSA2, Alg. 3) with
+                             the delta_t run refine applied in-kernel.
+* ``stjoin_sim_fused_flat``  fused pass 2: the raw similarity scatter
+                             (Eq. 2), one ``[rows*ms, bc*ms]`` block per
+                             tile (a tile's matches can only land in the
+                             slots of its own rows and candidates).
+* ``stjoin_sim_panel_flat``  fused pass 2 restricted to one row panel, in
+                             both orientations (top-K engine, DESIGN §8).
 """
 from __future__ import annotations
 
@@ -41,885 +63,487 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret, shift_points
 
-def _kernel(ref_x, ref_y, ref_t, ref_id, ref_ok,
-            cand_x, cand_y, cand_t, cand_id, cand_ok,
-            eps, out_w, out_idx):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_w[...] = jnp.zeros_like(out_w)
-        out_idx[...] = jnp.full_like(out_idx, -1)
-
-    eps_sp = eps[0]
-    eps_t = eps[1]
-
-    rx = ref_x[...]                       # [bp]
-    ry = ref_y[...]
-    rt = ref_t[...]
-    rid = ref_id[...]
-    rok = ref_ok[...]
-
-    cx = cand_x[...]                      # [bc, bm]
-    cy = cand_y[...]
-    ct = cand_t[...]
-    cid = cand_id[...]                    # [bc]
-    cok = cand_ok[...]
-
-    bp = rx.shape[0]
-    bc, bm = cx.shape
-
-    dx = rx[:, None, None] - cx[None, :, :]          # [bp, bc, bm]
-    dy = ry[:, None, None] - cy[None, :, :]
-    dt = jnp.abs(rt[:, None, None] - ct[None, :, :])
-    d2 = dx * dx + dy * dy
-
-    ok = (d2 <= eps_sp * eps_sp) & (dt <= eps_t)
-    ok &= rok[:, None, None] & cok[None, :, :]
-    ok &= rid[:, None, None] != cid[None, :, None]
-
-    w = jnp.where(ok, 1.0 - jnp.sqrt(d2) / eps_sp, -1.0)  # [bp, bc, bm]
-
-    tile_w = jnp.max(w, axis=-1)                          # [bp, bc]
-    tile_arg = jnp.argmax(w, axis=-1).astype(jnp.int32)   # [bp, bc]
-    tile_idx = jnp.where(tile_w > 0.0, tile_arg + k * bm, -1)
-    tile_w = jnp.maximum(tile_w, 0.0)
-
-    run_w = out_w[...]
-    run_idx = out_idx[...]
-    better = tile_w > run_w
-    out_w[...] = jnp.where(better, tile_w, run_w)
-    out_idx[...] = jnp.where(better, tile_idx, run_idx)
-
-
-def _pruned_kernel(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                   cand_x, cand_y, cand_t, cand_id, cand_ok,
-                   eps, out_w, out_idx):
-    """Same contraction as ``_kernel`` but over gathered candidate tiles.
-
-    Grid is (ref block i, surviving-tile slot s, cand-point chunk k); the
-    candidate operands were pre-gathered to ``[nRb, K, bc, Mc]`` so the
-    block index map stays static.  The k-axis accumulation is identical to
-    the dense kernel's, which keeps surviving tiles bit-identical.
-    """
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_w[...] = jnp.zeros_like(out_w)
-        out_idx[...] = jnp.full_like(out_idx, -1)
-
-    eps_sp = eps[0]
-    eps_t = eps[1]
-
-    rx = ref_x[...]                       # [bp]
-    ry = ref_y[...]
-    rt = ref_t[...]
-    rid = ref_id[...]
-    rok = ref_ok[...]
-
-    cx = cand_x[0, 0]                     # [bc, bm]
-    cy = cand_y[0, 0]
-    ct = cand_t[0, 0]
-    cid = cand_id[0, 0]                   # [bc]
-    cok = cand_ok[0, 0]
-
-    bm = cx.shape[-1]
-
-    dx = rx[:, None, None] - cx[None, :, :]          # [bp, bc, bm]
-    dy = ry[:, None, None] - cy[None, :, :]
-    dt = jnp.abs(rt[:, None, None] - ct[None, :, :])
-    d2 = dx * dx + dy * dy
-
-    ok = (d2 <= eps_sp * eps_sp) & (dt <= eps_t)
-    ok &= rok[:, None, None] & cok[None, :, :]
-    ok &= rid[:, None, None] != cid[None, :, None]
-
-    w = jnp.where(ok, 1.0 - jnp.sqrt(d2) / eps_sp, -1.0)  # [bp, bc, bm]
-
-    tile_w = jnp.max(w, axis=-1)                          # [bp, bc]
-    tile_arg = jnp.argmax(w, axis=-1).astype(jnp.int32)
-    tile_idx = jnp.where(tile_w > 0.0, tile_arg + k * bm, -1)
-    tile_w = jnp.maximum(tile_w, 0.0)
-
-    run_w = out_w[0, 0]
-    run_idx = out_idx[0, 0]
-    better = tile_w > run_w
-    out_w[0, 0] = jnp.where(better, tile_w, run_w)
-    out_idx[0, 0] = jnp.where(better, tile_idx, run_idx)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+_ANY = pl.BlockSpec(memory_space=pl.ANY)
+_BIG = 3.4e38
 
 
 # ---------------------------------------------------------------------------
-# Fused epilogue variants (streaming join): the dense [P, C] best-match cube
-# never leaves VMEM.  The flash-attention idiom (kernels/attention/flash.py)
-# applied to the join: one program instance owns a (ref block, cand block)
-# tile, scans the candidate points in ``bm`` slabs with a running max/argmax
-# carry, and — instead of writing the [bp, bc] tile to HBM — folds it into
-# the consumers' accumulators in-kernel:
-#
-#   pass 1 (``_vote_kernel``)  per-point vote sums [P] (Eq. 4) + bit-packed
-#                              neighbor words [P, C/32] (TSA2, Alg. 3), with
-#                              the delta_t run refine applied in-kernel.
-#   pass 2 (``_sim_kernel``)   scatter-add of refined best-match weights into
-#                              the [S+1, S+1] similarity accumulator (Eq. 2),
-#                              re-sweeping the same tiles (recompute instead
-#                              of a second HBM read of the cube).
-#
-# HBM traffic drops from O(T*M*C) (f32 + i32 cubes, written once and re-read
-# once per consumer) to O(T*M + T*M*C/32 + S^2) accumulator bytes.
+# Tile plans
 # ---------------------------------------------------------------------------
 
 
-def _sweep_best(rx, ry, rt, rid, rok, cx, cy, ct, cid, cok,
-                eps_sp, eps_t, bm: int, with_idx: bool):
-    """Running best-match over candidate-point slabs, VMEM-resident.
+def slot_codes(tile_ids):
+    """``[nRb, K]`` candidate block ids (-1 padded, ascending) -> codes.
 
-    ``cx``/``cy``/``ct``/``cok``: [bc, Mc] block values; scanned in ``bm``
-    chunks with a (max, argmax) carry — the same contraction as the
-    materializing kernels' k grid axis, but kept entirely in registers.
-    Returns ``w [bp, bc]`` (and ``idx`` when ``with_idx``), where ties keep
-    the lowest candidate-point index (argmax-first, bit-identical to the
-    dense kernel's chunked accumulation).
+    ``code >= 0``: a live slot that sweeps candidate block ``code``.
+    ``code < 0``: a dead slot that computes nothing; ``-code - 1`` is the
+    block its index maps name.  That is the row's last live block, so the
+    pipeline sees a revisit and writes the block back unchanged, or block
+    0 of a row with no survivor, which slot 0 fills with the empty value.
     """
-    bp = rx.shape[0]
-    bc, Mc = cx.shape
+    live = tile_ids >= 0
+    last = jax.lax.cummax(jnp.where(live, tile_ids, -1), axis=1)
+    return jnp.where(live, tile_ids, -jnp.maximum(last, 0) - 1).astype(
+        jnp.int32)
 
-    def chunk(k, carry):
-        cxk = jax.lax.dynamic_slice_in_dim(cx, k * bm, bm, axis=1)
-        cyk = jax.lax.dynamic_slice_in_dim(cy, k * bm, bm, axis=1)
-        ctk = jax.lax.dynamic_slice_in_dim(ct, k * bm, bm, axis=1)
-        cokk = jax.lax.dynamic_slice_in_dim(cok, k * bm, bm, axis=1)
 
-        dx = rx[:, None, None] - cxk[None, :, :]          # [bp, bc, bm]
-        dy = ry[:, None, None] - cyk[None, :, :]
-        dt = jnp.abs(rt[:, None, None] - ctk[None, :, :])
+def dense_codes(n_ref_blocks: int, n_cand_blocks: int, first: int = 0):
+    """The plan that sweeps candidate blocks ``first ..`` for every row."""
+    return jnp.broadcast_to(
+        first + jnp.arange(n_cand_blocks, dtype=jnp.int32),
+        (n_ref_blocks, n_cand_blocks))
+
+
+def _block_of(code):
+    return jnp.where(code >= 0, code, -code - 1)
+
+
+def _eps(*vals):
+    return jnp.stack([jnp.asarray(v, jnp.float32) for v in vals])
+
+
+def _col(a, dtype):
+    return a.astype(dtype).reshape(-1, 1)
+
+
+# ---------------------------------------------------------------------------
+# Tile bodies shared by every kernel
+# ---------------------------------------------------------------------------
+
+
+def _sweep(ref, cand, cid, eps_sp, eps_t, carry, *, n, base=0, csub=None):
+    """Running best match of the tile over candidate-point rows ``[0, n)``.
+
+    ``ref``: ``(x, y, t, id, ok)`` ``[bp, 1]`` values; ``cand``: ``(x, y,
+    t, ok)`` refs of shape ``[n, bc]``; ``cid``: ``[1, bc]``.  ``carry``
+    is ``(w,)`` or ``(w, follow)``; only a strictly larger positive weight
+    replaces the running best, so ties keep the lowest point index and a
+    pair with no match keeps its initial value.  ``follow`` moves with the
+    best match: the point index ``base + m``, or, given ``csub`` (an
+    ``[n, bc]`` ref), the candidate point's sub-slot, which spares pass 2
+    a gather.
+    """
+    rx, ry, rt, rid, rok = ref
+    cx, cy, ct, cok = cand
+    eps2 = eps_sp * eps_sp
+    pair_ok = (rok != 0) & (rid != cid)                     # [bp, bc]
+
+    def body(m, c):
+        row = pl.ds(m, 1)
+        dx = rx - cx[row, :]
+        dy = ry - cy[row, :]
         d2 = dx * dx + dy * dy
+        ok = (pair_ok & (cok[row, :] != 0) & (d2 <= eps2)
+              & (jnp.abs(rt - ct[row, :]) <= eps_t))
+        w = 1.0 - jnp.sqrt(d2) / eps_sp
+        better = ok & (w > c[0])
+        follow = base + m if csub is None else csub[row, :]
+        return (jnp.where(better, w, c[0]),) + tuple(
+            jnp.where(better, follow, x) for x in c[1:])
 
-        ok = (d2 <= eps_sp * eps_sp) & (dt <= eps_t)
-        ok &= rok[:, None, None] & cokk[None, :, :]
-        ok &= rid[:, None, None] != cid[None, :, None]
+    return jax.lax.fori_loop(0, n, body, carry)
 
-        w = jnp.where(ok, 1.0 - jnp.sqrt(d2) / eps_sp, -1.0)
 
-        tile_w = jnp.max(w, axis=-1)                      # [bp, bc]
-        if with_idx:
-            run_w, run_idx = carry
-            tile_arg = jnp.argmax(w, axis=-1).astype(jnp.int32)
-            tile_idx = jnp.where(tile_w > 0.0, tile_arg + k * bm, -1)
-            tile_w = jnp.maximum(tile_w, 0.0)
-            better = tile_w > run_w
-            return (jnp.where(better, tile_w, run_w),
-                    jnp.where(better, tile_idx, run_idx))
-        return (jnp.maximum(jnp.maximum(tile_w, 0.0), carry[0]),)
-
-    init = (jnp.zeros((bp, bc), jnp.float32),)
-    if with_idx:
-        init = init + (jnp.full((bp, bc), -1, jnp.int32),)
-    out = jax.lax.fori_loop(0, Mc // bm, chunk, init)
-    return out if with_idx else out[0]
+def _refs(*refs):
+    return tuple(r[...] for r in refs)
 
 
 def _run_refine(w, rt, rows: int, M: int, delta_t):
     """In-kernel DTJ Refine (delta_t): zero matches in short runs.
 
-    ``w``: [bp, bc] best weights for ``rows`` whole trajectory rows of ``M``
-    points each (``bp == rows * M`` — the fused wrappers enforce row-aligned
-    ref blocks precisely so runs never cross a block boundary).  A run is a
-    maximal streak of consecutive matched ref points for one candidate; it
-    survives iff its time extent ``t[last] - t[first] >= delta_t``.  Because
-    ``t`` is ascending within a row, each point's run boundaries are the
-    latest start at-or-before it (forward cummax of start times) and the
-    earliest end at-or-after it (reverse cummin of end times) — no gather or
-    scatter, so the whole refine stays in VMEM.  Matches
-    ``repro.core.geometry.filter_delta_t`` exactly (delta_t == 0 is the
-    identity on matched points: every run has extent >= 0).
+    ``w``: ``[bp, bc]`` best weights for ``rows`` whole trajectory rows of
+    ``M`` points (``bp == rows * M``; the fused wrappers keep ref blocks
+    row-aligned so runs never cross a block).  A run is a maximal streak
+    of consecutive matched ref points for one candidate; it survives iff
+    ``t[last] - t[first] >= delta_t``.  With ``t`` ascending in a row, a
+    point's run starts at the latest run start at or before it and ends
+    at the earliest run end at or after it: a prefix max and a suffix min,
+    done as log-step doubling scans (exact for max and min).  Matches
+    ``repro.core.geometry.filter_delta_t``; ``delta_t == 0`` keeps every
+    match.
     """
     bp, bc = w.shape
     m = w.reshape(rows, M, bc)
-    matched = m > 0.0
-    prev = jnp.pad(matched, ((0, 0), (1, 0), (0, 0)))[:, :M]
-    nxt = jnp.pad(matched, ((0, 0), (0, 1), (0, 0)))[:, 1:]
-    t3 = jnp.broadcast_to(rt.reshape(rows, M)[:, :, None], (rows, M, bc))
-    big = jnp.float32(3.4e38)
-    start_t = jax.lax.cummax(
-        jnp.where(matched & ~prev, t3, -big), axis=1)
-    end_t = jax.lax.cummin(
-        jnp.where(matched & ~nxt, t3, big), axis=1, reverse=True)
-    keep = matched & ((end_t - start_t) >= delta_t)
+    matched = (m > 0.0).astype(jnp.int32)
+    prev = shift_points(matched, 1, 0)
+    nxt = shift_points(matched, -1, 0)
+    t3 = jnp.broadcast_to(rt.reshape(rows, M, 1), (rows, M, bc))
+    start = jnp.where((matched > prev), t3, -_BIG)
+    end = jnp.where((matched > nxt), t3, _BIG)
+    k = 1
+    while k < M:
+        start = jnp.maximum(start, shift_points(start, k, -_BIG))
+        end = jnp.minimum(end, shift_points(end, -k, _BIG))
+        k *= 2
+    keep = (matched > 0) & ((end - start) >= delta_t)
     return jnp.where(keep, m, 0.0).reshape(bp, bc)
 
 
-def _vote_word_epilogue(w, shift_base, bc: int, out_vote, out_word, first_j,
-                        first_word):
-    """Fold a refined [bp, bc] tile into the vote / packed-word accumulators.
+def _pack_words(w, j, out_words):
+    """OR the tile's match bits into the packed-word accumulator.
 
-    ``shift_base``: bit offset of this candidate block inside its uint32
-    word (``(j * bc) % 32``; ``bc`` divides 32, so a block never straddles a
-    word boundary).  Bits of distinct blocks are disjoint, so ``+=`` is OR.
+    Candidate ``g = j * bc + lane`` is bit ``g % 32`` of word ``g // 32``;
+    a block touches ``max(1, bc // 32)`` words (``bc`` divides 32 or is a
+    multiple of it).  Bits are disjoint, so an int32 lane sum is an OR.
     """
-    @pl.when(first_j)
-    def _init_vote():
-        out_vote[...] = jnp.zeros_like(out_vote)
-
-    @pl.when(first_word)
-    def _init_word():
-        out_word[...] = jnp.zeros_like(out_word)
-
-    out_vote[...] += jnp.sum(w, axis=1)
-    weights = (jnp.uint32(1)
-               << (shift_base.astype(jnp.uint32)
-                   + jnp.arange(bc, dtype=jnp.uint32)))
-    bits = (w > 0.0).astype(jnp.uint32)
-    out_word[...] += jnp.sum(bits * weights[None, :], axis=1,
-                             keepdims=True)
+    bc = w.shape[1]
+    g = j * bc + jax.lax.broadcasted_iota(jnp.int32, (1, bc), 1)
+    bits = jnp.where(w > 0.0, jnp.left_shift(1, g & 31), 0)
+    lo = (j * bc) // 32
+    word = jax.lax.broadcasted_iota(jnp.int32, (1, out_words.shape[1]), 1)
+    acc = out_words[...]
+    for q in range(max(1, bc // 32)):
+        val = jnp.sum(jnp.where((g >> 5) == lo + q, bits, 0), axis=1,
+                      keepdims=True)
+        acc = acc + jnp.where(word == lo + q, val, 0)
+    out_words[...] = acc
 
 
-def _vote_kernel(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                 cand_x, cand_y, cand_t, cand_id, cand_ok,
-                 eps, out_vote, *outs, rows: int, M: int, bc: int,
-                 bm: int):
-    """Dense pass 1; ``outs`` holds the packed-word ref only when the
-    caller needs TSA2 neighbor sets (vote-only otherwise)."""
-    j = pl.program_id(1)
-    w = _sweep_best(ref_x[...], ref_y[...], ref_t[...], ref_id[...],
-                    ref_ok[...], cand_x[...], cand_y[...], cand_t[...],
-                    cand_id[...], cand_ok[...], eps[0], eps[1], bm, False)
-    w = _run_refine(w, ref_t[...], rows, M, eps[2])
-    if outs:
-        _vote_word_epilogue(w, (j * bc) % 32, bc, out_vote, outs[0],
-                            j == 0, (j * bc) % 32 == 0)
-    else:
-        @pl.when(j == 0)
-        def _init_vote():
-            out_vote[...] = jnp.zeros_like(out_vote)
-
-        out_vote[...] += jnp.sum(w, axis=1)
+# ---------------------------------------------------------------------------
+# Materializing join
+# ---------------------------------------------------------------------------
 
 
-def _vote_kernel_pruned(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                        cand_x, cand_y, cand_t, cand_id, cand_ok,
-                        tile_id, eps, out_vote, *outs, rows: int,
-                        M: int, bc: int, bm: int):
-    """Pruned-grid pass 1: grid (ref block i, surviving-tile slot s).
+def _join_kernel(codes, rx, ry, rt, rid, rok, cx, cy, ct, cid, cok, eps,
+                 w0, i0, out_w, out_idx):
+    del w0, i0                    # aliased empty outputs (unvisited blocks)
+    i, s, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    live = codes[i, s] >= 0
 
-    The candidate operands were gathered to ``[nRb, K, bc, Mc]`` (same
-    layout as ``stjoin_pallas_pruned``); dead slots carry ``cand_ok ==
-    False`` everywhere, so they contribute no votes and no bits.  The packed
-    word cannot be routed by an index map (the word column depends on the
-    *value* of ``tile_id``), so each slot emits its [bp] word contribution
-    at (i, s) and the wrapper scatter-adds it into the [nRb, bp, W] layout
-    (disjoint bit ranges -> add == OR).  ``outs`` is empty on the
-    vote-only (TSA1) path.
-    """
-    s = pl.program_id(1)
-    w = _sweep_best(ref_x[...], ref_y[...], ref_t[...], ref_id[...],
-                    ref_ok[...], cand_x[0, 0], cand_y[0, 0], cand_t[0, 0],
-                    cand_id[0, 0], cand_ok[0, 0], eps[0], eps[1], bm, False)
-    w = _run_refine(w, ref_t[...], rows, M, eps[2])
-
-    @pl.when(s == 0)
-    def _init_vote():
-        out_vote[...] = jnp.zeros_like(out_vote)
-
-    out_vote[...] += jnp.sum(w, axis=1)
-    if outs:
-        jt = jnp.maximum(tile_id[0, 0], 0)
-        weights = (jnp.uint32(1)
-                   << (((jt * bc) % 32).astype(jnp.uint32)
-                       + jnp.arange(bc, dtype=jnp.uint32)))
-        bits = (w > 0.0).astype(jnp.uint32)
-        outs[0][0, 0] = jnp.sum(bits * weights[None, :], axis=1)
-
-
-def _sim_epilogue(w, idx, ref_gid, cand_gid, out_sim, first):
-    """Scatter a refined tile into the [Sr+1, Sc+1] similarity accumulator.
-
-    Mirrors ``repro.core.similarity.similarity_matrix``: the destination is
-    the candidate *point*'s subtrajectory slot (gathered from ``cand_gid``
-    at the best-match index); unmatched / unsegmented entries go to the
-    sentinel row/column and are sliced off by the wrapper.  Weights are
-    already delta_t-refined, so a dropped match adds exactly 0.
-    """
-    bc, Mc = cand_gid.shape
-    sent_c = out_sim.shape[1] - 1
-
-    @pl.when(first)
+    @pl.when((k == 0) & (live | (s == 0)))
     def _init():
-        out_sim[...] = jnp.zeros_like(out_sim)
-
-    dstg = cand_gid[jnp.arange(bc)[None, :], jnp.clip(idx, 0, Mc - 1)]
-    dst = jnp.where((w > 0.0) & (idx >= 0), dstg, sent_c)    # [bp, bc]
-    src = jnp.broadcast_to(ref_gid[:, None], w.shape)
-    out_sim[...] = out_sim[...].at[src, dst].add(w)
-
-
-def _sim_kernel(ref_x, ref_y, ref_t, ref_id, ref_ok, ref_gid,
-                cand_x, cand_y, cand_t, cand_id, cand_ok, cand_gid,
-                eps, out_sim, *, rows: int, M: int, bc: int, bm: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    w, idx = _sweep_best(ref_x[...], ref_y[...], ref_t[...], ref_id[...],
-                         ref_ok[...], cand_x[...], cand_y[...], cand_t[...],
-                         cand_id[...], cand_ok[...], eps[0], eps[1], bm,
-                         True)
-    w = _run_refine(w, ref_t[...], rows, M, eps[2])
-    _sim_epilogue(w, idx, ref_gid[...], cand_gid[...], out_sim,
-                  (i == 0) & (j == 0))
-
-
-def _sim_kernel_pruned(ref_x, ref_y, ref_t, ref_id, ref_ok, ref_gid,
-                       cand_x, cand_y, cand_t, cand_id, cand_ok, cand_gid,
-                       eps, out_sim, *, rows: int, M: int, bc: int, bm: int):
-    i = pl.program_id(0)
-    s = pl.program_id(1)
-    w, idx = _sweep_best(ref_x[...], ref_y[...], ref_t[...], ref_id[...],
-                         ref_ok[...], cand_x[0, 0], cand_y[0, 0],
-                         cand_t[0, 0], cand_id[0, 0], cand_ok[0, 0],
-                         eps[0], eps[1], bm, True)
-    w = _run_refine(w, ref_t[...], rows, M, eps[2])
-    _sim_epilogue(w, idx, ref_gid[...], cand_gid[0, 0], out_sim,
-                  (i == 0) & (s == 0))
-
-
-def _sim_panel_epilogue(w, idx, ref_gid, ref_lgid, cand_gid, cand_lgid,
-                        out_fwd, out_rev, first):
-    """Scatter a refined tile into one row panel — in BOTH orientations.
-
-    The top-K streaming engine (DESIGN.md §8) consumes the similarity
-    matrix one ``Sb``-row panel at a time and needs each panel's rows of
-    ``raw`` *and* of ``raw.T`` so the max-symmetrization stays exact
-    without ever holding ``[S, S]``:
-
-        fwd[src - p0, dst] += w      (the panel's rows of ``raw``)
-        rev[dst - p0, src] += w      (the panel's rows of ``raw.T``)
-
-    ``ref_lgid`` / ``cand_lgid`` are the panel-localized slot maps
-    (sentinel ``Sb`` outside the panel, computed by the wrapper), so both
-    scatters hit a ``[Sb + 1, S + 1]`` accumulator.  Contributions arrive
-    in the same tile order as ``_sim_epilogue``'s dense scatter, keeping
-    per-cell sums bit-equal to the dense raw matrix's.
-    """
-    bc, Mc = cand_gid.shape
-    sent_c = out_fwd.shape[1] - 1
-    sent_r = out_fwd.shape[0] - 1
-
-    @pl.when(first)
-    def _init():
-        out_fwd[...] = jnp.zeros_like(out_fwd)
-        out_rev[...] = jnp.zeros_like(out_rev)
-
-    cols = jnp.arange(bc)[None, :]
-    safe = jnp.clip(idx, 0, Mc - 1)
-    ok = (w > 0.0) & (idx >= 0)
-    dst = jnp.where(ok, cand_gid[cols, safe], sent_c)        # [bp, bc]
-    dst_l = jnp.where(ok, cand_lgid[cols, safe], sent_r)
-    src = jnp.broadcast_to(ref_gid[:, None], w.shape)
-    src_l = jnp.broadcast_to(ref_lgid[:, None], w.shape)
-    out_fwd[...] = out_fwd[...].at[src_l, dst].add(w)
-    out_rev[...] = out_rev[...].at[dst_l, src].add(w)
-
-
-def _sim_panel_kernel(ref_x, ref_y, ref_t, ref_id, ref_ok, ref_gid, ref_lgid,
-                      cand_x, cand_y, cand_t, cand_id, cand_ok, cand_gid,
-                      cand_lgid, eps, out_fwd, out_rev, *, rows: int, M: int,
-                      bc: int, bm: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    w, idx = _sweep_best(ref_x[...], ref_y[...], ref_t[...], ref_id[...],
-                         ref_ok[...], cand_x[...], cand_y[...], cand_t[...],
-                         cand_id[...], cand_ok[...], eps[0], eps[1], bm,
-                         True)
-    w = _run_refine(w, ref_t[...], rows, M, eps[2])
-    _sim_panel_epilogue(w, idx, ref_gid[...], ref_lgid[...], cand_gid[...],
-                        cand_lgid[...], out_fwd, out_rev,
-                        (i == 0) & (j == 0))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows", "M", "bc", "bm", "n_src", "n_dst", "panel",
-                     "interpret"))
-def stjoin_sim_panel_fused_flat(ref_x, ref_y, ref_t, ref_id, ref_ok, ref_gid,
-                                ref_lgid, cand_x, cand_y, cand_t, cand_id,
-                                cand_ok, cand_gid, cand_lgid, eps_sp, eps_t,
-                                delta_t, *, rows: int, M: int, n_src: int,
-                                n_dst: int, panel: int, bc: int = 8,
-                                bm: int = 128, interpret: bool = True):
-    """Fused pass 2, panel-streamed: ``(fwd [Sb, n_dst], rev [Sb, n_src])``.
-
-    Identical tile sweep to ``stjoin_sim_fused_flat`` (same recompute of
-    the best-match contraction after segmentation), but the epilogue
-    accumulates only one ``Sb``-row panel of the similarity scatter — in
-    both orientations — so the whole call's output is O(Sb * S) instead of
-    O(S^2).  ``ref_lgid`` / ``cand_lgid`` hold the panel-localized slot of
-    each point (``panel`` = Sb sentinel for out-of-panel slots); the
-    caller sweeps panels by re-invoking with shifted localizations (a
-    traced offset — one trace covers every panel).
-    """
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    bp = rows * M
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-
-    eps = _fused_eps(eps_sp, eps_t, delta_t)
-    grid = (P // bp, C // bc)
-    ref_spec = pl.BlockSpec((bp,), lambda i, j: (i,))
-    cand_spec = pl.BlockSpec((bc, Mc), lambda i, j: (j, 0))
-    cid_spec = pl.BlockSpec((bc,), lambda i, j: (j,))
-    eps_spec = pl.BlockSpec((3,), lambda i, j: (0,))
-    fwd_spec = pl.BlockSpec((panel + 1, n_dst + 1), lambda i, j: (0, 0))
-    rev_spec = pl.BlockSpec((panel + 1, n_src + 1), lambda i, j: (0, 0))
-
-    fwd, rev = pl.pallas_call(
-        functools.partial(_sim_panel_kernel, rows=rows, M=M, bc=bc, bm=bm),
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [ref_spec] * 2 + [cand_spec] * 3
-        + [cid_spec, cand_spec, cand_spec, cand_spec, eps_spec],
-        out_specs=[fwd_spec, rev_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((panel + 1, n_dst + 1), jnp.float32),
-            jax.ShapeDtypeStruct((panel + 1, n_src + 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), ref_gid.astype(jnp.int32),
-      ref_lgid.astype(jnp.int32), cand_x, cand_y, cand_t,
-      cand_id.astype(jnp.int32), cand_ok.astype(jnp.bool_),
-      cand_gid.astype(jnp.int32), cand_lgid.astype(jnp.int32), eps)
-    return fwd[:panel, :n_dst], rev[:panel, :n_src]
-
-
-def _sim_panel_kernel_pruned(ref_x, ref_y, ref_t, ref_id, ref_ok, ref_gid,
-                             ref_lgid, cand_x, cand_y, cand_t, cand_id,
-                             cand_ok, cand_gid, cand_lgid, eps, out_fwd,
-                             out_rev, *, rows: int, M: int, bc: int,
-                             bm: int):
-    i = pl.program_id(0)
-    s = pl.program_id(1)
-    w, idx = _sweep_best(ref_x[...], ref_y[...], ref_t[...], ref_id[...],
-                         ref_ok[...], cand_x[0, 0], cand_y[0, 0],
-                         cand_t[0, 0], cand_id[0, 0], cand_ok[0, 0],
-                         eps[0], eps[1], bm, True)
-    w = _run_refine(w, ref_t[...], rows, M, eps[2])
-    _sim_panel_epilogue(w, idx, ref_gid[...], ref_lgid[...], cand_gid[0, 0],
-                        cand_lgid[0, 0], out_fwd, out_rev,
-                        (i == 0) & (s == 0))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows", "M", "bc", "bm", "n_src", "n_dst", "panel",
-                     "interpret"))
-def stjoin_sim_panel_fused_pruned_flat(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                                       ref_gid, ref_lgid, cand_x, cand_y,
-                                       cand_t, cand_id, cand_ok, cand_gid,
-                                       cand_lgid, tile_ids, eps_sp, eps_t,
-                                       delta_t, *, rows: int, M: int,
-                                       n_src: int, n_dst: int, panel: int,
-                                       bc: int = 8, bm: int = 128,
-                                       interpret: bool = True):
-    """Panel-streamed fused pass 2 over the index-pruned tile plan.
-
-    Same gather layout as ``stjoin_sim_fused_pruned_flat``; only the
-    plan's surviving tiles are swept per panel, yet the (fwd, rev) slabs
-    equal the dense panel sweep's (skipped tiles contribute exactly 0).
-    """
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    bp = rows * M
-    nRb = P // bp
-    nCb = C // bc
-    K = tile_ids.shape[1]
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-    assert tile_ids.shape[0] == nRb, (tile_ids.shape, nRb)
-
-    live = tile_ids >= 0
-    safe = jnp.clip(tile_ids, 0, nCb - 1)
-    gather = lambda a: a.reshape(nCb, bc, Mc)[safe]
-    gx, gy, gt = gather(cand_x), gather(cand_y), gather(cand_t)
-    gok = gather(cand_ok.astype(jnp.bool_)) & live[:, :, None, None]
-    gid = cand_id.astype(jnp.int32).reshape(nCb, bc)[safe]
-    ggid = gather(cand_gid.astype(jnp.int32))
-    glgid = gather(cand_lgid.astype(jnp.int32))
-
-    eps = _fused_eps(eps_sp, eps_t, delta_t)
-    grid = (nRb, K)
-    ref_spec = pl.BlockSpec((bp,), lambda i, s: (i,))
-    cand_spec = pl.BlockSpec((1, 1, bc, Mc), lambda i, s: (i, s, 0, 0))
-    cid_spec = pl.BlockSpec((1, 1, bc), lambda i, s: (i, s, 0))
-    eps_spec = pl.BlockSpec((3,), lambda i, s: (0,))
-    fwd_spec = pl.BlockSpec((panel + 1, n_dst + 1), lambda i, s: (0, 0))
-    rev_spec = pl.BlockSpec((panel + 1, n_src + 1), lambda i, s: (0, 0))
-
-    fwd, rev = pl.pallas_call(
-        functools.partial(_sim_panel_kernel_pruned, rows=rows, M=M, bc=bc,
-                          bm=bm),
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [ref_spec] * 2 + [cand_spec] * 3
-        + [cid_spec, cand_spec, cand_spec, cand_spec, eps_spec],
-        out_specs=[fwd_spec, rev_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((panel + 1, n_dst + 1), jnp.float32),
-            jax.ShapeDtypeStruct((panel + 1, n_src + 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), ref_gid.astype(jnp.int32),
-      ref_lgid.astype(jnp.int32), gx, gy, gt, gid, gok, ggid, glgid, eps)
-    return fwd[:panel, :n_dst], rev[:panel, :n_src]
-
-
-def _fused_eps(eps_sp, eps_t, delta_t):
-    return jnp.stack([jnp.asarray(eps_sp, jnp.float32),
-                      jnp.asarray(eps_t, jnp.float32),
-                      jnp.asarray(delta_t, jnp.float32)])
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows", "M", "bc", "bm", "with_words", "interpret"))
-def stjoin_vote_fused_flat(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                           cand_x, cand_y, cand_t, cand_id, cand_ok,
-                           eps_sp, eps_t, delta_t, *, rows: int, M: int,
-                           bc: int = 8, bm: int = 128,
-                           with_words: bool = True,
-                           interpret: bool = True):
-    """Fused pass 1 over the dense tile grid.
-
-    Ref points are flattened ``[P]`` with ``P = n_rows_total * M`` and block
-    size ``bp = rows * M`` (whole trajectory rows per block — required by
-    the in-kernel delta_t refine).  Returns ``(vote [P] f32,
-    words [P, C/32] uint32 | None)``; C must be a multiple of 32 and ``bc``
-    a divisor of 32 so every candidate block lands inside one uint32 word.
-    ``with_words=False`` (the TSA1 path) skips the packed-word accumulator
-    entirely — no bit packing, no extra output traffic.
-    """
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    bp = rows * M
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-    assert C % 32 == 0 and 32 % bc == 0, (C, bc)
-    W = C // 32
-
-    eps = _fused_eps(eps_sp, eps_t, delta_t)
-    grid = (P // bp, C // bc)
-    ref_spec = pl.BlockSpec((bp,), lambda i, j: (i,))
-    cand_spec = pl.BlockSpec((bc, Mc), lambda i, j: (j, 0))
-    cid_spec = pl.BlockSpec((bc,), lambda i, j: (j,))
-    eps_spec = pl.BlockSpec((3,), lambda i, j: (0,))
-
-    out_specs = [pl.BlockSpec((bp,), lambda i, j: (i,))]
-    out_shape = [jax.ShapeDtypeStruct((P,), jnp.float32)]
-    if with_words:
-        out_specs.append(
-            pl.BlockSpec((bp, 1), lambda i, j: (i, (j * bc) // 32)))
-        out_shape.append(jax.ShapeDtypeStruct((P, W), jnp.uint32))
-
-    out = pl.pallas_call(
-        functools.partial(_vote_kernel, rows=rows, M=M, bc=bc, bm=bm),
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [cand_spec] * 3 + [cid_spec, cand_spec,
-                                                     eps_spec],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), cand_x, cand_y, cand_t,
-      cand_id.astype(jnp.int32), cand_ok.astype(jnp.bool_), eps)
-    return (out[0], out[1]) if with_words else (out[0], None)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows", "M", "bc", "bm", "with_words", "interpret"))
-def stjoin_vote_fused_pruned_flat(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                                  cand_x, cand_y, cand_t, cand_id, cand_ok,
-                                  tile_ids, eps_sp, eps_t, delta_t, *,
-                                  rows: int, M: int, bc: int = 8,
-                                  bm: int = 128, with_words: bool = True,
-                                  interpret: bool = True):
-    """Fused pass 1 over the index-pruned tile plan (``tile_ids [nRb, K]``).
-
-    Same gather layout as ``stjoin_pallas_pruned``; only surviving tiles are
-    swept, yet the outputs are the full dense-equivalent accumulators
-    (pruning is conservative, so skipped tiles contribute exactly 0).
-    ``with_words=False`` skips the packed-word contributions and scatter.
-    """
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    bp = rows * M
-    nRb = P // bp
-    nCb = C // bc
-    K = tile_ids.shape[1]
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-    assert C % 32 == 0 and 32 % bc == 0, (C, bc)
-    assert tile_ids.shape[0] == nRb, (tile_ids.shape, nRb)
-    W = C // 32
-
-    live = tile_ids >= 0                                    # [nRb, K]
-    safe = jnp.clip(tile_ids, 0, nCb - 1)
-    gather = lambda a: a.reshape(nCb, bc, Mc)[safe]         # [nRb, K, bc, Mc]
-    gx, gy, gt = gather(cand_x), gather(cand_y), gather(cand_t)
-    gok = gather(cand_ok.astype(jnp.bool_)) & live[:, :, None, None]
-    gid = cand_id.astype(jnp.int32).reshape(nCb, bc)[safe]  # [nRb, K, bc]
-
-    eps = _fused_eps(eps_sp, eps_t, delta_t)
-    grid = (nRb, K)
-    ref_spec = pl.BlockSpec((bp,), lambda i, s: (i,))
-    cand_spec = pl.BlockSpec((1, 1, bc, Mc), lambda i, s: (i, s, 0, 0))
-    cid_spec = pl.BlockSpec((1, 1, bc), lambda i, s: (i, s, 0))
-    tid_spec = pl.BlockSpec((1, 1), lambda i, s: (i, s))
-    eps_spec = pl.BlockSpec((3,), lambda i, s: (0,))
-
-    out_specs = [pl.BlockSpec((bp,), lambda i, s: (i,))]
-    out_shape = [jax.ShapeDtypeStruct((P,), jnp.float32)]
-    if with_words:
-        out_specs.append(pl.BlockSpec((1, 1, bp), lambda i, s: (i, s, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((nRb, K, bp), jnp.uint32))
-
-    out = pl.pallas_call(
-        functools.partial(_vote_kernel_pruned, rows=rows, M=M, bc=bc, bm=bm),
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [cand_spec] * 3 + [cid_spec, cand_spec,
-                                                     tid_spec, eps_spec],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), gx, gy, gt, gid, gok,
-      tile_ids.astype(jnp.int32), eps)
-    if not with_words:
-        return out[0], None
-    vote, contrib = out
-
-    # host-side word scatter: slot s of ref block i carries the bits of
-    # candidate block tile_ids[i, s]; distinct slots of one word hold
-    # disjoint bit ranges, so scatter-add == OR.  Dead slots -> dummy col W.
-    word_col = jnp.where(live, (safe * bc) // 32, W)        # [nRb, K]
-    rows_ix = jnp.arange(nRb, dtype=jnp.int32)[:, None]
-    words = jnp.zeros((nRb, W + 1, bp), jnp.uint32)
-    words = words.at[rows_ix, word_col].add(contrib, mode="drop")
-    words = words[:, :W].transpose(0, 2, 1).reshape(P, W)
-    return vote, words
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows", "M", "bc", "bm", "n_src", "n_dst", "interpret"))
-def stjoin_sim_fused_flat(ref_x, ref_y, ref_t, ref_id, ref_ok, ref_gid,
-                          cand_x, cand_y, cand_t, cand_id, cand_ok, cand_gid,
-                          eps_sp, eps_t, delta_t, *, rows: int, M: int,
-                          n_src: int, n_dst: int, bc: int = 8, bm: int = 128,
-                          interpret: bool = True):
-    """Fused pass 2 (dense grid): raw similarity scatter ``[n_src, n_dst]``.
-
-    ``ref_gid [P]``: subtrajectory slot of each ref point (``n_src`` =
-    sentinel for unsegmented/padding).  ``cand_gid [C, Mc]``: slot of each
-    candidate *point* (``n_dst`` sentinel).  Returns the un-normalized
-    scatter of refined best-match weights — ``similarity_matrix``'s ``raw``
-    — with the sentinel row/column already sliced off.
-
-    Capacity note: the whole ``[n_src+1, n_dst+1]`` accumulator is one
-    revisited output block, so on real TPU (interpret=False) ``S`` is
-    capped by VMEM (~16 MiB -> S up to ~2000 slots f32).  Beyond that,
-    tile the accumulator columns and run one sweep per column block — the
-    distributed ``sim_strategy="allgather"`` path already has exactly that
-    shape (each model rank owns an ``[S, S/m]`` block); on one chip the
-    same column loop applies.  CPU interpret (the correctness path) has no
-    such cap.
-    """
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    bp = rows * M
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-
-    eps = _fused_eps(eps_sp, eps_t, delta_t)
-    grid = (P // bp, C // bc)
-    ref_spec = pl.BlockSpec((bp,), lambda i, j: (i,))
-    cand_spec = pl.BlockSpec((bc, Mc), lambda i, j: (j, 0))
-    cid_spec = pl.BlockSpec((bc,), lambda i, j: (j,))
-    eps_spec = pl.BlockSpec((3,), lambda i, j: (0,))
-
-    raw = pl.pallas_call(
-        functools.partial(_sim_kernel, rows=rows, M=M, bc=bc, bm=bm),
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [ref_spec] + [cand_spec] * 3
-        + [cid_spec, cand_spec, cand_spec, eps_spec],
-        out_specs=pl.BlockSpec((n_src + 1, n_dst + 1), lambda i, j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_src + 1, n_dst + 1), jnp.float32),
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), ref_gid.astype(jnp.int32),
-      cand_x, cand_y, cand_t, cand_id.astype(jnp.int32),
-      cand_ok.astype(jnp.bool_), cand_gid.astype(jnp.int32), eps)
-    return raw[:n_src, :n_dst]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows", "M", "bc", "bm", "n_src", "n_dst", "interpret"))
-def stjoin_sim_fused_pruned_flat(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                                 ref_gid, cand_x, cand_y, cand_t, cand_id,
-                                 cand_ok, cand_gid, tile_ids, eps_sp, eps_t,
-                                 delta_t, *, rows: int, M: int, n_src: int,
-                                 n_dst: int, bc: int = 8, bm: int = 128,
-                                 interpret: bool = True):
-    """Fused pass 2 over the index-pruned tile plan (same plan as pass 1)."""
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    bp = rows * M
-    nRb = P // bp
-    nCb = C // bc
-    K = tile_ids.shape[1]
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-    assert tile_ids.shape[0] == nRb, (tile_ids.shape, nRb)
-
-    live = tile_ids >= 0
-    safe = jnp.clip(tile_ids, 0, nCb - 1)
-    gather = lambda a: a.reshape(nCb, bc, Mc)[safe]
-    gx, gy, gt = gather(cand_x), gather(cand_y), gather(cand_t)
-    gok = gather(cand_ok.astype(jnp.bool_)) & live[:, :, None, None]
-    gid = cand_id.astype(jnp.int32).reshape(nCb, bc)[safe]
-    ggid = gather(cand_gid.astype(jnp.int32))
-
-    eps = _fused_eps(eps_sp, eps_t, delta_t)
-    grid = (nRb, K)
-    ref_spec = pl.BlockSpec((bp,), lambda i, s: (i,))
-    cand_spec = pl.BlockSpec((1, 1, bc, Mc), lambda i, s: (i, s, 0, 0))
-    cid_spec = pl.BlockSpec((1, 1, bc), lambda i, s: (i, s, 0))
-    eps_spec = pl.BlockSpec((3,), lambda i, s: (0,))
-
-    raw = pl.pallas_call(
-        functools.partial(_sim_kernel_pruned, rows=rows, M=M, bc=bc, bm=bm),
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [ref_spec] + [cand_spec] * 3
-        + [cid_spec, cand_spec, cand_spec, eps_spec],
-        out_specs=pl.BlockSpec((n_src + 1, n_dst + 1), lambda i, s: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_src + 1, n_dst + 1), jnp.float32),
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), ref_gid.astype(jnp.int32),
-      gx, gy, gt, gid, gok, ggid, eps)
-    return raw[:n_src, :n_dst]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("bp", "bc", "bm", "interpret"))
-def stjoin_pallas_pruned(ref_x, ref_y, ref_t, ref_id, ref_ok,
-                         cand_x, cand_y, cand_t, cand_id, cand_ok,
-                         tile_ids, eps_sp, eps_t, *, bp: int = 256,
-                         bc: int = 8, bm: int = 128,
-                         interpret: bool = True):
-    """Sparse-grid join: visit only the surviving (ref block, cand tile)
-    pairs named by ``tile_ids``.
-
-    ``tile_ids``: [nRb, K] int32 — per reference block, the candidate
-    j-block ids (``C // bc`` of them exist) whose bounding boxes intersect
-    the eps-expanded reference-block box, -1 padded, ascending.  Produced
-    by ``repro.index.grid.compact_candidates``.
-
-    Returns dense (best_w [P, C], best_idx [P, C]); entries of pruned
-    tiles are (0, -1) — exactly what the dense kernel yields for them,
-    because pruning is conservative.
-
-    Memory note: the gather materializes the surviving candidate tiles as
-    ``[nRb, K, bc, Mc]`` arrays (duplication factor ~nRb*K/nCb over the
-    raw candidate set), which keeps the block index maps static at the
-    cost of HBM footprint.  The TPU follow-up is a scalar-prefetch grid
-    (``tile_ids`` as a prefetch operand indexing the original [C, Mc]
-    arrays) that removes the duplication; on CPU interpret this is the
-    correctness-path layout.
-    """
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    nRb = P // bp
-    nCb = C // bc
-    K = tile_ids.shape[1]
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-    assert tile_ids.shape[0] == nRb, (tile_ids.shape, nRb)
-
-    live = tile_ids >= 0                                    # [nRb, K]
-    safe = jnp.clip(tile_ids, 0, nCb - 1)
-
-    # gather candidate j-blocks per reference block: [nRb, K, bc, Mc]
-    gather = lambda a: a.reshape(nCb, bc, Mc)[safe]
-    gx, gy, gt = gather(cand_x), gather(cand_y), gather(cand_t)
-    gok = gather(cand_ok.astype(jnp.bool_)) & live[:, :, None, None]
-    gid = cand_id.astype(jnp.int32).reshape(nCb, bc)[safe]  # [nRb, K, bc]
-
-    eps = jnp.stack([jnp.asarray(eps_sp, jnp.float32),
-                     jnp.asarray(eps_t, jnp.float32)])
-
-    grid = (nRb, K, Mc // bm)
-    ref_spec = pl.BlockSpec((bp,), lambda i, s, k: (i,))
-    cand_spec = pl.BlockSpec((1, 1, bc, bm), lambda i, s, k: (i, s, 0, k))
-    cid_spec = pl.BlockSpec((1, 1, bc), lambda i, s, k: (i, s, 0))
-    eps_spec = pl.BlockSpec((2,), lambda i, s, k: (0,))
-    out_spec = pl.BlockSpec((1, 1, bp, bc), lambda i, s, k: (i, s, 0, 0))
-
-    tw, tidx = pl.pallas_call(
-        _pruned_kernel,
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [cand_spec] * 3 + [cid_spec, cand_spec,
-                                                     eps_spec],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((nRb, K, bp, bc), jnp.float32),
-            jax.ShapeDtypeStruct((nRb, K, bp, bc), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), gx, gy, gt, gid, gok, eps)
-
-    # scatter surviving tiles back to the dense [P, C] layout; each (i, j)
-    # appears at most once in a row of tile_ids, so .set is exact.
-    col = jnp.where(live, safe, nCb)                        # dummy col nCb
-    rows = jnp.arange(nRb, dtype=jnp.int32)[:, None]
-    w = jnp.zeros((nRb, nCb + 1, bp, bc), jnp.float32)
-    idx = jnp.full((nRb, nCb + 1, bp, bc), -1, jnp.int32)
-    w = w.at[rows, col].set(tw, mode="drop")
-    idx = idx.at[rows, col].set(tidx, mode="drop")
-    w = w[:, :nCb].transpose(0, 2, 1, 3).reshape(P, C)
-    idx = idx[:, :nCb].transpose(0, 2, 1, 3).reshape(P, C)
-    return w, idx
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("bp", "bc", "bm", "interpret"))
+        out_w[...] = jnp.zeros_like(out_w)
+        out_idx[...] = jnp.full_like(out_idx, -1)
+
+    @pl.when(live)
+    def _chunk():
+        bm = cx.shape[0]
+        w, idx = _sweep(_refs(rx, ry, rt, rid, rok), (cx, cy, ct, cok),
+                        cid[...], eps[0], eps[1], (out_w[...], out_idx[...]),
+                        n=bm, base=k * bm)
+        out_w[...] = w
+        out_idx[...] = idx
+
+
+@functools.partial(jax.jit, static_argnames=("bp", "bc", "bm", "interpret"))
 def stjoin_pallas(ref_x, ref_y, ref_t, ref_id, ref_ok,
                   cand_x, cand_y, cand_t, cand_id, cand_ok,
-                  eps_sp, eps_t, *, bp: int = 256, bc: int = 8,
-                  bm: int = 128, interpret: bool = True):
-    """Returns (best_w[P, C] f32, best_idx[P, C] i32)."""
+                  eps_sp, eps_t, tile_ids=None, *, bp: int = 256,
+                  bc: int = 128, bm: int = 128,
+                  interpret: bool | None = None):
+    """Returns ``(best_w [P, C] f32, best_idx [P, C] i32)``.
+
+    ``P % bp == C % bc == Mc % bm == 0``.  ``tile_ids`` (``[P/bp, K]``,
+    from ``repro.index.grid.compact_candidates``) restricts the sweep to
+    the listed candidate blocks; the rest of the output keeps the empty
+    value ``(0, -1)``, which is what the dense sweep yields there because
+    pruning is conservative.
+    """
+    if interpret is None:
+        interpret = default_interpret()
     P = ref_x.shape[0]
     C, Mc = cand_x.shape
     assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
+    nRb, nCb = P // bp, C // bc
+    codes = (dense_codes(nRb, nCb) if tile_ids is None
+             else slot_codes(tile_ids))
+    assert codes.shape[0] == nRb, (codes.shape, nRb)
 
-    eps = jnp.stack([jnp.asarray(eps_sp, jnp.float32),
-                     jnp.asarray(eps_t, jnp.float32)])
-
-    grid = (P // bp, C // bc, Mc // bm)
-    ref_spec = pl.BlockSpec((bp,), lambda i, j, k: (i,))
-    cand_spec = pl.BlockSpec((bc, bm), lambda i, j, k: (j, k))
-    cid_spec = pl.BlockSpec((bc,), lambda i, j, k: (j,))
-    eps_spec = pl.BlockSpec((2,), lambda i, j, k: (0,))
-    out_spec = pl.BlockSpec((bp, bc), lambda i, j, k: (i, j))
-
+    ref_spec = pl.BlockSpec((bp, 1), lambda i, s, k, c: (i, 0))
+    cand_spec = pl.BlockSpec((bm, bc),
+                             lambda i, s, k, c: (k, _block_of(c[i, s])))
+    cid_spec = pl.BlockSpec((1, bc), lambda i, s, k, c: (0, _block_of(c[i, s])))
+    out_spec = pl.BlockSpec((bp, bc),
+                            lambda i, s, k, c: (i, _block_of(c[i, s])))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(nRb, codes.shape[1], Mc // bm),
+        in_specs=[ref_spec] * 5 + [cand_spec] * 3
+        + [cid_spec, cand_spec, _SMEM, _ANY, _ANY],
+        out_specs=[out_spec, out_spec])
+    f32, i32 = jnp.float32, jnp.int32
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[ref_spec] * 5 + [cand_spec] * 3 + [cid_spec, cand_spec,
-                                                     eps_spec],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((P, C), jnp.float32),
-            jax.ShapeDtypeStruct((P, C), jnp.int32),
-        ],
+        _join_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((P, C), f32),
+                   jax.ShapeDtypeStruct((P, C), i32)],
+        input_output_aliases={12: 0, 13: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(ref_x, ref_y, ref_t, ref_id.astype(jnp.int32),
-      ref_ok.astype(jnp.bool_), cand_x, cand_y, cand_t,
-      cand_id.astype(jnp.int32), cand_ok.astype(jnp.bool_), eps)
+    )(codes, _col(ref_x, f32), _col(ref_y, f32), _col(ref_t, f32),
+      _col(ref_id, i32), _col(ref_ok, i32), cand_x.astype(f32).T,
+      cand_y.astype(f32).T, cand_t.astype(f32).T,
+      cand_id.astype(i32).reshape(1, C), cand_ok.astype(i32).T,
+      _eps(eps_sp, eps_t), jnp.zeros((P, C), f32), jnp.full((P, C), -1, i32))
+
+
+# ---------------------------------------------------------------------------
+# Fused pass 1: votes + packed neighbor words
+# ---------------------------------------------------------------------------
+
+
+def _vote_kernel(codes, rx, ry, rt, rid, rok, cx, cy, ct, cid, cok, eps,
+                 out_vote, *outs, rows: int, M: int):
+    """``outs`` holds the packed-word accumulator only when the caller
+    needs TSA2 neighbor sets (vote-only otherwise)."""
+    i, s = pl.program_id(0), pl.program_id(1)
+    code = codes[i, s]
+
+    @pl.when(s == 0)
+    def _init():
+        out_vote[...] = jnp.zeros_like(out_vote)
+        for o in outs:
+            o[...] = jnp.zeros_like(o)
+
+    @pl.when(code >= 0)
+    def _tile():
+        bp, bc = rx.shape[0], cx.shape[1]
+        (w,) = _sweep(_refs(rx, ry, rt, rid, rok), (cx, cy, ct, cok),
+                      cid[...], eps[0], eps[1],
+                      (jnp.zeros((bp, bc), jnp.float32),), n=cx.shape[0])
+        w = _run_refine(w, rt[...], rows, M, eps[2])
+        out_vote[...] += jnp.sum(w, axis=1, keepdims=True)
+        if outs:
+            _pack_words(w, code, outs[0])
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rows", "M", "bc", "with_words", "interpret"))
+def stjoin_vote_fused_flat(ref_x, ref_y, ref_t, ref_id, ref_ok,
+                           cand_x, cand_y, cand_t, cand_id, cand_ok,
+                           eps_sp, eps_t, delta_t, tile_ids=None, *,
+                           rows: int, M: int, bc: int = 128,
+                           with_words: bool = True,
+                           interpret: bool | None = None):
+    """Fused pass 1: ``(vote [P] f32, words [P, C/32] uint32 | None)``.
+
+    Ref points are flattened ``[P]`` with ``P = n_rows_total * M`` and
+    block ``bp = rows * M`` (whole trajectory rows per block, as the
+    in-kernel delta_t refine needs).  ``C`` must be a multiple of 32 and
+    of ``bc``; ``bc`` divides 32 or is a multiple of it.
+    ``with_words=False`` (the TSA1 path) skips the packed words entirely.
+    ``tile_ids`` sweeps only the listed candidate blocks (same result).
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    P = ref_x.shape[0]
+    C, Mc = cand_x.shape
+    bp = rows * M
+    assert P % bp == 0 and C % bc == 0 and C % 32 == 0, (P, C, bp, bc)
+    assert 32 % bc == 0 or bc % 32 == 0, bc
+    nRb, W = P // bp, C // 32
+    codes = (dense_codes(nRb, C // bc) if tile_ids is None
+             else slot_codes(tile_ids))
+
+    ref_spec = pl.BlockSpec((bp, 1), lambda i, s, c: (i, 0))
+    cand_spec = pl.BlockSpec((Mc, bc), lambda i, s, c: (0, _block_of(c[i, s])))
+    cid_spec = pl.BlockSpec((1, bc), lambda i, s, c: (0, _block_of(c[i, s])))
+    out_specs = [pl.BlockSpec((bp, 1), lambda i, s, c: (i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((P, 1), jnp.float32)]
+    if with_words:
+        out_specs.append(pl.BlockSpec((bp, W), lambda i, s, c: (i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((P, W), jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(nRb, codes.shape[1]),
+        in_specs=[ref_spec] * 5 + [cand_spec] * 3
+        + [cid_spec, cand_spec, _SMEM],
+        out_specs=out_specs)
+    f32, i32 = jnp.float32, jnp.int32
+    out = pl.pallas_call(
+        functools.partial(_vote_kernel, rows=rows, M=M),
+        grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(codes, _col(ref_x, f32), _col(ref_y, f32), _col(ref_t, f32),
+      _col(ref_id, i32), _col(ref_ok, i32), cand_x.astype(f32).T,
+      cand_y.astype(f32).T, cand_t.astype(f32).T,
+      cand_id.astype(i32).reshape(1, C), cand_ok.astype(i32).T,
+      _eps(eps_sp, eps_t, delta_t))
+    vote = out[0].reshape(P)
+    if not with_words:
+        return vote, None
+    return vote, jax.lax.bitcast_convert_type(out[1], jnp.uint32)
+
+
+# ---------------------------------------------------------------------------
+# Fused pass 2: raw similarity scatter, one slot block per tile
+# ---------------------------------------------------------------------------
+
+
+def _sim_kernel(codes, base, rx, ry, rt, rid, rok, rsub, cx, cy, ct, cid,
+                cok, csub, eps, o0, out, w_scr, sub_scr, *, rows: int,
+                M: int):
+    """Tile (i, s) -> raw block ``out[r * ms + a, k, c]``: the summed
+    refined weight of ref row r's sub-slot ``a`` matched into candidate
+    c's sub-slot ``k``.  Each cell is summed over the row's points in
+    ascending order, the order of the materializing scatter-add."""
+    del o0                        # aliased zeros (unvisited blocks)
+    i, s = pl.program_id(0), pl.program_id(1)
+    live = codes[i, s] >= 0
+
+    @pl.when(live | (s == 0))
+    def _init():
+        out[...] = jnp.zeros_like(out)
+
+    @pl.when(live)
+    def _tile():
+        bp, bc = w_scr.shape
+        ms = out.shape[1]
+        w, sub = _sweep(
+            _refs(rx, ry, rt, rid, rok), (cx, cy, ct, cok), cid[...],
+            eps[0], eps[1], (jnp.zeros((bp, bc), jnp.float32),
+                             jnp.full((bp, bc), -1, jnp.int32)),
+            n=cx.shape[0], csub=csub)
+        w_scr[...] = _run_refine(w, rt[...], rows, M, eps[2])
+        sub_scr[...] = sub
+        k = jax.lax.broadcasted_iota(jnp.int32, (ms, 1), 0)
+
+        def row(r, carry):
+            def point(m, carry):
+                p = r * M + m
+                a = rsub[0, 0, p]
+
+                @pl.when(a >= 0)
+                def _add():
+                    hit = k == sub_scr[pl.ds(p, 1), :]           # [ms, bc]
+                    out[r * ms + a] += jnp.where(
+                        hit, w_scr[pl.ds(p, 1), :], 0.0)
+                return carry
+            return jax.lax.fori_loop(0, M, point, carry)
+
+        jax.lax.fori_loop(0, rows, row, 0)
+
+
+def _sim_call(ref_ops, rsub, cand_ops, csub, eps, codes, base, *, rows: int,
+              M: int, ms: int, bc: int, n_out_blocks: int,
+              interpret: bool):
+    """Raw blocks for the ref blocks ``base[0] + [0, codes.shape[0])`` and
+    the candidate blocks ``codes`` names; output column block
+    ``code - base[1]``.  Returns ``[nI * rows * ms, n_out_blocks * bc *
+    ms]`` with slot ``(c, k)`` at column ``c * ms + k``."""
+    P = ref_ops[0].shape[0]
+    C, Mc = cand_ops[0].shape
+    bp = rows * M
+    nI, K = codes.shape
+    R = rows * ms
+    f32, i32 = jnp.float32, jnp.int32
+
+    ref_spec = pl.BlockSpec((bp, 1), lambda i, s, c, b: (i + b[0], 0))
+    rsub_spec = pl.BlockSpec((1, 1, bp),
+                             lambda i, s, c, b: (i + b[0], 0, 0),
+                             memory_space=pltpu.SMEM)
+    cand_spec = pl.BlockSpec((Mc, bc),
+                             lambda i, s, c, b: (0, _block_of(c[i, s])))
+    cid_spec = pl.BlockSpec((1, bc), lambda i, s, c, b: (0, _block_of(c[i, s])))
+    out_spec = pl.BlockSpec(
+        (R, ms, bc), lambda i, s, c, b: (i, 0, _block_of(c[i, s]) - b[1]))
+    out_shape = (nI * R, ms, n_out_blocks * bc)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(nI, K),
+        in_specs=[ref_spec] * 5 + [rsub_spec] + [cand_spec] * 3
+        + [cid_spec, cand_spec, cand_spec, _SMEM, _ANY],
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((bp, bc), f32),
+                        pltpu.VMEM((bp, bc), i32)])
+    rx, ry, rt, rid, rok = ref_ops
+    cx, cy, ct, cid, cok = cand_ops
+    out = pl.pallas_call(
+        functools.partial(_sim_kernel, rows=rows, M=M),
+        grid_spec=grid_spec, out_shape=jax.ShapeDtypeStruct(out_shape, f32),
+        input_output_aliases={15: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(codes, base, _col(rx, f32), _col(ry, f32), _col(rt, f32),
+      _col(rid, i32), _col(rok, i32), rsub.astype(i32).reshape(-1, 1, bp),
+      cx.astype(f32).T, cy.astype(f32).T, ct.astype(f32).T,
+      cid.astype(i32).reshape(1, C), cok.astype(i32).T, csub.astype(i32).T,
+      eps, jnp.zeros(out_shape, f32))
+    return jnp.swapaxes(out, 1, 2).reshape(nI * R, -1)
+
+
+def _sim_plan(ref_ops, cand_ops, tile_ids, rows, M, bc):
+    """``(nRb, nCb, codes)`` for pass 2.  A plan built on pass 1's
+    words-padded candidate axis may list blocks past this one; those are
+    pure padding (no valid point, so no survivor), dropped all the same."""
+    P = ref_ops[0].shape[0]
+    C = cand_ops[0].shape[0]
+    assert P % (rows * M) == 0 and C % bc == 0, (P, C, rows, M, bc)
+    nRb, nCb = P // (rows * M), C // bc
+    if tile_ids is None:
+        return nRb, nCb, dense_codes(nRb, nCb)
+    return nRb, nCb, slot_codes(jnp.where(tile_ids < nCb, tile_ids, -1))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rows", "M", "ms", "bc", "interpret"))
+def stjoin_sim_fused_flat(ref_ops, ref_sub, cand_ops, cand_sub, eps_sp,
+                          eps_t, delta_t, tile_ids=None, *, rows: int,
+                          M: int, ms: int, bc: int = 128,
+                          interpret: bool | None = None):
+    """Fused pass 2: the raw similarity scatter ``[P/M * ms, C * ms]``.
+
+    ``ref_ops`` / ``cand_ops``: ``(x, y, t, id, ok)`` with ref arrays
+    ``[P]`` (whole rows of ``M``, ``P % (rows*M) == 0``) and candidate
+    arrays ``[C, Mc]`` (ids ``[C]``, ``C % bc == 0``).  ``ref_sub [P]`` /
+    ``cand_sub [C, Mc]``: each point's local sub-slot in ``[0, ms)``, or
+    -1 when unsegmented.  Row ``r * ms + a`` / column ``c * ms + k`` holds
+    the summed refined best-match weight from sub-slot ``a`` of ref row
+    ``r`` to sub-slot ``k`` of candidate ``c`` (``similarity_matrix``'s
+    ``raw``).  ``tile_ids`` sweeps only the listed candidate blocks.
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    _, nCb, codes = _sim_plan(ref_ops, cand_ops, tile_ids, rows, M, bc)
+    return _sim_call(ref_ops, ref_sub, cand_ops, cand_sub,
+                     _eps(eps_sp, eps_t, delta_t), codes,
+                     jnp.zeros((2,), jnp.int32), rows=rows, M=M, ms=ms,
+                     bc=bc, n_out_blocks=nCb, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rows", "M", "ms", "bc", "panel",
+                              "interpret"))
+def stjoin_sim_panel_flat(ref_ops, ref_sub, cand_ops, cand_sub, eps_sp,
+                          eps_t, delta_t, p0, tile_ids=None, *, rows: int,
+                          M: int, ms: int, panel: int, bc: int = 128,
+                          interpret: bool | None = None):
+    """One ``panel``-row slab of ``stjoin_sim_fused_flat``'s output in both
+    orientations: ``(fwd, rev)`` with ``fwd[q, :] = raw[p0 + q, :]`` and
+    ``rev[q, :] = raw[:, p0 + q]``.
+
+    ``fwd`` sweeps only the ref blocks that hold the panel's rows (through
+    ``tile_ids`` when given); ``rev`` sweeps every ref block against the
+    candidate blocks that hold the panel's columns.  Each raw cell is
+    computed by one tile in one fixed order, so both slabs equal the full
+    sweep's rows and columns bit for bit.  ``p0`` may be traced (one trace
+    serves every panel).
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    nRb, nCb, codes = _sim_plan(ref_ops, cand_ops, tile_ids, rows, M, bc)
+    eps = _eps(eps_sp, eps_t, delta_t)
+    p0 = jnp.asarray(p0, jnp.int32)
+    kw = dict(rows=rows, M=M, ms=ms, bc=bc, interpret=interpret)
+
+    R = rows * ms                                   # slot rows per ref block
+    nI = min(nRb, -(-panel // R) + 1)
+    i0 = jnp.clip(p0 // R, 0, nRb - nI)
+    codes = jax.lax.dynamic_slice_in_dim(codes, i0, nI, axis=0)
+    rows_raw = _sim_call(ref_ops, ref_sub, cand_ops, cand_sub, eps, codes,
+                         jnp.stack([i0, 0]), n_out_blocks=nCb, **kw)
+    fwd = jax.lax.dynamic_slice_in_dim(rows_raw, p0 - i0 * R, panel, axis=0)
+
+    Rc = bc * ms                                    # slot cols per cand block
+    nJ = min(nCb, -(-panel // Rc) + 1)
+    j0 = jnp.clip(p0 // Rc, 0, nCb - nJ)
+    cols_raw = _sim_call(ref_ops, ref_sub, cand_ops, cand_sub, eps,
+                         dense_codes(nRb, nJ, j0), jnp.stack([0, j0]),
+                         n_out_blocks=nJ, **kw)
+    rev = jax.lax.dynamic_slice_in_dim(cols_raw, p0 - j0 * Rc, panel, axis=1)
+    return fwd, rev.T

@@ -27,6 +27,7 @@ from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams
 from repro.data.synthetic import (ais_like, default_dsc_params_for,
                                   figure1_scenario)
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("run_dsc")
@@ -353,6 +354,26 @@ def _exit_code_for(exc) -> int | None:
     return None
 
 
+def dataset_and_params(args):
+    """``(batch, params, mean_dt)`` for parsed launcher ``args``: the
+    config's generated dataset (``--n-trajs`` overrides its trajectory
+    count) and the DSC parameters derived from it."""
+    rc = get_dsc_config(args.config)
+    n_trajs = args.n_trajs or rc.n_trajs
+    batch = make_dataset(args.config, n_trajs, rc.max_points, args.seed)
+    diam, mean_dt = default_dsc_params_for(batch)
+    params = DSCParams(
+        eps_sp=0.15 * diam if args.config != "dsc_synth" else 0.42,
+        eps_t=1.0 * mean_dt, delta_t=rc.delta_t,
+        w=min(rc.w, 6 if args.config == "dsc_synth" else rc.w),
+        tau=0.15 if args.config == "dsc_synth" else rc.tau,
+        alpha_sigma=-1.0, k_sigma=-1.0,
+        max_subtrajs_per_traj=rc.max_subtrajs,
+        segmentation=args.segmentation or ("tsa2" if args.config ==
+                                           "dsc_synth" else rc.segmentation))
+    return batch, params, mean_dt
+
+
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
@@ -368,20 +389,8 @@ def main(argv=None):
         print(f"{args.check_readme}: CLI table in sync")
         return None
     plan = plan_from_args(args, ap)
-
-    rc = get_dsc_config(args.config)
-    n_trajs = args.n_trajs or min(rc.n_trajs, 64)
-    batch = make_dataset(args.config, n_trajs, rc.max_points, args.seed)
-    diam, mean_dt = default_dsc_params_for(batch)
-    params = DSCParams(
-        eps_sp=0.15 * diam if args.config != "dsc_synth" else 0.42,
-        eps_t=1.0 * mean_dt, delta_t=rc.delta_t,
-        w=min(rc.w, 6 if args.config == "dsc_synth" else rc.w),
-        tau=0.15 if args.config == "dsc_synth" else rc.tau,
-        alpha_sigma=-1.0, k_sigma=-1.0,
-        max_subtrajs_per_traj=rc.max_subtrajs,
-        segmentation=args.segmentation or ("tsa2" if args.config ==
-                                           "dsc_synth" else rc.segmentation))
+    enable_compile_cache()
+    batch, params, mean_dt = dataset_and_params(args)
 
     if args.stream:
         if args.distributed:

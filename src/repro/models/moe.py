@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.config import MoEConfig
-from repro.utils.compat import axis_size as axis_size_compat
-from repro.utils.compat import shard_map as shard_map_compat
 
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -87,7 +85,7 @@ def moe_ffn(p, x, cfg: MoEConfig, *, axis_name: str | None = None,
     B, L, D = x.shape
     N = B * L
     xt = x.reshape(N, D).astype(COMPUTE_DTYPE)
-    m = 1 if axis_name is None else axis_size_compat(axis_name)
+    m = 1 if axis_name is None else jax.lax.axis_size(axis_name)
     E = p["router"].shape[1]
     E_loc = E // m
     k = cfg.top_k
@@ -212,9 +210,9 @@ def moe_ffn_shard_map(p, x, cfg: MoEConfig, mesh, dp_axes: tuple,
         dropped = lax.pmean(dropped, all_axes)
         return y, aux, dropped
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(p_specs, x_spec),
-        out_specs=(x_spec, P(), P()))
+        out_specs=(x_spec, P(), P()), check_vma=False)
     return fn(p, x)
 
 

@@ -270,10 +270,12 @@ def join_candidates(T: int, M: int, base: EnginePlan) -> list[EnginePlan]:
         cands.append(base.replace(mode="fused", fused_rows=rows,
                                   fused_bc=bc, fused_bm=bm))
 
-    add(None, 16, 128)                       # the fused library default
-    auto_rows = max(1, 2048 // max(M, 1))
+    add(None, 128, 128)                      # the fused library default
+    auto_rows = max(1, 256 // max(M, 1))
+    # lane tiles a TPU accepts: a multiple of 128 (clamped to every
+    # candidate when there are fewer)
     for rows in (1, 4, auto_rows):
-        for bc, bm in ((8, 64), (16, 128), (32, 32)):
+        for bc, bm in ((128, 64), (128, 128), (256, 32)):
             if len(cands) >= 8:
                 return cands
             add(rows, bc, bm)

@@ -60,7 +60,7 @@ def test_fused_tiles_collapse_to_none_at_defaults():
     # default fused fields -> None so default plans keep the pre-plan jit
     # cache keys (no retrace on upgrade)
     assert EnginePlan().fused_tiles is None
-    assert EnginePlan(fused_bm=32).fused_tiles == (None, 16, 32)
+    assert EnginePlan(fused_bm=32).fused_tiles == (None, 128, 32)
     assert EnginePlan().cluster_tiles == (8, 128)
 
 
