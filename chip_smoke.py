@@ -9,9 +9,10 @@ One chip (the default) runs three phases in this one process:
    ``dsc_brest`` deployment's widths (M = 128 points per trajectory, 8
    sub-slots, w = 20) at T = 2048 trajectories, once with TSA1 and once
    with TSA2, under the kernel plan (Pallas join, index, segmentation and
-   clustering kernels).  The stage programs are compiled again from the
-   persistent cache and must contain ``tpu_custom_call``: no kernel ran in
-   interpret mode.  Labels must be consistent.
+   clustering kernels).  The stage programs the dispatcher ran are
+   recorded (``repro.utils.programs``); each must contain
+   ``tpu_custom_call``, so no kernel ran in interpret mode.  Labels must
+   be consistent.
 2. parity: at T = 256 the kernel plan's labels equal the plain plan's
    (materialize join in jnp, dense similarity, sequential clustering)
    label for label, for TSA1 and TSA2.
@@ -103,42 +104,23 @@ def check_labels(out, tag: str):
     return int(rep.sum()), int(owned.sum()), int(outl.sum())
 
 
-def kernel_counts(args, out, plan):
-    """Compile the kernel plan's stage programs for this run's shapes
-    (a persistent-cache hit after the run) and count their Pallas
-    kernels."""
-    import jax
-    from repro.core import dsc
-    from repro.index import grid as gridx
-    from repro.kernels.stjoin import ops as stjoin_ops
-    from repro.launch.run_dsc import build_parser, dataset_and_params
-
-    batch, params, _ = dataset_and_params(build_parser().parse_args(args))
-    mask, counts, _ = stjoin_ops.plan_join_index(
-        batch, batch, params.eps_sp, params.eps_t)
-    tile_ids, _ = gridx.compact_candidates(mask,
-                                           gridx.plan_max_tiles(counts))
-    join = stjoin_ops._join_sweep.lower(
-        batch, batch, params.eps_sp, params.eps_t, tile_ids, bp=256,
-        bc=min(128, batch.num_trajs), bm=128, interpret=None)
-    join_spec = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
-        out.join)
-    S = batch.num_trajs * params.max_subtrajs_per_traj
-    tail = dsc._run_dsc_from_join.lower(
-        batch, params, join_spec, plan.replace(sim_topk=min(32, S)))
+def kernel_counts(programs):
+    """Pallas kernels in each recorded stage program's compiled text (the
+    compile is a cache hit: the same program just ran)."""
     return {name: low.compile().as_text().count(
                 'custom_call_target="tpu_custom_call"')
-            for name, low in (("join", join), ("tail", tail))}
+            for name, low in programs}
 
 
-def batch_phase(dev, plan_path: Path, plan):
+def batch_phase(dev, plan_path: Path):
     import jax
     from repro.launch import run_dsc
+    from repro.utils.programs import record_programs
     for seg in ("tsa1", "tsa2"):
         args = launcher_args(BATCH_T, seg, ["--plan", str(plan_path)])
         t0 = time.perf_counter()
-        out = run_dsc.main(args)
+        with record_programs() as programs:
+            out = run_dsc.main(args)
         jax.block_until_ready(out.result.member_of)
         wall = time.perf_counter() - t0
         reps, members, outliers = check_labels(out, f"batch {seg}")
@@ -147,11 +129,15 @@ def batch_phase(dev, plan_path: Path, plan):
             f"clusters={reps} members={members} outliers={outliers} "
             f"wall_s={wall:.2f} (generation, planning, compile and run) "
             f"peak_bytes_in_use={peak_bytes(dev)}")
-        kernels = kernel_counts(args, out, plan)
+        kernels = kernel_counts(programs)
         log(f"batch {seg}: tpu_custom_call per stage program {kernels}")
-        # join: the stjoin sweep; tail: SP sweep, round scan, claim-max
-        # (+ the Jaccard segmentation kernel under TSA2)
-        want = {"join": 1, "tail": 4 if seg == "tsa2" else 3}
+        # the pruned stjoin sweep; the tail's SP sweep, round scan and
+        # claim-max (+ the Jaccard segmentation kernel under TSA2)
+        want = {"_join_sweep": 1,
+                "_run_dsc_from_join": 4 if seg == "tsa2" else 3}
+        check(sorted(kernels) == sorted(want),
+              f"batch {seg}: ran stage programs {sorted(kernels)}, "
+              f"expected {sorted(want)}")
         for name, n in want.items():
             check(kernels[name] >= n,
                   f"batch {seg}: {name} program has {kernels[name]} "
@@ -282,11 +268,10 @@ def main(argv=None) -> int:
     if args.chips == 4:
         mesh_phase(devs)
     else:
-        plan = kernel_plan()
         with tempfile.TemporaryDirectory() as tmp:
             plan_path = Path(tmp) / "kernel_plan.json"
-            plan.save(plan_path)
-            batch_phase(dev, plan_path, plan)
+            kernel_plan().save(plan_path)
+            batch_phase(dev, plan_path)
             parity_phase(dev, plan_path)
         stream_phase(dev)
     log(f"total_wall_s={time.perf_counter() - t0:.2f}")

@@ -49,17 +49,17 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import segmentation as seg_mod
 from repro.core.clustering import cluster
-from repro.core.geometry import filter_delta_t
+from repro.core.geometry import refine_join
 from repro.core.partitioning import PartitionedBatch
 from repro.core.plan import EnginePlan, resolve_plan
 from repro.core.refine import refine_states
 from repro.core.similarity import (build_subtraj_table_arrays, finalize_sim,
-                                   finalize_sim_cols, largest_divisor,
+                                   finalize_sim_cols,
                                    merge_topk_blocks, merge_topk_lists,
                                    sim_row_moments, topk_overflow)
 from repro.core.voting import normalized_voting
 from repro.core.types import (ClusteringResult, DSCParams, JoinResult,
-                              SubtrajTable, TopKSim)
+                              SubtrajTable, TopKSim, TrajectoryBatch)
 from repro.core.windows import pack_bits
 from repro.utils.tree import pytree_dataclass
 
@@ -109,11 +109,6 @@ def _ring_gather(x, axis, n):
         # after s forwarding hops the buffer holds rank (r - s)'s block
         out = lax.dynamic_update_index_in_dim(out, buf, (r - s) % n, 0)
     return out
-
-
-# largest-divisor tile sizing shares one implementation with the panel
-# planner (repro.core.similarity.largest_divisor)
-_pick_block = largest_divisor
 
 
 def run_dsc_distributed(
@@ -300,23 +295,22 @@ class _DSCProgramBuilder:
         return cx, cy, ct, cv, slabs
 
     # ---------------- phase 1: halo exchange + join ----------------
-    def _join_slab(self, px, py, pt, pv, ref_ids, cid, kx, ky, kt, kv, Mc):
+    def _join_slab(self, px, py, pt, pv, traj_id, cid, kx, ky, kt, kv):
         """One best-match sweep of the candidate point arrays ``[Tc, Mc]``
         — the full ``3Mp`` concat under the barrier schedule, one ``Mp``
         slab at a time under the ring schedule."""
         params, T, Mp, Tc = self.params, self.T, self.Mp, self.Tc
         if self.use_kernel:
-            from repro.kernels import default_interpret
-            from repro.kernels.stjoin.stjoin import stjoin_pallas
-            return stjoin_pallas(
-                px.reshape(-1), py.reshape(-1), pt.reshape(-1),
-                ref_ids.astype(jnp.int32), pv.reshape(-1),
-                kx, ky, kt, cid, kv,
-                params.eps_sp, params.eps_t,
-                bp=_pick_block(T * Mp, 256),
-                bc=Tc if Tc <= 128 else _pick_block(Tc, 128),
-                bm=_pick_block(Mc, 128),
-                interpret=default_interpret())
+            # the padded single-chip wrapper: lane tiles of 128 (or the
+            # whole candidate axis), whatever Tc and Mc are
+            from repro.kernels.stjoin.ops import best_match_join_kernel
+            ref = TrajectoryBatch(x=px, y=py, t=pt, valid=pv,
+                                  traj_id=traj_id)
+            cand = TrajectoryBatch(x=kx, y=ky, t=kt, valid=kv, traj_id=cid)
+            j = best_match_join_kernel(ref, cand, params.eps_sp,
+                                       params.eps_t)
+            return (j.best_w.reshape(T * Mp, Tc),
+                    j.best_idx.reshape(T * Mp, Tc))
         from repro.kernels.stjoin.ref import stjoin_ref
         pair_mask = None
         if self.use_index:
@@ -325,6 +319,7 @@ class _DSCProgramBuilder:
                 px, py, pt, pv, kx, ky, kt, kv,
                 params.eps_sp, params.eps_t)               # [T, Tc]
             pair_mask = jnp.repeat(pmask, Mp, axis=0)      # [T*Mp, Tc]
+        ref_ids = jnp.broadcast_to(traj_id[:, None], (T, Mp)).reshape(-1)
         return stjoin_ref(
             px.reshape(-1), py.reshape(-1), pt.reshape(-1),
             ref_ids, pv.reshape(-1),
@@ -375,7 +370,6 @@ class _DSCProgramBuilder:
                 masks = jnp.zeros((T, Mp, 1), jnp.uint32)
             return join, vote, masks
 
-        ref_ids = jnp.broadcast_to(traj_id[:, None], (T, Mp)).reshape(-1)
         if self.halo_stream == "ring" and slabs is not None:
             # slab-streamed join: fold each slab's sweep as it lands.
             # Slab order mirrors the concat (own, left, right); strict
@@ -384,23 +378,18 @@ class _DSCProgramBuilder:
             bw = jnp.zeros((T * Mp, Tc), jnp.float32)
             bidx = jnp.full((T * Mp, Tc), -1, jnp.int32)
             for off, (sx, sy, st, sv) in zip((0, Mp, 2 * Mp), slabs):
-                w_s, i_s = self._join_slab(px, py, pt, pv, ref_ids, cid,
-                                           sl(sx), sl(sy), sl(st), sl(sv),
-                                           Mp)
+                w_s, i_s = self._join_slab(px, py, pt, pv, traj_id, cid,
+                                           sl(sx), sl(sy), sl(st), sl(sv))
                 better = w_s > bw
                 bidx = jnp.where(better, i_s + off, bidx)
                 bw = jnp.where(better, w_s, bw)
         else:
-            bw, bidx = self._join_slab(px, py, pt, pv, ref_ids, cid,
-                                       sl(cx), sl(cy), sl(ct), sl(cv),
-                                       3 * Mp)
+            bw, bidx = self._join_slab(px, py, pt, pv, traj_id, cid,
+                                       sl(cx), sl(cy), sl(ct), sl(cv))
 
         join = JoinResult(best_w=bw.reshape(T, Mp, Tc),
                           best_idx=bidx.reshape(T, Mp, Tc))
-        dt = jnp.asarray(params.delta_t, jnp.float32)
-        join = jax.lax.cond(
-            dt > 0.0, lambda j: filter_delta_t(j, pt, dt),
-            lambda j: j, join)
+        join = refine_join(join, pt, params.delta_t)
 
         vote = lax.psum(
             jnp.sum(join.best_w, axis=-1), self.model_axis)  # [T, Mp]

@@ -37,6 +37,7 @@ from repro.core.plan import EnginePlan, resolve_plan
 from repro.core.types import (ClusteringResult, DSCParams, JoinResult,
                               SubtrajSegmentation, SubtrajTable, TopKSim,
                               TrajectoryBatch)
+from repro.utils.programs import run_program
 from repro.utils.tree import pytree_dataclass
 
 # stage-state donation is best-effort (see repro.core.distributed): when a
@@ -91,8 +92,9 @@ def _similarity_body(batch, params, join, seg, table, plan: EnginePlan,
     The Pallas pass-2 sweep (``kernels.stjoin``) builds the raw scatter
     whenever the join runs on the kernels — fused mode, or the kernel
     join in materialize mode — instead of an XLA scatter of all ``T*M*C``
-    cube entries, which a TPU serializes and compiles for minutes at
-    deployment sizes.  Both accumulate every cell in the same order.
+    cube entries, whose index operands alone are as large as the cube
+    (DESIGN.md §3).  Both accumulate every cell in the same order.
+    ``tile_ids`` is the sweep's own index plan (:func:`plan_sweep_tiles`).
     """
     sweep = join is None or plan.use_kernel
     if plan.sim_mode == "topk":
@@ -197,12 +199,14 @@ def _run_dsc_materialize(batch: TrajectoryBatch, params: DSCParams,
 
 @functools.partial(jax.jit, static_argnames=("plan",), donate_argnums=(2,))
 def _run_dsc_from_join(batch: TrajectoryBatch, params: DSCParams,
-                       join: JoinResult, plan: EnginePlan) -> DSCOutput:
+                       join: JoinResult, tile_ids,
+                       plan: EnginePlan) -> DSCOutput:
     """Materializing tail for a join produced outside the jit boundary
     (the host-planned index-pruned Pallas join).  The cube is donated:
     the output hands it back without a second ``[T, M, C]`` copy."""
     vote, masks = _vote_from_join_body(params, join)
-    return _finish(batch, params, join, vote, masks, plan)
+    return _finish(batch, params, join, vote, masks, plan,
+                   tile_ids=tile_ids)
 
 
 def _tile_kwargs(fused_tiles):
@@ -298,17 +302,19 @@ def run_stage_score(result: ClusteringResult, sim, params: DSCParams):
     return _score_body(result, sim, params)
 
 
-def plan_fused_tile_ids(batch: TrajectoryBatch, params: DSCParams,
-                        plan: EnginePlan):
-    """Host-side fused-tile planning (``mode="fused"`` + ``use_index``).
+def plan_sweep_tiles(batch: TrajectoryBatch, params: DSCParams,
+                     plan: EnginePlan):
+    """Host-side tile planning for the fused sweeps under ``use_index``:
+    both passes in fused mode, the similarity pass (pass 2) under the
+    materialize-mode kernel join.
 
     Returns ``(tile_ids, plan)`` where the plan has the tile plan's
     resolved geometry bound, so every later sweep uses the exact tiling
-    the ids were built for.  ``tile_ids`` is None when the index is off.
-    Shared by :func:`run_dsc`'s dispatcher and the resilient runner —
-    both must plan identically for resume parity.
+    the ids were built for.  ``tile_ids`` is None when no sweep is
+    pruned.  Shared by :func:`run_dsc`'s dispatcher and the resilient
+    runner — both must plan identically for resume parity.
     """
-    if not (plan.mode == "fused" and plan.use_index):
+    if not (plan.use_index and (plan.mode == "fused" or plan.use_kernel)):
         return None, plan
     from repro.kernels.stjoin import ops as stjoin_ops
     tp = stjoin_ops.plan_fused_tiles(
@@ -423,10 +429,10 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
     k = min(plan.sim_topk if plan.sim_topk is not None else 32, S)
 
     def dispatch(k):
-        p = plan.replace(sim_topk=k)
+        tile_ids, p = plan_sweep_tiles(batch, params,
+                                       plan.replace(sim_topk=k))
         if p.mode == "fused":
-            tile_ids, p = plan_fused_tile_ids(batch, params, p)
-            return _run_dsc_fused(batch, params, tile_ids, p)
+            return run_program(_run_dsc_fused, batch, params, tile_ids, p)
         if p.use_index and p.use_kernel:
             # grid-pruned Pallas join: host-side planning pass, then
             # jitted tail
@@ -434,8 +440,9 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
             join = stjoin_ops.subtrajectory_join(
                 batch, batch, params.eps_sp, params.eps_t, params.delta_t,
                 use_index=True)
-            return _run_dsc_from_join(batch, params, join, p)
-        return _run_dsc_materialize(batch, params, p)
+            return run_program(_run_dsc_from_join, batch, params, join,
+                               tile_ids, p)
+        return run_program(_run_dsc_materialize, batch, params, p)
 
     if plan.sim_mode == "dense":
         return dispatch(k)

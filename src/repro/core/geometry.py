@@ -13,7 +13,6 @@ against this reference.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.types import JoinResult, TrajectoryBatch
@@ -105,19 +104,16 @@ def filter_delta_t(join: JoinResult, ref_t: jnp.ndarray,
 
 
 def refine_join(join: JoinResult, ref_t: jnp.ndarray,
-                delta_t) -> JoinResult:
+                delta_t: float) -> JoinResult:
     """``filter_delta_t`` when ``delta_t > 0``, else the join unchanged.
 
-    A concrete ``delta_t`` decides in Python: a ``lax.cond`` would compile
-    (and hold memory for) the filter branch even at ``delta_t == 0``, and
-    on a ``[T, M, C]`` cube that branch alone can exceed a chip's HBM.
+    ``delta_t`` is a concrete number (``DSCParams.delta_t`` is a static
+    field), so the choice is made at trace time: a ``lax.cond`` would
+    compile (and hold memory for) the filter branch even at
+    ``delta_t == 0``, and on a ``[T, M, C]`` cube that branch alone can
+    exceed a chip's HBM.
     """
-    if isinstance(delta_t, jax.core.Tracer):
-        dt = jnp.asarray(delta_t, jnp.float32)
-        return jax.lax.cond(dt > 0.0,
-                            lambda jj: filter_delta_t(jj, ref_t, dt),
-                            lambda jj: jj, join)
-    if delta_t > 0:
+    if float(delta_t) > 0:
         return filter_delta_t(join, ref_t, jnp.float32(delta_t))
     return join
 

@@ -37,7 +37,9 @@ class DSCParams:
 
     eps_sp: float = 0.1        # spatial matching threshold epsilon_sp
     eps_t: float = 0.5         # temporal matching tolerance epsilon_t
-    delta_t: float = 0.0       # minimum duration of a match (delta t)
+    # minimum duration of a match (delta t); static, so whether the Refine
+    # step runs is decided at trace time (DESIGN.md §3)
+    delta_t: float = static_field(default=0.0)
     w: int = static_field(default=10)     # sliding-window size (samples)
     tau: float = 0.4           # segmentation threshold on window difference
     alpha_sigma: float = 0.0   # similarity threshold, in sigmas around mean

@@ -32,6 +32,7 @@ from repro.kernels.stjoin.stjoin import (
     stjoin_sim_panel_flat,
     stjoin_vote_fused_flat,
 )
+from repro.utils.programs import run_program
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int, fill):
@@ -141,8 +142,8 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
             f"max_tiles={K} drops candidate tiles (need {need}); "
             "the pruned join would no longer match the dense join")
     tile_ids, counts = gridx.compact_candidates(mask, K)
-    out = _join_sweep(ref, cand, eps_sp, eps_t, tile_ids, bp=bp, bc=bc,
-                      bm=bm, interpret=interpret)
+    out = run_program(_join_sweep, ref, cand, eps_sp, eps_t, tile_ids,
+                      bp=bp, bc=bc, bm=bm, interpret=interpret)
     if return_stats:
         return out, gridx.prune_stats(counts, mask.shape[1])
     return out

@@ -479,7 +479,7 @@ class _SingleHostLoop(_StageLoop):
                          **kw)
         # host-side planning is deterministic, so recomputing it on
         # resume reproduces the original run exactly (never checkpointed)
-        self.tile_ids, self.plan = dsc_mod.plan_fused_tile_ids(
+        self.tile_ids, self.plan = dsc_mod.plan_sweep_tiles(
             batch, params, self.plan)
         self.plan = self.plan.replace(sim_topk=self.current_k({}))
 

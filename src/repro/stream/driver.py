@@ -74,7 +74,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager, checkpoint_meta
 from repro.core.clustering import cluster_rounds_topk, visit_order
-from repro.core.geometry import best_match_join, filter_delta_t
+from repro.core.geometry import best_match_join, refine_join
 from repro.core.segmentation import tsa1, tsa2
 from repro.core.similarity import (build_subtraj_table_arrays,
                                    sort_topk_lists, topk_overflow)
@@ -176,9 +176,9 @@ class StreamConfig:
 # distinct padded dirty-row bucket sizes, not by driver instances)
 # --------------------------------------------------------------------------
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("delta_t",))
 def _delta_join(dx, dy, dt_, dvalid, dobj, wx, wy, wt, wvalid, wobj,
-                fwd_mask, eps_sp, eps_t, delta_t):
+                fwd_mask, eps_sp, eps_t, *, delta_t: float):
     """Dirty-rows-vs-window join, both directions.
 
     Per-cell values equal the full batch join's: ``best_w[r, m, c]`` is a
@@ -187,13 +187,10 @@ def _delta_join(dx, dy, dt_, dvalid, dobj, wx, wy, wt, wvalid, wobj,
     """
     ref = TrajectoryBatch(x=dx, y=dy, t=dt_, valid=dvalid, traj_id=dobj)
     cand = TrajectoryBatch(x=wx, y=wy, t=wt, valid=wvalid, traj_id=wobj)
-    dt = jnp.asarray(delta_t, jnp.float32)
 
     def run(r, c, mask):
         j = best_match_join(r, c, eps_sp, eps_t, prune_mask=mask)
-        return jax.lax.cond(dt > 0.0,
-                            lambda jj: filter_delta_t(jj, r.t, dt),
-                            lambda jj: jj, j)
+        return refine_join(j, r.t, delta_t)
 
     fwd = run(ref, cand, fwd_mask)
     rev = run(cand, ref, fwd_mask.T)
@@ -689,7 +686,7 @@ class StreamDriver:
             jnp.asarray(self.ts), jnp.asarray(self.valid),
             jnp.asarray(self.obj_of_row.astype(np.int32)),
             fwd_mask, np.float32(c.eps_sp), np.float32(c.eps_t),
-            np.float32(c.delta_t))
+            delta_t=float(c.delta_t))
         fw, fi = np.asarray(fw), np.asarray(fi)
         rw, ri = np.asarray(rw), np.asarray(ri)
         nD = D.size

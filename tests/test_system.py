@@ -119,3 +119,24 @@ def test_kernel_path_matches_reference(fig1, fig1_params):
                                atol=1e-4)
     assert (np.asarray(a.result.member_of) ==
             np.asarray(b.result.member_of)).mean() > 0.99
+
+
+@pytest.mark.parametrize("delta_t", [0.0, 2.0])
+def test_kernel_index_plan_runs_recorded_pruned_programs(fig1, fig1_params,
+                                                         delta_t):
+    """The kernel + index plan runs the pruned join and one tail program,
+    whose similarity sweep is pruned by its own tile plan: the same
+    similarity and labels as the dense kernel plan, with the delta_t
+    Refine off and on."""
+    from repro.utils.programs import record_programs
+    batch, _ = fig1
+    params = fig1_params.replace(delta_t=delta_t)
+    with record_programs() as programs:
+        got = run_dsc(batch, params, use_kernel=True, use_index=True)
+    assert [name for name, _ in programs] == ["_join_sweep",
+                                              "_run_dsc_from_join"]
+    want = run_dsc(batch, params, use_kernel=True)
+    np.testing.assert_array_equal(np.asarray(got.sim), np.asarray(want.sim))
+    for f in ("member_of", "is_rep", "is_outlier"):
+        np.testing.assert_array_equal(np.asarray(getattr(got.result, f)),
+                                      np.asarray(getattr(want.result, f)))

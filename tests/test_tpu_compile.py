@@ -19,14 +19,20 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.types import TrajectoryBatch
 from repro.kernels.cluster import cluster as ck
 from repro.kernels.jaccard import jaccard as jk
+from repro.kernels.stjoin import ops as sops
 from repro.kernels.stjoin import stjoin as sk
 
 T, M, MS, W, WIN = 2048, 128, 8, 64, 20
 P, C, S = T * M, T, T * MS
 ROWS = 2                   # fused ref block: 2 rows of 128 points
 K = 8                      # surviving tiles per ref block (pruned plans)
+# one halo slab of the distributed materialize join: a rank's Mp points
+# per trajectory against a candidate column block whose width is not a
+# multiple of 128 (the wrapper pads it) and the 3*Mp-point halo concat
+MP, TC = 64, 1000
 
 
 @pytest.fixture(scope="module")
@@ -65,6 +71,13 @@ def _cands(spec, c, m):
     f32 = jnp.float32
     return [spec((c, m), f32)] * 3 + [spec((c,), jnp.int32),
                                        spec((c, m), jnp.bool_)]
+
+
+def _batch(spec, n, m):
+    """Abstract ``TrajectoryBatch`` leaves ``(x, y, t, valid, traj_id)``."""
+    f32 = jnp.float32
+    return [spec((n, m), f32)] * 3 + [spec((n, m), jnp.bool_),
+                                       spec((n,), jnp.int32)]
 
 
 def _kernel_calls(spec):
@@ -129,13 +142,19 @@ def _kernel_calls(spec):
         "jaccard": (
             lambda m: jk.jaccard_pallas(m, w=WIN, interpret=False),
             [spec((T, M, W), jnp.uint32)]),
+        "stjoin_mesh_slab": (
+            lambda *a: sops.best_match_join_kernel(
+                TrajectoryBatch(*a[:5]), TrajectoryBatch(*a[5:10]), *a[10:],
+                interpret=False),
+            [*_batch(spec, T, MP), *_batch(spec, TC, 3 * MP), e, e]),
     }
 
 
 KERNELS = ["stjoin_dense", "stjoin_pruned", "vote_dense_tsa2",
            "vote_dense_tsa1", "vote_pruned", "sim_dense", "sim_pruned",
            "sim_panel_dense", "sim_panel_pruned", "cluster_round_scan",
-           "cluster_assign", "topk_round_scan", "topk_assign", "jaccard"]
+           "cluster_assign", "topk_round_scan", "topk_assign", "jaccard",
+           "stjoin_mesh_slab"]
 
 
 @pytest.mark.parametrize("name", KERNELS)
