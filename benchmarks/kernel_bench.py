@@ -664,7 +664,12 @@ def bench_pipeline(smoke: bool = False, out_dir: str = ".") -> dict:
     # every variant through the traceable entry (run_dsc_lowerable): no
     # host-side index planning and no top-K overflow retry, so an
     # overflow at the benchmarked K still fails the gate loudly below
-    # instead of silently auto-widening past it
+    # instead of silently auto-widening past it.  The params are an
+    # argument, as in run_dsc's jit: closed over, eps_sp would be a
+    # compile-time constant, and XLA on CPU turns the jnp join's division
+    # by it into an FMA with the reciprocal, so the oracle would round
+    # differently from the kernels (which read eps at run time) and from
+    # itself run eagerly (DESIGN.md §3)
     e2e_plans = {
         "materialize_jnp_us": EnginePlan(),
         "materialize_kernel_us": EnginePlan.from_legacy(use_kernel=True),
@@ -678,8 +683,8 @@ def bench_pipeline(smoke: bool = False, out_dir: str = ".") -> dict:
     e2e, e2e_out = {}, {}
     for key, plan in e2e_plans.items():
         e2e_out[key], wall, _ = measure_compiled(
-            lambda b, p=plan: run_dsc_lowerable(b, params, p),
-            (batch,), iters=iters)
+            lambda b, q, p=plan: run_dsc_lowerable(b, q, p),
+            (batch, params), iters=iters)
         e2e[key] = wall * 1e6
     out_ref = e2e_out["materialize_jnp_us"]
     out_f = e2e_out["fused_us"]
