@@ -20,7 +20,10 @@ _DRIVER = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.distributed.pipeline import pipeline_apply, bubble_fraction
 
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    # Auto axes: the schedule is written for shard_map under an implicitly
+    # sharded mesh, and jax.make_mesh defaults to Explicit axes
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     S, D = 4, 16
     rng = np.random.default_rng(0)
     Ws = jnp.asarray(rng.normal(0, 0.5, (S, D, D)), jnp.float32)
