@@ -29,6 +29,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import geometry, segmentation, similarity, voting
 from repro.core.clustering import (cluster, rmse, rmse_from_result, sscr,
@@ -66,22 +67,26 @@ class DSCOutput:
 # (run_stage_*) compose these same functions, so a staged run executes
 # literally the same traced code per stage as a straight-through run — that
 # code-sharing is the resilient runner's bit-identity argument
-# (``repro.run.resilient``, DESIGN.md §10).
+# (``repro.run.resilient``, DESIGN.md §10).  Each body runs under its
+# stage's ``jax.named_scope`` (``dsc.join``, ``dsc.vote``, ``dsc.segment``,
+# ``dsc.similarity``, ``dsc.cluster``, ``dsc.score``), so the device
+# operations of every program that composes it carry the stage's name.
 # --------------------------------------------------------------------------
 
 
 def _segment_body(batch, params, vote, masks, plan: EnginePlan):
     """Voting signal -> segmentation -> subtrajectory table."""
-    nvote = voting.normalized_voting(vote, batch.valid)
-    if params.segmentation == "tsa1":
-        seg = segmentation.tsa1(nvote, batch.valid, params.w, params.tau,
-                                params.max_subtrajs_per_traj)
-    else:
-        seg = segmentation.tsa2(masks, batch.valid, params.w, params.tau,
-                                params.max_subtrajs_per_traj,
-                                use_kernel=plan.seg_use_kernel)
-    table = similarity.build_subtraj_table(
-        batch, seg, vote, params.max_subtrajs_per_traj)
+    with jax.named_scope("dsc.segment"):
+        nvote = voting.normalized_voting(vote, batch.valid)
+        if params.segmentation == "tsa1":
+            seg = segmentation.tsa1(nvote, batch.valid, params.w,
+                                    params.tau, params.max_subtrajs_per_traj)
+        else:
+            seg = segmentation.tsa2(masks, batch.valid, params.w, params.tau,
+                                    params.max_subtrajs_per_traj,
+                                    use_kernel=plan.seg_use_kernel)
+        table = similarity.build_subtraj_table(
+            batch, seg, vote, params.max_subtrajs_per_traj)
     return seg, table
 
 
@@ -96,60 +101,63 @@ def _similarity_body(batch, params, join, seg, table, plan: EnginePlan,
     (DESIGN.md §3).  Both accumulate every cell in the same order.
     ``tile_ids`` is the sweep's own index plan (:func:`plan_sweep_tiles`).
     """
-    sweep = join is None or plan.use_kernel
-    if plan.sim_mode == "topk":
-        # sparse SP relation: panel-streamed top-K lists, never [S, S]
+    with jax.named_scope("dsc.similarity"):
+        sweep = join is None or plan.use_kernel
+        if plan.sim_mode == "topk":
+            # sparse SP relation: panel-streamed top-K lists, never [S, S]
+            if sweep:
+                from repro.kernels.stjoin import ops as stjoin_ops
+                Sb = similarity.plan_panel(table.num_slots, plan.sim_panel)
+
+                def panel_raw(p0):
+                    return stjoin_ops.stjoin_sim_panel_fused(
+                        batch, batch, seg.sub_local, seg.sub_local,
+                        params.max_subtrajs_per_traj, params.eps_sp,
+                        params.eps_t, params.delta_t, p0=p0, panel=Sb,
+                        tile_ids=tile_ids, **_tile_kwargs(plan.fused_tiles))
+
+                topk = similarity.topk_stream(panel_raw, table,
+                                              k=plan.sim_topk, panel=Sb)
+            else:
+                topk = similarity.similarity_topk(
+                    join, seg, seg.sub_local, table,
+                    params.max_subtrajs_per_traj, k=plan.sim_topk,
+                    panel=plan.sim_panel)
+            return None, topk
+
         if sweep:
             from repro.kernels.stjoin import ops as stjoin_ops
-            Sb = similarity.plan_panel(table.num_slots, plan.sim_panel)
-
-            def panel_raw(p0):
-                return stjoin_ops.stjoin_sim_panel_fused(
-                    batch, batch, seg.sub_local, seg.sub_local,
-                    params.max_subtrajs_per_traj, params.eps_sp,
-                    params.eps_t, params.delta_t, p0=p0, panel=Sb,
-                    tile_ids=tile_ids, **_tile_kwargs(plan.fused_tiles))
-
-            topk = similarity.topk_stream(panel_raw, table, k=plan.sim_topk,
-                                          panel=Sb)
+            raw = stjoin_ops.stjoin_sim_fused(
+                batch, batch, seg.sub_local, seg.sub_local,
+                params.max_subtrajs_per_traj, params.eps_sp, params.eps_t,
+                params.delta_t, tile_ids=tile_ids,
+                **_tile_kwargs(plan.fused_tiles))
+            sim = similarity.finalize_sim(raw, table)
         else:
-            topk = similarity.similarity_topk(
-                join, seg, seg.sub_local, table,
-                params.max_subtrajs_per_traj, k=plan.sim_topk,
-                panel=plan.sim_panel)
-        return None, topk
-
-    if sweep:
-        from repro.kernels.stjoin import ops as stjoin_ops
-        raw = stjoin_ops.stjoin_sim_fused(
-            batch, batch, seg.sub_local, seg.sub_local,
-            params.max_subtrajs_per_traj, params.eps_sp, params.eps_t,
-            params.delta_t, tile_ids=tile_ids,
-            **_tile_kwargs(plan.fused_tiles))
-        sim = similarity.finalize_sim(raw, table)
-    else:
-        sim = similarity.similarity_matrix(
-            join, seg, seg.sub_local, table, params.max_subtrajs_per_traj)
-    return sim, None
+            sim = similarity.similarity_matrix(
+                join, seg, seg.sub_local, table, params.max_subtrajs_per_traj)
+        return sim, None
 
 
 def _cluster_body(simlike, table, params, plan: EnginePlan):
     """Problem 3: returns ``(result, overflow)``; overflow is None for the
     dense path (the certificate only exists for truncated top-K lists)."""
-    result = cluster(simlike, table, params, engine=plan.cluster_engine,
-                     use_kernel=plan.cluster_use_kernel,
-                     tiles=plan.cluster_tiles)
-    if isinstance(simlike, TopKSim):
-        return result, similarity.topk_overflow(simlike, result.alpha_used)
-    return result, None
+    with jax.named_scope("dsc.cluster"):
+        result = cluster(simlike, table, params, engine=plan.cluster_engine,
+                         use_kernel=plan.cluster_use_kernel,
+                         tiles=plan.cluster_tiles)
+        if isinstance(simlike, TopKSim):
+            return result, similarity.topk_overflow(simlike, result.alpha_used)
+        return result, None
 
 
 def _score_body(result, sim, params):
     """Quality metrics: moment-based when the dense matrix was skipped."""
-    if sim is None:
-        return sscr_from_result(result), rmse_from_result(result,
-                                                          params.eps_sp)
-    return sscr(result, sim), rmse(result, sim, params.eps_sp)
+    with jax.named_scope("dsc.score"):
+        if sim is None:
+            return sscr_from_result(result), rmse_from_result(result,
+                                                              params.eps_sp)
+        return sscr(result, sim), rmse(result, sim, params.eps_sp)
 
 
 def _finish(batch, params, join, vote, masks, plan: EnginePlan,
@@ -171,21 +179,23 @@ def _finish(batch, params, join, vote, masks, plan: EnginePlan,
 
 
 def _vote_from_join_body(params, join):
-    vote = voting.point_voting(join)
-    masks = (voting.neighbor_mask_packed(join)
-             if params.segmentation == "tsa2" else None)
-    return vote, masks
+    with jax.named_scope("dsc.vote"):
+        vote = voting.point_voting(join)
+        masks = (voting.neighbor_mask_packed(join)
+                 if params.segmentation == "tsa2" else None)
+        return vote, masks
 
 
 def _join_vote_materialize_body(batch, params, plan: EnginePlan):
-    if plan.use_kernel:
-        from repro.kernels.stjoin import ops as stjoin_ops
-        join = stjoin_ops.subtrajectory_join(
-            batch, batch, params.eps_sp, params.eps_t, params.delta_t)
-    else:
-        join = geometry.subtrajectory_join(
-            batch, batch, params.eps_sp, params.eps_t, params.delta_t,
-            use_index=plan.use_index)
+    with jax.named_scope("dsc.join"):
+        if plan.use_kernel:
+            from repro.kernels.stjoin import ops as stjoin_ops
+            join = stjoin_ops.subtrajectory_join(
+                batch, batch, params.eps_sp, params.eps_t, params.delta_t)
+        else:
+            join = geometry.subtrajectory_join(
+                batch, batch, params.eps_sp, params.eps_t, params.delta_t,
+                use_index=plan.use_index)
     vote, masks = _vote_from_join_body(params, join)
     return join, vote, masks
 
@@ -219,12 +229,13 @@ def _tile_kwargs(fused_tiles):
 
 def _join_vote_fused_body(batch, params, tile_ids, plan: EnginePlan):
     from repro.kernels.stjoin import ops as stjoin_ops
-    return stjoin_ops.stjoin_vote_fused_arrays(
-        batch.x, batch.y, batch.t, batch.valid, batch.traj_id,
-        batch.x, batch.y, batch.t, batch.valid, batch.traj_id,
-        params.eps_sp, params.eps_t, params.delta_t, tile_ids=tile_ids,
-        with_masks=params.segmentation == "tsa2",
-        **_tile_kwargs(plan.fused_tiles))
+    with jax.named_scope("dsc.join"):
+        return stjoin_ops.stjoin_vote_fused_arrays(
+            batch.x, batch.y, batch.t, batch.valid, batch.traj_id,
+            batch.x, batch.y, batch.t, batch.valid, batch.traj_id,
+            params.eps_sp, params.eps_t, params.delta_t, tile_ids=tile_ids,
+            with_masks=params.segmentation == "tsa2",
+            **_tile_kwargs(plan.fused_tiles))
 
 
 @functools.partial(jax.jit, static_argnames=("plan",))
@@ -406,59 +417,66 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
     raises immediately, ``"degrade"`` returns the truncated result with
     the violation recorded in ``out.sim_overflow``.  The default (None)
     keeps the legacy ``sim_topk_retry`` behavior; passing both raises.
+
+    The call is the host span ``dsc.run``, with ``T``, ``M``, ``mode``,
+    the final ``k`` and the ``attempts`` the top-K widen loop made.
     """
-    plan = resolve_plan(plan, mode=mode, use_kernel=use_kernel,
-                        use_index=use_index, fused_tiles=fused_tiles,
-                        cluster_engine=cluster_engine,
-                        cluster_use_kernel=cluster_use_kernel,
-                        seg_use_kernel=seg_use_kernel, sim_mode=sim_mode,
-                        sim_topk=sim_topk, sim_panel=sim_panel)
+    with TraceAnnotation("dsc.run") as span:
+        plan = resolve_plan(plan, mode=mode, use_kernel=use_kernel,
+                            use_index=use_index, fused_tiles=fused_tiles,
+                            cluster_engine=cluster_engine,
+                            cluster_use_kernel=cluster_use_kernel,
+                            seg_use_kernel=seg_use_kernel, sim_mode=sim_mode,
+                            sim_topk=sim_topk, sim_panel=sim_panel)
 
-    if on_overflow is not None:
-        if on_overflow not in ("raise", "widen", "degrade"):
-            raise ValueError(f"on_overflow={on_overflow!r}: expected "
-                             "'raise', 'widen', or 'degrade'")
-        if not sim_topk_retry:
-            raise ValueError("pass either on_overflow or "
-                             "sim_topk_retry=False, not both")
-        policy = on_overflow
-    else:
-        policy = "widen" if sim_topk_retry else "raise"
+        if on_overflow is not None:
+            if on_overflow not in ("raise", "widen", "degrade"):
+                raise ValueError(f"on_overflow={on_overflow!r}: expected "
+                                 "'raise', 'widen', or 'degrade'")
+            if not sim_topk_retry:
+                raise ValueError("pass either on_overflow or "
+                                 "sim_topk_retry=False, not both")
+            policy = on_overflow
+        else:
+            policy = "widen" if sim_topk_retry else "raise"
 
-    S = batch.num_trajs * params.max_subtrajs_per_traj
-    k = min(plan.sim_topk if plan.sim_topk is not None else 32, S)
+        S = batch.num_trajs * params.max_subtrajs_per_traj
+        k = min(plan.sim_topk if plan.sim_topk is not None else 32, S)
 
-    def dispatch(k):
-        tile_ids, p = plan_sweep_tiles(batch, params,
-                                       plan.replace(sim_topk=k))
-        if p.mode == "fused":
-            return run_program(_run_dsc_fused, batch, params, tile_ids, p)
-        if p.use_index and p.use_kernel:
-            # grid-pruned Pallas join: host-side planning pass, then
-            # jitted tail
-            from repro.kernels.stjoin import ops as stjoin_ops
-            join = stjoin_ops.subtrajectory_join(
-                batch, batch, params.eps_sp, params.eps_t, params.delta_t,
-                use_index=True)
-            return run_program(_run_dsc_from_join, batch, params, join,
-                               tile_ids, p)
-        return run_program(_run_dsc_materialize, batch, params, p)
+        def dispatch(k):
+            tile_ids, p = plan_sweep_tiles(batch, params,
+                                           plan.replace(sim_topk=k))
+            if p.mode == "fused":
+                return run_program(_run_dsc_fused, batch, params, tile_ids, p)
+            if p.use_index and p.use_kernel:
+                # grid-pruned Pallas join: host-side planning pass, then
+                # jitted tail
+                from repro.kernels.stjoin import ops as stjoin_ops
+                join = stjoin_ops.subtrajectory_join(
+                    batch, batch, params.eps_sp, params.eps_t, params.delta_t,
+                    use_index=True)
+                return run_program(_run_dsc_from_join, batch, params, join,
+                                   tile_ids, p)
+            return run_program(_run_dsc_materialize, batch, params, p)
 
-    if plan.sim_mode == "dense":
-        return dispatch(k)
-    while True:
-        out = dispatch(k)
-        overflow = int(out.sim_overflow)
-        if overflow == 0 or policy == "degrade":
-            return out
-        if k >= S:                  # unreachable: K == S cannot spill
-            raise AssertionError("overflow with K == S")
-        if policy == "raise":
-            raise RuntimeError(
-                f"sim_topk={k} truncated a potential alpha-edge on "
-                f"{overflow} rows (spill >= alpha): labels would not be "
-                "exact.  Raise sim_topk or enable sim_topk_retry.")
-        k = min(2 * k, S)
+        attempts, out = 1, dispatch(k)
+        while plan.sim_mode != "dense":
+            overflow = int(out.sim_overflow)
+            if overflow == 0 or policy == "degrade":
+                break
+            if k >= S:                  # unreachable: K == S cannot spill
+                raise AssertionError("overflow with K == S")
+            if policy == "raise":
+                raise RuntimeError(
+                    f"sim_topk={k} truncated a potential alpha-edge on "
+                    f"{overflow} rows (spill >= alpha): labels would not be "
+                    "exact.  Raise sim_topk or enable sim_topk_retry.")
+            k = min(2 * k, S)
+            attempts, out = attempts + 1, dispatch(k)
+        if TraceAnnotation.is_enabled():
+            span.set_metadata(T=batch.num_trajs, M=batch.x.shape[1],
+                              mode=plan.mode, k=k, attempts=attempts)
+        return out
 
 
 def cluster_summary(out: DSCOutput) -> dict:

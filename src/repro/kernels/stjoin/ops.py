@@ -22,6 +22,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.geometry import refine_join
 from repro.core.types import JoinResult, TrajectoryBatch
@@ -91,6 +92,51 @@ def best_match_join_kernel(ref: TrajectoryBatch, cand: TrajectoryBatch,
                        interpret=interpret)
 
 
+def _tile_mask(ref_pts, cand_rows, bp: int, bc: int, eps_sp, eps_t,
+               use_cells: bool):
+    """The grid index over one planning's padded operands: ``ref_pts``
+    ``(x, y, t, ok)`` flat ``[P]`` in blocks of ``bp`` points, ``cand_rows``
+    ``(x, y, t, ok)`` ``[C, Mc]`` in blocks of ``bc`` rows.
+
+    Block boxes, then the grid fitted to them and its cell table (with
+    ``use_cells``), then the candidate-tile mask, each in its host span.
+    Returns ``(mask [nRb, nCb] bool, counts [nRb] i32, spec | None)``.
+    """
+    with TraceAnnotation("index.boxes"):
+        rboxes = gridx.point_block_boxes(*ref_pts, bp)
+        cboxes = gridx.traj_block_boxes(*cand_rows, bc)
+    spec = None
+    if use_cells:
+        with TraceAnnotation("index.grid"):
+            spec = gridx.fit_grid(cboxes, float(eps_sp), float(eps_t))
+            table = gridx.build_cell_table(spec, cboxes)
+    with TraceAnnotation("index.mask"):
+        if use_cells:
+            mask = gridx.candidate_tile_mask(
+                spec, table, rboxes, cboxes, eps_sp, eps_t)
+        else:
+            mask = gridx.exact_pair_mask(rboxes, cboxes, eps_sp, eps_t)
+        counts = jnp.sum(mask, axis=1).astype(jnp.int32)
+    return mask, counts, spec
+
+
+def _compact_tiles(mask, counts, K: int | None):
+    """Compact ``mask`` to each row block's surviving tile ids, ``K`` wide
+    (``None``: the widest list).  ``counts`` comes to the host once, for
+    the width, the check that ``K`` drops no tile, and the kept count.
+    Returns ``(tile_ids [nRb, K], counts, K, kept)``."""
+    with TraceAnnotation("index.compact"):
+        host = np.asarray(counts)
+        need = gridx.plan_max_tiles(host)
+        K = need if K is None else K
+        if int(np.max(host, initial=0)) > K:
+            raise ValueError(
+                f"max_tiles={K} drops candidate tiles (need {need}); the "
+                "pruned sweep would no longer match the dense sweep")
+        tile_ids, counts = gridx.compact_candidates(mask, K)
+    return tile_ids, counts, K, int(host.sum())
+
+
 def plan_join_index(ref: TrajectoryBatch, cand: TrajectoryBatch,
                     eps_sp, eps_t, *, bp=256, bc=128, use_cells: bool = True):
     """Candidate-tile mask + per-ref-block survivor counts.
@@ -103,18 +149,8 @@ def plan_join_index(ref: TrajectoryBatch, cand: TrajectoryBatch,
     """
     (rx, ry, rt, _, rok), (cx, cy, ct, _, cok) = _padded_operands(
         ref, cand, bp, bc, 1)
-    rboxes = gridx.point_block_boxes(rx, ry, rt, rok, bp)
-    cboxes = gridx.traj_block_boxes(cx, cy, ct, cok, bc)
-    spec = None
-    if use_cells:
-        spec = gridx.fit_grid(cboxes, float(eps_sp), float(eps_t))
-        table = gridx.build_cell_table(spec, cboxes)
-        mask = gridx.candidate_tile_mask(
-            spec, table, rboxes, cboxes, eps_sp, eps_t)
-    else:
-        mask = gridx.exact_pair_mask(rboxes, cboxes, eps_sp, eps_t)
-    counts = jnp.sum(mask, axis=1).astype(jnp.int32)
-    return mask, counts, spec
+    return _tile_mask((rx, ry, rt, rok), (cx, cy, ct, cok), bp, bc,
+                      eps_sp, eps_t, use_cells)
 
 
 def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
@@ -129,19 +165,16 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
     grid, compacts the surviving candidate-tile lists to a static width
     ``K`` (``max_tiles`` or the observed maximum), then sweeps only those
     tiles.  Raises if ``max_tiles`` is too small to keep every survivor,
-    since dropping one would break parity.
+    since dropping one would break parity.  The planning is the host span
+    ``index.plan.join``, with the kept tiles, all tiles and ``K``.
     """
     bc, bm = _join_geometry(cand.x.shape[0], cand.x.shape[1], bc, bm)
-    mask, counts, _ = plan_join_index(
-        ref, cand, eps_sp, eps_t, bp=bp, bc=bc, use_cells=use_cells)
-
-    need = gridx.plan_max_tiles(counts)
-    K = max_tiles if max_tiles is not None else need
-    if int(np.max(np.asarray(counts), initial=0)) > K:
-        raise ValueError(
-            f"max_tiles={K} drops candidate tiles (need {need}); "
-            "the pruned join would no longer match the dense join")
-    tile_ids, counts = gridx.compact_candidates(mask, K)
+    with TraceAnnotation("index.plan.join") as span:
+        mask, counts, _ = plan_join_index(
+            ref, cand, eps_sp, eps_t, bp=bp, bc=bc, use_cells=use_cells)
+        tile_ids, counts, K, kept = _compact_tiles(mask, counts, max_tiles)
+        if TraceAnnotation.is_enabled():
+            span.set_metadata(kept=kept, total=mask.size, K=K)
     out = run_program(_join_sweep, ref, cand, eps_sp, eps_t, tile_ids,
                       bp=bp, bc=bc, bm=bm, interpret=interpret)
     if return_stats:
@@ -255,36 +288,30 @@ def plan_fused_tiles(rx, ry, rt, rvalid, cx, cy, ct, cvalid, eps_sp, eps_t,
     tracing: the sweep must run the exact tiling the tile ids were built
     for.  The autotuner (``repro.tune.autotune.tune_join``) sweeps this
     lattice rather than guessing.
-    """
-    M = rx.shape[1]
-    rows, bc, bm, padC, mc_pad = _fused_geometry(
-        rx.shape[0], M, cx.shape[0], cx.shape[1], rows, bc, bm)
-    bp = rows * M
-    frx, fry, frt, _, frok = _fused_ref_operands(
-        rx, ry, rt, rvalid, jnp.zeros((rx.shape[0],), jnp.int32), rows)
-    fcx, fcy, fct, _, fcok = _fused_cand_operands(
-        cx, cy, ct, cvalid, jnp.zeros((cx.shape[0],), jnp.int32), padC,
-        mc_pad)
 
-    rboxes = gridx.point_block_boxes(frx, fry, frt, frok, bp)
-    cboxes = gridx.traj_block_boxes(fcx, fcy, fct, fcok, bc)
-    if use_cells:
-        spec = gridx.fit_grid(cboxes, float(eps_sp), float(eps_t))
-        table = gridx.build_cell_table(spec, cboxes)
-        mask = gridx.candidate_tile_mask(
-            spec, table, rboxes, cboxes, eps_sp, eps_t)
-    else:
-        mask = gridx.exact_pair_mask(rboxes, cboxes, eps_sp, eps_t)
-    counts = jnp.sum(mask, axis=1).astype(jnp.int32)
-    need = gridx.plan_max_tiles(counts)
-    # K >= 1 even when nothing survives: a zero-width slot axis would give
-    # the kernels an empty grid and leave their accumulators uninitialized
-    K = max(max_tiles, 1) if max_tiles is not None else need
-    if int(np.max(np.asarray(counts), initial=0)) > K:
-        raise ValueError(
-            f"max_tiles={K} drops candidate tiles (need {need}); "
-            "the fused pruned sweep would no longer match the dense sweep")
-    tile_ids, _ = gridx.compact_candidates(mask, K)
+    The planning is the host span ``index.plan.sweep``, with the kept
+    tiles, all tiles, ``K`` and the resolved ``rows`` and ``bc``.
+    """
+    with TraceAnnotation("index.plan.sweep") as span:
+        M = rx.shape[1]
+        rows, bc, bm, padC, mc_pad = _fused_geometry(
+            rx.shape[0], M, cx.shape[0], cx.shape[1], rows, bc, bm)
+        frx, fry, frt, _, frok = _fused_ref_operands(
+            rx, ry, rt, rvalid, jnp.zeros((rx.shape[0],), jnp.int32), rows)
+        fcx, fcy, fct, _, fcok = _fused_cand_operands(
+            cx, cy, ct, cvalid, jnp.zeros((cx.shape[0],), jnp.int32), padC,
+            mc_pad)
+        mask, counts, _ = _tile_mask((frx, fry, frt, frok),
+                                     (fcx, fcy, fct, fcok), rows * M, bc,
+                                     eps_sp, eps_t, use_cells)
+        # K >= 1 even when nothing survives: a zero-width slot axis would
+        # give the kernels an empty grid and leave their accumulators
+        # uninitialized
+        tile_ids, _, K, kept = _compact_tiles(
+            mask, counts, None if max_tiles is None else max(max_tiles, 1))
+        if TraceAnnotation.is_enabled():
+            span.set_metadata(kept=kept, total=mask.size, K=K, rows=rows,
+                              bc=bc)
     return FusedTilePlan(tile_ids=tile_ids, rows=rows, bc=bc, bm=bm)
 
 

@@ -6,11 +6,14 @@ The dispatchers that plan on the host and then call jitted stage programs
 also lowered with the exact arguments it ran with, so a caller can
 inspect the programs that ran (for example, count the Pallas kernels in
 their compiled text) without rebuilding them.  Outside the block the
-call is made directly and nothing is lowered.
+call is made directly and nothing is lowered.  Each call, which only
+enqueues the program, is the host span ``dispatch.<fn.__name__>``.
 """
 from __future__ import annotations
 
 import contextlib
+
+from jax.profiler import TraceAnnotation
 
 _recorders: list[list] = []
 
@@ -23,7 +26,8 @@ def run_program(fn, *args, **kwargs):
         lowered = fn.lower(*args, **kwargs)
         for got in _recorders:
             got.append((fn.__name__, lowered))
-    return fn(*args, **kwargs)
+    with TraceAnnotation(f"dispatch.{fn.__name__}"):
+        return fn(*args, **kwargs)
 
 
 @contextlib.contextmanager
