@@ -21,10 +21,11 @@ neighborhood, which is what makes the coarse cell test below conservative.
 
 Pruning is two-staged and *conservative by construction*:
 
-1. coarse — candidate tiles are bucketed by the grid cell of their bbox
-   center (CSR-style: ``order``/``starts`` arrays, built under ``jit``);
-   a reference tile keeps the cells overlapping its bbox expanded by
-   ``eps + max tile half-extent`` per axis.
+1. coarse — each candidate tile is placed in the grid cell of its bbox
+   center (``cell_coords``); a reference tile keeps the cells overlapping
+   its bbox expanded by ``eps + max tile half-extent`` per axis.
+   ``build_cell_table`` also buckets the tiles into CSR-style
+   ``order``/``starts`` lists for consumers that gather per cell.
 2. exact  — surviving tiles are re-checked with the eps-expanded
    bounding-box distance test (Euclidean in space, interval in time), so a
    kept tile really can contain a matching point pair and a dropped tile
@@ -35,13 +36,24 @@ Because stage 2 never drops a tile that could match, the pruned join is
 while the surviving-tile count — the quantity ``benchmarks/kernel_bench.py``
 records — shrinks with data clustering exactly as the paper's Fig. 8 run
 does.
+
+Every function here is traceable except ``fit_grid`` and
+``build_cell_table``, which need the grid's cell counts static.  The
+planners (``repro.kernels.stjoin.ops.plan_join_index`` and
+``plan_fused_tiles``) therefore fit the grid on the device
+(``fit_grid_arrays``, traced origin, cell sizes and cell counts) and run
+each step as one compiled program: block boxes, grid and center cells,
+mask and counts, compaction at the static width ``K``.  The one host copy
+of a planning is its survivor counts, for ``K``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.utils.tree import pytree_dataclass
@@ -153,42 +165,77 @@ def traj_block_boxes(x, y, t, valid, block: int) -> TileBoxes:
 # grid fitting + CSR cell table
 # --------------------------------------------------------------------------
 
-def fit_grid(boxes: TileBoxes, eps_sp: float, eps_t: float, *,
-             max_cells_per_axis: int = 64) -> GridSpec:
-    """Host-side: derive a static GridSpec from concrete tile bboxes.
+@pytree_dataclass
+class GridFit:
+    """A ``GridSpec``'s fields as device scalars: the grid as fitted under
+    ``jit`` by ``fit_grid_arrays``.  ``cell_coords`` and
+    ``coarse_pair_mask`` take it in place of a ``GridSpec``."""
+
+    x0: jnp.ndarray      # [] float32
+    y0: jnp.ndarray
+    t0: jnp.ndarray
+    cell_sp: jnp.ndarray
+    cell_t: jnp.ndarray
+    nx: jnp.ndarray      # [] int32, in [1, max_cells_per_axis]
+    ny: jnp.ndarray
+    nt: jnp.ndarray
+
+
+def fit_grid_arrays(boxes: TileBoxes, eps_sp, eps_t, *,
+                    max_cells_per_axis: int = 64) -> GridFit:
+    """Fit the grid to the nonempty tiles' bboxes; traceable, so a planner
+    fits it inside its compiled program with ``eps_sp``/``eps_t`` traced.
 
     Cell sizes start at the matching thresholds (``eps_sp``, ``eps_t``) and
     are coarsened only when the domain would need more than
     ``max_cells_per_axis`` cells on some axis.  Empty inputs yield a 1-cell
-    grid.
+    grid at the origin.
     """
-    ne = np.asarray(boxes.nonempty)
-    eps_sp = float(eps_sp)
-    eps_t = float(eps_t)
+    ne = boxes.nonempty
+    some = jnp.any(ne)
+    max_n = jnp.float32(max_cells_per_axis)
 
     def axis(lo_a, hi_a, base):
-        if not ne.any():
-            return 0.0, max(base, 1e-6), 1
-        lo = float(np.min(np.asarray(lo_a)[ne]))
-        hi = float(np.max(np.asarray(hi_a)[ne]))
-        cell = max(base, 1e-6)
-        extent = max(hi - lo, 0.0)
-        n = int(np.floor(extent / cell)) + 1
-        if n > max_cells_per_axis:
-            cell = extent / max_cells_per_axis * (1 + 1e-6)
-            n = int(np.floor(extent / cell)) + 1
-        return lo, cell, n
+        lo = jnp.where(some, jnp.min(jnp.where(ne, lo_a, jnp.inf)), 0.0)
+        hi = jnp.where(some, jnp.max(jnp.where(ne, hi_a, -jnp.inf)), 0.0)
+        cell = jnp.maximum(jnp.asarray(base, jnp.float32), 1e-6)
+        extent = jnp.maximum(hi - lo, 0.0)
+        coarsen = jnp.floor(extent / cell) + 1 > max_n
+        cell = jnp.where(coarsen, extent / max_n * (1 + 1e-6), cell)
+        n = jnp.minimum(jnp.floor(extent / cell) + 1, max_n)
+        return lo, cell, n.astype(jnp.int32)
 
     x0, csx, nx = axis(boxes.xmin, boxes.xmax, eps_sp)
     y0, csy, ny = axis(boxes.ymin, boxes.ymax, eps_sp)
     t0, cst, nt = axis(boxes.tmin, boxes.tmax, eps_t)
     # one spatial resolution for both axes (square cells)
-    cell_sp = max(csx, csy)
-    return GridSpec(x0=x0, y0=y0, t0=t0, cell_sp=cell_sp, cell_t=cst,
-                    nx=nx, ny=ny, nt=nt)
+    return GridFit(x0=x0, y0=y0, t0=t0, cell_sp=jnp.maximum(csx, csy),
+                   cell_t=cst, nx=nx, ny=ny, nt=nt)
 
 
-def _center_coords(spec: GridSpec, boxes: TileBoxes):
+@functools.partial(jax.jit, static_argnames=("max_cells_per_axis",))
+def _fit_grid_packed(boxes, eps_sp, eps_t, *, max_cells_per_axis):
+    fit = fit_grid_arrays(boxes, eps_sp, eps_t,
+                          max_cells_per_axis=max_cells_per_axis)
+    return jnp.stack([v.astype(jnp.float32) for v in jax.tree.leaves(fit)])
+
+
+def fit_grid(boxes: TileBoxes, eps_sp: float, eps_t: float, *,
+             max_cells_per_axis: int = 64) -> GridSpec:
+    """Static ``GridSpec`` of the grid ``fit_grid_arrays`` fits, for
+    consumers that need static cell counts (``build_cell_table``).
+
+    The fit runs compiled on the device; its eight numbers come to the
+    host in one copy (the cell counts are at most ``max_cells_per_axis``,
+    exact in float32).
+    """
+    x0, y0, t0, cell_sp, cell_t, nx, ny, nt = np.asarray(_fit_grid_packed(
+        boxes, eps_sp, eps_t, max_cells_per_axis=max_cells_per_axis)).tolist()
+    return GridSpec(x0=x0, y0=y0, t0=t0, cell_sp=cell_sp, cell_t=cell_t,
+                    nx=int(nx), ny=int(ny), nt=int(nt))
+
+
+def _center_coords(spec, boxes: TileBoxes):
     """Integer cell coords of each tile's bbox center, clipped into range."""
     def quant(lo, hi, origin, cell, n):
         center = 0.5 * (lo + hi)
@@ -201,24 +248,30 @@ def _center_coords(spec: GridSpec, boxes: TileBoxes):
     return ix, iy, it
 
 
+def cell_coords(spec, boxes: TileBoxes) -> jnp.ndarray:
+    """[n, 3] int32 ``(ix, iy, it)`` cell of each tile's bbox center, -1
+    for empty tiles.  ``spec`` is a ``GridSpec`` or a traced ``GridFit``."""
+    coords = jnp.stack(_center_coords(spec, boxes), axis=-1)
+    return jnp.where(boxes.nonempty[:, None], coords, -1)
+
+
 def build_cell_table(spec: GridSpec, boxes: TileBoxes) -> CellTable:
     """Bucket tiles into grid cells; CSR arrays built under ``jit``.
 
     The pruning queries below consume only ``coords`` (vectorized cell
-    range tests); the ``order``/``starts`` CSR lists exist for consumers
-    that gather per-cell tile lists directly — the planned segmentation
-    neighbor masks and similarity scatter (ROADMAP).
+    range tests), and the planners compute those alone (``cell_coords``);
+    the ``order``/``starts`` CSR lists exist for consumers that gather
+    per-cell tile lists directly — the planned segmentation neighbor
+    masks and similarity scatter (ROADMAP).
     """
-    n = boxes.num_tiles
-    ix, iy, it = _center_coords(spec, boxes)
+    coords = cell_coords(spec, boxes)
+    ix, iy, it = coords[:, 0], coords[:, 1], coords[:, 2]
     cell = (ix * spec.ny + iy) * spec.nt + it
     cell = jnp.where(boxes.nonempty, cell, spec.num_cells)  # park empties
     order = jnp.argsort(cell, stable=True).astype(jnp.int32)
     sorted_cells = cell[order]
     starts = jnp.searchsorted(
         sorted_cells, jnp.arange(spec.num_cells + 1)).astype(jnp.int32)
-    coords = jnp.stack([ix, iy, it], axis=-1).astype(jnp.int32)
-    coords = jnp.where(boxes.nonempty[:, None], coords, -1)
     return CellTable(order=order, starts=starts,
                      cell_of=cell.astype(jnp.int32), coords=coords)
 
@@ -255,14 +308,16 @@ def exact_pair_mask(ref: TileBoxes, cand: TileBoxes, eps_sp, eps_t):
     return ok & ref.nonempty[:, None] & cand.nonempty[None, :]
 
 
-def coarse_pair_mask(spec: GridSpec, table: CellTable, ref: TileBoxes,
-                     cand: TileBoxes, eps_sp, eps_t):
+def coarse_pair_mask(spec, coords, ref: TileBoxes, cand: TileBoxes,
+                     eps_sp, eps_t):
     """[nR, nC] bool coarse cell test (conservative superset of exact).
 
-    A candidate tile is kept when its *center cell* lies inside the ref
-    tile's bbox expanded by ``eps`` plus the fleet-wide max candidate tile
-    half-extent — the slack that makes center-bucketing safe for tiles
-    that straddle cell boundaries.
+    A candidate tile is kept when its *center cell* (``coords``, from
+    ``cell_coords``) lies inside the ref tile's bbox expanded by ``eps``
+    plus the fleet-wide max candidate tile half-extent — the slack that
+    makes center-bucketing safe for tiles that straddle cell boundaries.
+    Cell quantization is monotone, so the test stays conservative for any
+    origin and cell size: ``spec`` may be a ``GridSpec`` or a ``GridFit``.
     """
     ext = lambda lo, hi: jnp.where(cand.nonempty, hi - lo, 0.0)
     half_x = 0.5 * jnp.max(ext(cand.xmin, cand.xmax), initial=0.0)
@@ -283,17 +338,17 @@ def coarse_pair_mask(spec: GridSpec, table: CellTable, ref: TileBoxes,
     tlo, thi = rng(ref.tmin, ref.tmax, eps_t + half_t,
                    spec.t0, spec.cell_t, spec.nt)
 
-    cc = table.coords                              # [nC, 3]
+    cc = coords                                    # [nC, 3]
     inx = (cc[None, :, 0] >= xlo[:, None]) & (cc[None, :, 0] <= xhi[:, None])
     iny = (cc[None, :, 1] >= ylo[:, None]) & (cc[None, :, 1] <= yhi[:, None])
     int_ = (cc[None, :, 2] >= tlo[:, None]) & (cc[None, :, 2] <= thi[:, None])
     return inx & iny & int_ & ref.nonempty[:, None] & cand.nonempty[None, :]
 
 
-def candidate_tile_mask(spec: GridSpec, table: CellTable, ref: TileBoxes,
-                        cand: TileBoxes, eps_sp, eps_t):
+def candidate_tile_mask(spec, coords, ref: TileBoxes, cand: TileBoxes,
+                        eps_sp, eps_t):
     """Coarse cell test refined by the exact eps-expanded bbox test."""
-    coarse = coarse_pair_mask(spec, table, ref, cand, eps_sp, eps_t)
+    coarse = coarse_pair_mask(spec, coords, ref, cand, eps_sp, eps_t)
     return coarse & exact_pair_mask(ref, cand, eps_sp, eps_t)
 
 

@@ -54,7 +54,7 @@ def _join_geometry(C: int, Mc: int, bc: int, bm: int):
 
 def _padded_operands(ref: TrajectoryBatch, cand: TrajectoryBatch,
                      bp: int, bc: int, bm: int):
-    """The materializing join's padding, shared with its index planner."""
+    """The materializing join's padding."""
     T, M = ref.x.shape
     rx = _pad_to(ref.x.reshape(-1), bp, 0, 0.0)
     ry = _pad_to(ref.y.reshape(-1), bp, 0, 0.0)
@@ -92,38 +92,76 @@ def best_match_join_kernel(ref: TrajectoryBatch, cand: TrajectoryBatch,
                        interpret=interpret)
 
 
-def _tile_mask(ref_pts, cand_rows, bp: int, bc: int, eps_sp, eps_t,
-               use_cells: bool):
-    """The grid index over one planning's padded operands: ``ref_pts``
-    ``(x, y, t, ok)`` flat ``[P]`` in blocks of ``bp`` points, ``cand_rows``
-    ``(x, y, t, ok)`` ``[C, Mc]`` in blocks of ``bc`` rows.
+@functools.partial(jax.jit, static_argnames=("bp", "bc", "pad_c"))
+def _plan_boxes(rx, ry, rt, rok, cx, cy, ct, cok, *, bp, bc, pad_c):
+    """Both sides' block boxes for one planning: the ``[T, M]`` reference
+    points flattened and padded to blocks of ``bp``, the ``[C, Mc]``
+    candidate rows padded by ``pad_c`` rows, in blocks of ``bc`` rows.
+    Padding is invalid, so it never widens a box."""
+    ref = lambda a, fill: _pad_to(a.reshape(-1), bp, 0, fill)
+    cand = lambda a, fill: jnp.pad(a, ((0, pad_c), (0, 0)),
+                                   constant_values=fill)
+    return (gridx.point_block_boxes(ref(rx, 0.0), ref(ry, 0.0), ref(rt, 0.0),
+                                    ref(rok, False), bp),
+            gridx.traj_block_boxes(cand(cx, 0.0), cand(cy, 0.0),
+                                   cand(ct, 0.0), cand(cok, False), bc))
 
-    Block boxes, then the grid fitted to them and its cell table (with
-    ``use_cells``), then the candidate-tile mask, each in its host span.
-    Returns ``(mask [nRb, nCb] bool, counts [nRb] i32, spec | None)``.
+
+@jax.jit
+def _plan_grid(cboxes, eps_sp, eps_t):
+    """The grid fitted to the candidate blocks, and their center cells."""
+    grid = gridx.fit_grid_arrays(cboxes, eps_sp, eps_t)
+    return grid, gridx.cell_coords(grid, cboxes)
+
+
+@jax.jit
+def _plan_mask(rboxes, cboxes, eps_sp, eps_t, grid, coords):
+    """The candidate-tile mask (the coarse cell test and the exact test,
+    or the exact test alone when ``grid`` is None) and its row counts."""
+    if grid is None:
+        mask = gridx.exact_pair_mask(rboxes, cboxes, eps_sp, eps_t)
+    else:
+        mask = gridx.candidate_tile_mask(grid, coords, rboxes, cboxes,
+                                         eps_sp, eps_t)
+    return mask, jnp.sum(mask, axis=1).astype(jnp.int32)
+
+
+_plan_compact = jax.jit(gridx.compact_candidates,
+                        static_argnames="max_tiles")
+
+
+def _tile_mask(ref_pts, cand_rows, bp: int, bc: int, pad_c: int, eps_sp,
+               eps_t, use_cells: bool):
+    """The grid index over one planning's operands: ``ref_pts``
+    ``(x, y, t, ok)`` ``[T, M]`` in blocks of ``bp`` points, ``cand_rows``
+    ``(x, y, t, ok)`` ``[C, Mc]`` padded by ``pad_c`` rows, in blocks of
+    ``bc`` rows.
+
+    Block boxes, then the grid fitted to them and the candidates' center
+    cells (with ``use_cells``), then the candidate-tile mask: each one
+    compiled program in its host span, ``eps_sp``/``eps_t`` traced, so a
+    new batch of the same shape compiles nothing.  Nothing comes to the
+    host.  Returns ``(mask [nRb, nCb] bool, counts [nRb] i32,
+    grid | None)``, the grid a ``GridFit`` on the device.
     """
     with TraceAnnotation("index.boxes"):
-        rboxes = gridx.point_block_boxes(*ref_pts, bp)
-        cboxes = gridx.traj_block_boxes(*cand_rows, bc)
-    spec = None
+        rboxes, cboxes = _plan_boxes(*ref_pts, *cand_rows, bp=bp, bc=bc,
+                                     pad_c=pad_c)
+    grid = coords = None
     if use_cells:
         with TraceAnnotation("index.grid"):
-            spec = gridx.fit_grid(cboxes, float(eps_sp), float(eps_t))
-            table = gridx.build_cell_table(spec, cboxes)
+            grid, coords = _plan_grid(cboxes, eps_sp, eps_t)
     with TraceAnnotation("index.mask"):
-        if use_cells:
-            mask = gridx.candidate_tile_mask(
-                spec, table, rboxes, cboxes, eps_sp, eps_t)
-        else:
-            mask = gridx.exact_pair_mask(rboxes, cboxes, eps_sp, eps_t)
-        counts = jnp.sum(mask, axis=1).astype(jnp.int32)
-    return mask, counts, spec
+        mask, counts = _plan_mask(rboxes, cboxes, eps_sp, eps_t, grid,
+                                  coords)
+    return mask, counts, grid
 
 
 def _compact_tiles(mask, counts, K: int | None):
     """Compact ``mask`` to each row block's surviving tile ids, ``K`` wide
-    (``None``: the widest list).  ``counts`` comes to the host once, for
-    the width, the check that ``K`` drops no tile, and the kept count.
+    (``None``: the widest list).  ``counts`` comes to the host once, the
+    planning's one host copy, for the width, the check that ``K`` drops no
+    tile, and the kept count; the compaction is one program a width.
     Returns ``(tile_ids [nRb, K], counts, K, kept)``."""
     with TraceAnnotation("index.compact"):
         host = np.asarray(counts)
@@ -133,7 +171,7 @@ def _compact_tiles(mask, counts, K: int | None):
             raise ValueError(
                 f"max_tiles={K} drops candidate tiles (need {need}); the "
                 "pruned sweep would no longer match the dense sweep")
-        tile_ids, counts = gridx.compact_candidates(mask, K)
+        tile_ids, counts = _plan_compact(mask, max_tiles=K)
     return tile_ids, counts, K, int(host.sum())
 
 
@@ -141,16 +179,17 @@ def plan_join_index(ref: TrajectoryBatch, cand: TrajectoryBatch,
                     eps_sp, eps_t, *, bp=256, bc=128, use_cells: bool = True):
     """Candidate-tile mask + per-ref-block survivor counts.
 
-    Host-driven (not jitted as a whole: the grid geometry is fitted from
-    the concrete data, and baking it in as a static jit argument would
-    retrace on every new batch).  The array math inside is plain jnp.
-    ``bc`` is the resolved candidate tile.
-    Returns ``(mask [nRb, nCb] bool, counts [nRb] i32, spec | None)``.
+    The materializing join's block geometry: reference points in blocks
+    of ``bp``, candidates in blocks of ``bc`` rows (the resolved tile).
+    The planning's steps are compiled programs that fit the grid on the
+    device (see ``_tile_mask``); nothing comes to the host here, and the
+    one host copy is the caller's, of ``counts``, for the width ``K``.
+    Returns ``(mask [nRb, nCb] bool, counts [nRb] i32, grid | None)``,
+    ``grid`` the fitted ``GridFit`` (None without ``use_cells``).
     """
-    (rx, ry, rt, _, rok), (cx, cy, ct, _, cok) = _padded_operands(
-        ref, cand, bp, bc, 1)
-    return _tile_mask((rx, ry, rt, rok), (cx, cy, ct, cok), bp, bc,
-                      eps_sp, eps_t, use_cells)
+    return _tile_mask((ref.x, ref.y, ref.t, ref.valid),
+                      (cand.x, cand.y, cand.t, cand.valid), bp, bc,
+                      (-cand.x.shape[0]) % bc, eps_sp, eps_t, use_cells)
 
 
 def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
@@ -161,11 +200,12 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
                            return_stats: bool = False):
     """Index-pruned best-match join; bit-identical to the dense kernel.
 
-    Host-driven planning (concrete inputs required): fits the eps-derived
+    Plans on the device (concrete inputs required): fits the eps-derived
     grid, compacts the surviving candidate-tile lists to a static width
-    ``K`` (``max_tiles`` or the observed maximum), then sweeps only those
-    tiles.  Raises if ``max_tiles`` is too small to keep every survivor,
-    since dropping one would break parity.  The planning is the host span
+    ``K`` (``max_tiles`` or the observed maximum, which needs the one host
+    copy of the survivor counts), then sweeps only those tiles.  Raises if
+    ``max_tiles`` is too small to keep every survivor, since dropping one
+    would break parity.  The planning is the host span
     ``index.plan.join``, with the kept tiles, all tiles and ``K``.
     """
     bc, bm = _join_geometry(cand.x.shape[0], cand.x.shape[1], bc, bm)
@@ -268,14 +308,15 @@ def _resolve_plan(tile_ids, rows: int, bc: int, bm: int):
 def plan_fused_tiles(rx, ry, rt, rvalid, cx, cy, ct, cvalid, eps_sp, eps_t,
                      *, rows: int | None = None, bc: int = 128, bm: int = 128,
                      use_cells: bool = True, max_tiles: int | None = None):
-    """Host-driven candidate-tile plan for the fused kernels.
+    """Candidate-tile plan for the fused kernels.
 
-    Same two-stage conservative pruning as ``plan_join_index`` but on raw
-    ``[T, M]`` / ``[C, Mc]`` arrays with the fused row-aligned block
-    geometry.  Returns a ``FusedTilePlan`` (tile ids -1 padded, ascending,
-    plus the resolved geometry) ready for the fused entry points, which
-    reject a plan whose geometry differs from their own.  Raises if
-    ``max_tiles`` would drop a survivor.
+    Same two-stage conservative pruning as ``plan_join_index``, the same
+    compiled steps and one host copy, but on raw ``[T, M]`` / ``[C, Mc]``
+    arrays with the fused row-aligned block geometry (a reference block is
+    ``rows`` whole rows).  Returns a ``FusedTilePlan`` (tile ids -1
+    padded, ascending, plus the resolved geometry) ready for the fused
+    entry points, which reject a plan whose geometry differs from their
+    own.  Raises if ``max_tiles`` would drop a survivor.
 
     Geometry knobs (``rows``, ``bc``, ``bm``) are the fused tile plan of
     ``EnginePlan.fused_tiles`` (DESIGN.md §9): ``rows`` reference-trajectory
@@ -294,15 +335,10 @@ def plan_fused_tiles(rx, ry, rt, rvalid, cx, cy, ct, cvalid, eps_sp, eps_t,
     """
     with TraceAnnotation("index.plan.sweep") as span:
         M = rx.shape[1]
-        rows, bc, bm, padC, mc_pad = _fused_geometry(
+        rows, bc, bm, padC, _ = _fused_geometry(
             rx.shape[0], M, cx.shape[0], cx.shape[1], rows, bc, bm)
-        frx, fry, frt, _, frok = _fused_ref_operands(
-            rx, ry, rt, rvalid, jnp.zeros((rx.shape[0],), jnp.int32), rows)
-        fcx, fcy, fct, _, fcok = _fused_cand_operands(
-            cx, cy, ct, cvalid, jnp.zeros((cx.shape[0],), jnp.int32), padC,
-            mc_pad)
-        mask, counts, _ = _tile_mask((frx, fry, frt, frok),
-                                     (fcx, fcy, fct, fcok), rows * M, bc,
+        mask, counts, _ = _tile_mask((rx, ry, rt, rvalid),
+                                     (cx, cy, ct, cvalid), rows * M, bc, padC,
                                      eps_sp, eps_t, use_cells)
         # K >= 1 even when nothing survives: a zero-width slot axis would
         # give the kernels an empty grid and leave their accumulators

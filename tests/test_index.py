@@ -79,7 +79,8 @@ def test_coarse_mask_is_superset_of_exact():
     cb = gridx.traj_block_boxes(cand.x, cand.y, cand.t, cand.valid, 2)
     spec = gridx.fit_grid(cb, 2.0, 10.0)
     table = gridx.build_cell_table(spec, cb)
-    coarse = np.asarray(gridx.coarse_pair_mask(spec, table, rb, cb, 2.0, 10.0))
+    coarse = np.asarray(gridx.coarse_pair_mask(spec, table.coords, rb, cb,
+                                               2.0, 10.0))
     exact = np.asarray(gridx.exact_pair_mask(rb, cb, 2.0, 10.0))
     assert (coarse | ~exact).all()      # exact => coarse
 
