@@ -64,11 +64,11 @@ def bench_stjoin_pruned(smoke: bool = False, out_dir: str = ".") -> dict:
     bp, bc, bm = (32, 2, 32) if smoke else (64, 2, 64)
 
     kw = dict(bp=bp, bc=bc, bm=bm)
-    d_secs, dense = time_fn(best_match_join_kernel, batch, batch,
-                            eps_sp, eps_t, iters=2, **kw)
+    d_secs, (dense, _) = time_fn(best_match_join_kernel, batch, batch,
+                                 eps_sp, eps_t, iters=2, **kw)
     p_secs, out = time_fn(best_match_join_pruned, batch, batch,
                           eps_sp, eps_t, iters=2, return_stats=True, **kw)
-    pruned, stats = out
+    (pruned, _), stats = out
 
     parity = (np.array_equal(np.asarray(dense.best_w),
                              np.asarray(pruned.best_w))
@@ -611,7 +611,7 @@ def bench_pipeline(smoke: bool = False, out_dir: str = ".") -> dict:
     stages: dict[str, dict] = {"materialize": {}, "fused": {}}
 
     join, join_secs, hlo_join = measure_compiled(
-        lambda b: subtrajectory_join(b, b, eps_sp, eps_t, delta_t),
+        lambda b: subtrajectory_join(b, b, eps_sp, eps_t, delta_t)[0],
         (batch,), iters=iters)
     stages["materialize"]["join"] = join_secs * 1e6
     (vote, masks), c_secs, _ = measure_compiled(

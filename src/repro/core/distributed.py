@@ -307,8 +307,8 @@ class _DSCProgramBuilder:
             ref = TrajectoryBatch(x=px, y=py, t=pt, valid=pv,
                                   traj_id=traj_id)
             cand = TrajectoryBatch(x=kx, y=ky, t=kt, valid=kv, traj_id=cid)
-            j = best_match_join_kernel(ref, cand, params.eps_sp,
-                                       params.eps_t)
+            j, _ = best_match_join_kernel(ref, cand, params.eps_sp,
+                                          params.eps_t)
             return (j.best_w.reshape(T * Mp, Tc),
                     j.best_idx.reshape(T * Mp, Tc))
         from repro.kernels.stjoin.ref import stjoin_ref

@@ -60,6 +60,10 @@ class DSCOutput:
     result: ClusteringResult
     sscr: jnp.ndarray               # Eq. 3 objective
     rmse: jnp.ndarray               # Sec. 6.2 quality metric
+    # [2] i32 on the device: the join's matches before and after DTJ's
+    # delta_t refine, counted in the index-pruned Pallas join; None at
+    # delta_t 0 and on the other paths
+    join_matches: jnp.ndarray | None = None
 
 
 # --------------------------------------------------------------------------
@@ -190,7 +194,7 @@ def _join_vote_materialize_body(batch, params, plan: EnginePlan):
     with jax.named_scope("dsc.join"):
         if plan.use_kernel:
             from repro.kernels.stjoin import ops as stjoin_ops
-            join = stjoin_ops.subtrajectory_join(
+            join, _ = stjoin_ops.subtrajectory_join(
                 batch, batch, params.eps_sp, params.eps_t, params.delta_t)
         else:
             join = geometry.subtrajectory_join(
@@ -449,14 +453,15 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
             if p.mode == "fused":
                 return run_program(_run_dsc_fused, batch, params, tile_ids, p)
             if p.use_index and p.use_kernel:
-                # grid-pruned Pallas join: host-side planning pass, then
-                # jitted tail
+                # grid-pruned Pallas join (delta_t refine in the kernel):
+                # host-side planning pass, then jitted tail
                 from repro.kernels.stjoin import ops as stjoin_ops
-                join = stjoin_ops.subtrajectory_join(
+                join, matches = stjoin_ops.subtrajectory_join(
                     batch, batch, params.eps_sp, params.eps_t, params.delta_t,
                     use_index=True)
-                return run_program(_run_dsc_from_join, batch, params, join,
-                                   tile_ids, p)
+                out = run_program(_run_dsc_from_join, batch, params, join,
+                                  tile_ids, p)
+                return out.replace(join_matches=matches)
             return run_program(_run_dsc_materialize, batch, params, p)
 
         attempts, out = 1, dispatch(k)

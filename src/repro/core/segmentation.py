@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core.types import SubtrajSegmentation
 from repro.core.windows import sliding_reduce, window_pair
+from repro.kernels import exact_ratio
 
 
 def _window_means(sig: jnp.ndarray, valid: jnp.ndarray, w: int):
@@ -164,7 +165,9 @@ def tsa2_signal(packed_masks: jnp.ndarray, w: int, *,
     ``impl="packed"`` is the production packed-word engine;
     ``impl="bitplane"`` the retained 32x-expanded chunked oracle.  Both
     produce bit-identical ``d`` (same integer counts, same float ops) —
-    the bench gates on exactly that plus the structural memory win.
+    the bench gates on exactly that plus the structural memory win.  The
+    ratio is correctly rounded (``repro.kernels.exact_ratio``), as in the
+    Pallas kernel, for at most ``RATIO_MAX_COUNT`` set members.
     """
     if impl == "packed":
         inter, union = _window_overlap_counts(packed_masks, w)
@@ -174,7 +177,9 @@ def tsa2_signal(packed_masks: jnp.ndarray, w: int, *,
         raise ValueError(f"unknown tsa2 signal impl {impl!r}")
     inter = inter.astype(jnp.float32)
     union = union.astype(jnp.float32)
-    return jnp.where(union > 0, 1.0 - inter / jnp.maximum(union, 1.0), 0.0)
+    return jnp.where(union > 0,
+                     1.0 - exact_ratio(inter, jnp.maximum(union, 1.0),
+                                       32 * packed_masks.shape[-1]), 0.0)
 
 
 def tsa2(packed_masks: jnp.ndarray, valid: jnp.ndarray, w: int, tau,

@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import default_interpret, shift_points as _shift
+from repro.kernels import (default_interpret, exact_ratio,
+                           shift_points as _shift)
 
 
 def _window_or(masks, w: int, sign: int):
@@ -58,7 +59,9 @@ def _kernel(masks_ref, out_d_ref, *, w: int):
     inter = jnp.sum(pc(l1 & l2), axis=-1).astype(jnp.float32)
     union = jnp.sum(pc(l1 | l2), axis=-1).astype(jnp.float32)
     out_d_ref[...] = jnp.where(
-        union > 0, 1.0 - inter / jnp.maximum(union, 1.0), 0.0)
+        union > 0,
+        1.0 - exact_ratio(inter, jnp.maximum(union, 1.0),
+                          32 * masks_ref.shape[-1]), 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "bt", "interpret"))
@@ -67,7 +70,9 @@ def jaccard_pallas(masks: jnp.ndarray, *, w: int, bt: int = 8,
     """[T, M, W] packed masks -> [T, M] window Jaccard dissimilarity.
 
     The words are carried as int32 (Mosaic reduces no unsigned type);
-    OR, shifts and popcount are bit-for-bit the same on either.
+    OR, shifts and popcount are bit-for-bit the same on either.  The
+    ratio is correctly rounded (``exact_ratio``) for sets of at most
+    ``RATIO_MAX_COUNT`` members (``W <= 1024``).
     """
     if interpret is None:
         interpret = default_interpret()

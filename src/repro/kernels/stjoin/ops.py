@@ -5,6 +5,8 @@ These wrappers own the padding and the tile geometry, so the kernels in
 
 * ``best_match_join_kernel``  — the dense materializing join: every (ref
   block, cand block) tile is visited.  Fallback and parity oracle.
+  With ``delta_t > 0`` DTJ's run refine runs in the kernel and the
+  matches are counted before and after it.
 * ``best_match_join_pruned``  — index-accelerated join: a spatiotemporal
   grid over tile bounding boxes (``repro.index.grid``) first emits, per
   reference block, the compacted list of candidate tiles that can contain
@@ -24,10 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
-from repro.core.geometry import refine_join
 from repro.core.types import JoinResult, TrajectoryBatch
 from repro.index import grid as gridx
 from repro.kernels.stjoin.stjoin import (
+    stjoin_dtj_pallas,
     stjoin_pallas,
     stjoin_sim_fused_flat,
     stjoin_sim_panel_flat,
@@ -71,25 +73,49 @@ def _padded_operands(ref: TrajectoryBatch, cand: TrajectoryBatch,
     return (rx, ry, rt, rid, rok), (cx, cy, ct, cid, cok)
 
 
-@functools.partial(jax.jit, static_argnames=("bp", "bc", "bm", "interpret"))
-def _join_sweep(ref, cand, eps_sp, eps_t, tile_ids, *, bp, bc, bm,
+def _row_block(T: int, M: int, bp: int, delta_t) -> int:
+    """The join's reference block: ``bp`` points, or, with the delta_t
+    refine, as many whole rows of ``M`` as fit in ``bp`` (at least one, at
+    most ``T``), so that a run never spans two blocks."""
+    if float(delta_t) <= 0:
+        return bp
+    return max(1, min(bp // max(M, 1), T)) * M
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("bp", "bc", "bm", "delta_t", "interpret"))
+def _join_sweep(ref, cand, eps_sp, eps_t, tile_ids, *, bp, bc, bm, delta_t,
                 interpret):
+    """``(JoinResult [T, M, C], matches)``: the Pallas join, refined in the
+    kernel when ``delta_t > 0`` (``bp`` whole rows, ``_row_block``), with
+    ``matches`` ``[2]`` i32 the count before and after the refine, else
+    None."""
     T, M = ref.x.shape
     C = cand.x.shape[0]
     ref_ops, cand_ops = _padded_operands(ref, cand, bp, bc, bm)
-    w, idx = stjoin_pallas(*ref_ops, *cand_ops, eps_sp, eps_t, tile_ids,
-                           bp=bp, bc=bc, bm=bm, interpret=interpret)
+    matches = None
+    if delta_t > 0:
+        w, idx, matches = stjoin_dtj_pallas(
+            *ref_ops, *cand_ops, eps_sp, eps_t, delta_t, tile_ids,
+            rows=bp // M, M=M, bc=bc, bm=bm, interpret=interpret)
+    else:
+        w, idx = stjoin_pallas(*ref_ops, *cand_ops, eps_sp, eps_t, tile_ids,
+                               bp=bp, bc=bc, bm=bm, interpret=interpret)
     return JoinResult(best_w=w[:T * M, :C].reshape(T, M, C),
-                      best_idx=idx[:T * M, :C].reshape(T, M, C))
+                      best_idx=idx[:T * M, :C].reshape(T, M, C)), matches
 
 
 def best_match_join_kernel(ref: TrajectoryBatch, cand: TrajectoryBatch,
-                           eps_sp, eps_t, *, bp=256, bc=128, bm=128,
-                           interpret: bool | None = None) -> JoinResult:
-    """Dense Pallas join: ``JoinResult [T, M, C]``."""
+                           eps_sp, eps_t, delta_t=0.0, *, bp=256, bc=128,
+                           bm=128, interpret: bool | None = None):
+    """Dense Pallas join: ``(JoinResult [T, M, C], matches)``, refined in
+    the kernel when ``delta_t`` (concrete) is above 0, ``matches`` then
+    the ``[2]`` i32 count of matches before and after the refine on the
+    device, else None (see ``_join_sweep``)."""
     bc, bm = _join_geometry(cand.x.shape[0], cand.x.shape[1], bc, bm)
-    return _join_sweep(ref, cand, eps_sp, eps_t, None, bp=bp, bc=bc, bm=bm,
-                       interpret=interpret)
+    return _join_sweep(ref, cand, eps_sp, eps_t, None,
+                       bp=_row_block(*ref.x.shape, bp, delta_t), bc=bc,
+                       bm=bm, delta_t=float(delta_t), interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bp", "bc", "pad_c"))
@@ -193,12 +219,14 @@ def plan_join_index(ref: TrajectoryBatch, cand: TrajectoryBatch,
 
 
 def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
-                           eps_sp, eps_t, *, bp=256, bc=128, bm=128,
-                           max_tiles: int | None = None,
+                           eps_sp, eps_t, delta_t=0.0, *, bp=256, bc=128,
+                           bm=128, max_tiles: int | None = None,
                            use_cells: bool = True,
                            interpret: bool | None = None,
                            return_stats: bool = False):
-    """Index-pruned best-match join; bit-identical to the dense kernel.
+    """Index-pruned best-match join; bit-identical to the dense kernel,
+    ``(JoinResult, matches)`` as ``best_match_join_kernel`` (with
+    ``return_stats``, ``((JoinResult, matches), stats)``).
 
     Plans on the device (concrete inputs required): fits the eps-derived
     grid, compacts the surviving candidate-tile lists to a static width
@@ -209,6 +237,7 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
     ``index.plan.join``, with the kept tiles, all tiles and ``K``.
     """
     bc, bm = _join_geometry(cand.x.shape[0], cand.x.shape[1], bc, bm)
+    bp = _row_block(*ref.x.shape, bp, delta_t)
     with TraceAnnotation("index.plan.join") as span:
         mask, counts, _ = plan_join_index(
             ref, cand, eps_sp, eps_t, bp=bp, bc=bc, use_cells=use_cells)
@@ -216,7 +245,8 @@ def best_match_join_pruned(ref: TrajectoryBatch, cand: TrajectoryBatch,
         if TraceAnnotation.is_enabled():
             span.set_metadata(kept=kept, total=mask.size, K=K)
     out = run_program(_join_sweep, ref, cand, eps_sp, eps_t, tile_ids,
-                      bp=bp, bc=bc, bm=bm, interpret=interpret)
+                      bp=bp, bc=bc, bm=bm, delta_t=float(delta_t),
+                      interpret=interpret)
     if return_stats:
         return out, gridx.prune_stats(counts, mask.shape[1])
     return out
@@ -503,14 +533,13 @@ def stjoin_sim_panel_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
 
 def subtrajectory_join(ref: TrajectoryBatch, cand: TrajectoryBatch,
                        eps_sp, eps_t, delta_t=0.0, *, use_index: bool = False,
-                       **kw) -> JoinResult:
-    """Kernel-backed Problem 1 (join + delta_t refine).
+                       **kw):
+    """Kernel-backed Problem 1: ``(JoinResult, matches)``, the join with
+    DTJ's delta_t refine applied in the kernel (``delta_t`` concrete; 0
+    compiles the plain join, and ``matches`` is None).
 
     ``use_index=True`` routes through the grid-pruned kernel (requires
     concrete inputs for the host-side planning pass); output is identical.
     """
-    if use_index:
-        j = best_match_join_pruned(ref, cand, eps_sp, eps_t, **kw)
-    else:
-        j = best_match_join_kernel(ref, cand, eps_sp, eps_t, **kw)
-    return refine_join(j, ref.t, delta_t)
+    join = best_match_join_pruned if use_index else best_match_join_kernel
+    return join(ref, cand, eps_sp, eps_t, delta_t, **kw)

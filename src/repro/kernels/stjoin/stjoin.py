@@ -46,6 +46,9 @@ Kernels
 * ``stjoin_pallas``          the materializing join: ``(best_w, best_idx)
                              [P, C]``; the grid's last axis walks ``bm``
                              candidate-point chunks.
+* ``stjoin_dtj_pallas``      the same join with the delta_t run refine
+                             applied in-kernel to each tile's best matches,
+                             and the matches counted before and after it.
 * ``stjoin_vote_fused_flat`` fused pass 1: per-point vote sums (Eq. 4) and
                              bit-packed neighbor words (TSA2, Alg. 3) with
                              the delta_t run refine applied in-kernel.
@@ -153,11 +156,11 @@ def _refs(*refs):
     return tuple(r[...] for r in refs)
 
 
-def _run_refine(w, rt, rows: int, M: int, delta_t):
-    """In-kernel DTJ Refine (delta_t): zero matches in short runs.
+def _run_keep(w, rt, rows: int, M: int, delta_t):
+    """In-kernel DTJ Refine (delta_t): which matches survive.
 
     ``w``: ``[bp, bc]`` best weights for ``rows`` whole trajectory rows of
-    ``M`` points (``bp == rows * M``; the fused wrappers keep ref blocks
+    ``M`` points (``bp == rows * M``; the wrappers keep ref blocks
     row-aligned so runs never cross a block).  A run is a maximal streak
     of consecutive matched ref points for one candidate; it survives iff
     ``t[last] - t[first] >= delta_t``.  With ``t`` ascending in a row, a
@@ -165,11 +168,10 @@ def _run_refine(w, rt, rows: int, M: int, delta_t):
     at the earliest run end at or after it: a prefix max and a suffix min,
     done as log-step doubling scans (exact for max and min).  Matches
     ``repro.core.geometry.filter_delta_t``; ``delta_t == 0`` keeps every
-    match.
+    match.  Returns ``keep [bp, bc]`` bool.
     """
     bp, bc = w.shape
-    m = w.reshape(rows, M, bc)
-    matched = (m > 0.0).astype(jnp.int32)
+    matched = (w.reshape(rows, M, bc) > 0.0).astype(jnp.int32)
     prev = shift_points(matched, 1, 0)
     nxt = shift_points(matched, -1, 0)
     t3 = jnp.broadcast_to(rt.reshape(rows, M, 1), (rows, M, bc))
@@ -181,7 +183,12 @@ def _run_refine(w, rt, rows: int, M: int, delta_t):
         end = jnp.minimum(end, shift_points(end, -k, _BIG))
         k *= 2
     keep = (matched > 0) & ((end - start) >= delta_t)
-    return jnp.where(keep, m, 0.0).reshape(bp, bc)
+    return keep.reshape(bp, bc)
+
+
+def _run_refine(w, rt, rows: int, M: int, delta_t):
+    """``w`` with the matches ``_run_keep`` drops zeroed."""
+    return jnp.where(_run_keep(w, rt, rows, M, delta_t), w, 0.0)
 
 
 def _pack_words(w, j, out_words):
@@ -210,7 +217,12 @@ def _pack_words(w, j, out_words):
 
 
 def _join_kernel(codes, rx, ry, rt, rid, rok, cx, cy, ct, cid, cok, eps,
-                 w0, i0, out_w, out_idx):
+                 w0, i0, out_w, out_idx, *out_cnt, rows: int = 0, M: int = 0):
+    """``out_cnt`` is there only in the DTJ join (``stjoin_dtj_pallas``):
+    the last point chunk of a live tile then applies the run refine to the
+    tile's best matches before they are written, and adds the tile's
+    matches before and after it to lanes 0 and 1 of the row block's
+    counts."""
     del w0, i0                    # aliased empty outputs (unvisited blocks)
     i, s, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     live = codes[i, s] >= 0
@@ -220,6 +232,11 @@ def _join_kernel(codes, rx, ry, rt, rid, rok, cx, cy, ct, cid, cok, eps,
         out_w[...] = jnp.zeros_like(out_w)
         out_idx[...] = jnp.full_like(out_idx, -1)
 
+    for cnt in out_cnt:
+        @pl.when((k == 0) & (s == 0))
+        def _init_cnt():
+            cnt[...] = jnp.zeros_like(cnt)
+
     @pl.when(live)
     def _chunk():
         bm = cx.shape[0]
@@ -228,6 +245,73 @@ def _join_kernel(codes, rx, ry, rt, rid, rok, cx, cy, ct, cid, cok, eps,
                         n=bm, base=k * bm)
         out_w[...] = w
         out_idx[...] = idx
+        for cnt in out_cnt:
+            @pl.when(k == pl.num_programs(2) - 1)
+            def _refine():
+                keep = _run_keep(w, rt[...], rows, M, eps[2])
+                out_w[...] = jnp.where(keep, w, 0.0)
+                out_idx[...] = jnp.where(keep, idx, -1)
+
+                def total(x):
+                    x = jnp.sum(x.astype(jnp.int32), axis=1, keepdims=True)
+                    return jnp.sum(x, axis=0, keepdims=True)   # [1, 1]
+
+                lane = jax.lax.broadcasted_iota(jnp.int32, cnt.shape, 2)
+                cnt[...] += jnp.where(
+                    lane == 0, total(w > 0.0)[None],
+                    jnp.where(lane == 1, total(keep)[None], 0))
+
+
+def _join_call(ref_ops, cand_ops, eps, tile_ids, *, bp: int, bc: int,
+               bm: int, interpret: bool, rows: int = 0, M: int = 0):
+    """The materializing join's ``pallas_call``, ``eps`` its scalars; with
+    ``rows`` (the DTJ join, ``bp == rows * M``, ``eps`` with ``delta_t``)
+    it refines in-kernel and also returns the ``[P/bp, 1, 128]`` per-block
+    match counts."""
+    if interpret is None:
+        interpret = default_interpret()
+    P = ref_ops[0].shape[0]
+    C, Mc = cand_ops[0].shape
+    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
+    nRb, nCb = P // bp, C // bc
+    codes = (dense_codes(nRb, nCb) if tile_ids is None
+             else slot_codes(tile_ids))
+    assert codes.shape[0] == nRb, (codes.shape, nRb)
+
+    ref_spec = pl.BlockSpec((bp, 1), lambda i, s, k, c: (i, 0))
+    cand_spec = pl.BlockSpec((bm, bc),
+                             lambda i, s, k, c: (k, _block_of(c[i, s])))
+    cid_spec = pl.BlockSpec((1, bc), lambda i, s, k, c: (0, _block_of(c[i, s])))
+    out_spec = pl.BlockSpec((bp, bc),
+                            lambda i, s, k, c: (i, _block_of(c[i, s])))
+    f32, i32 = jnp.float32, jnp.int32
+    out_specs = [out_spec, out_spec]
+    out_shape = [jax.ShapeDtypeStruct((P, C), f32),
+                 jax.ShapeDtypeStruct((P, C), i32)]
+    if rows:
+        out_specs.append(pl.BlockSpec((1, 1, 128),
+                                      lambda i, s, k, c: (i, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((nRb, 1, 128), i32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(nRb, codes.shape[1], Mc // bm),
+        in_specs=[ref_spec] * 5 + [cand_spec] * 3
+        + [cid_spec, cand_spec, _SMEM, _ANY, _ANY],
+        out_specs=out_specs)
+    rx, ry, rt, rid, rok = ref_ops
+    cx, cy, ct, cid, cok = cand_ops
+    kernel = (functools.partial(_join_kernel, rows=rows, M=M) if rows
+              else _join_kernel)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        input_output_aliases={12: 0, 13: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(codes, _col(rx, f32), _col(ry, f32), _col(rt, f32),
+      _col(rid, i32), _col(rok, i32), cx.astype(f32).T,
+      cy.astype(f32).T, ct.astype(f32).T,
+      cid.astype(i32).reshape(1, C), cok.astype(i32).T,
+      _eps(*eps), jnp.zeros((P, C), f32), jnp.full((P, C), -1, i32))
 
 
 @functools.partial(jax.jit, static_argnames=("bp", "bc", "bm", "interpret"))
@@ -244,41 +328,34 @@ def stjoin_pallas(ref_x, ref_y, ref_t, ref_id, ref_ok,
     value ``(0, -1)``, which is what the dense sweep yields there because
     pruning is conservative.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    P = ref_x.shape[0]
-    C, Mc = cand_x.shape
-    assert P % bp == 0 and C % bc == 0 and Mc % bm == 0, (P, C, Mc, bp, bc, bm)
-    nRb, nCb = P // bp, C // bc
-    codes = (dense_codes(nRb, nCb) if tile_ids is None
-             else slot_codes(tile_ids))
-    assert codes.shape[0] == nRb, (codes.shape, nRb)
+    return _join_call(
+        (ref_x, ref_y, ref_t, ref_id, ref_ok),
+        (cand_x, cand_y, cand_t, cand_id, cand_ok), (eps_sp, eps_t),
+        tile_ids, bp=bp, bc=bc, bm=bm, interpret=interpret)
 
-    ref_spec = pl.BlockSpec((bp, 1), lambda i, s, k, c: (i, 0))
-    cand_spec = pl.BlockSpec((bm, bc),
-                             lambda i, s, k, c: (k, _block_of(c[i, s])))
-    cid_spec = pl.BlockSpec((1, bc), lambda i, s, k, c: (0, _block_of(c[i, s])))
-    out_spec = pl.BlockSpec((bp, bc),
-                            lambda i, s, k, c: (i, _block_of(c[i, s])))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(nRb, codes.shape[1], Mc // bm),
-        in_specs=[ref_spec] * 5 + [cand_spec] * 3
-        + [cid_spec, cand_spec, _SMEM, _ANY, _ANY],
-        out_specs=[out_spec, out_spec])
-    f32, i32 = jnp.float32, jnp.int32
-    return pl.pallas_call(
-        _join_kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((P, C), f32),
-                   jax.ShapeDtypeStruct((P, C), i32)],
-        input_output_aliases={12: 0, 13: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(codes, _col(ref_x, f32), _col(ref_y, f32), _col(ref_t, f32),
-      _col(ref_id, i32), _col(ref_ok, i32), cand_x.astype(f32).T,
-      cand_y.astype(f32).T, cand_t.astype(f32).T,
-      cand_id.astype(i32).reshape(1, C), cand_ok.astype(i32).T,
-      _eps(eps_sp, eps_t), jnp.zeros((P, C), f32), jnp.full((P, C), -1, i32))
+
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "M", "bc", "bm", "interpret"))
+def stjoin_dtj_pallas(ref_x, ref_y, ref_t, ref_id, ref_ok,
+                      cand_x, cand_y, cand_t, cand_id, cand_ok,
+                      eps_sp, eps_t, delta_t, tile_ids=None, *, rows: int,
+                      M: int, bc: int = 128, bm: int = 128,
+                      interpret: bool | None = None):
+    """``stjoin_pallas`` with DTJ's Refine in the kernel: ``(best_w, best_idx,
+    matches)``, the matches in runs shorter than ``delta_t`` dropped
+    (``geometry.filter_delta_t``'s result, bit for bit) and ``matches``
+    ``[2]`` i32 the count of matches before and after the refine.
+
+    Ref blocks are ``bp = rows * M`` points, ``rows`` whole trajectory
+    rows of ``M`` (``P % bp == 0``), so a run never spans two blocks;
+    ``tile_ids`` is a plan built for that block.
+    """
+    w, idx, cnt = _join_call(
+        (ref_x, ref_y, ref_t, ref_id, ref_ok),
+        (cand_x, cand_y, cand_t, cand_id, cand_ok),
+        (eps_sp, eps_t, delta_t), tile_ids, bp=rows * M, bc=bc, bm=bm,
+        interpret=interpret, rows=rows, M=M)
+    return w, idx, jnp.sum(cnt[:, 0, :2], axis=0)
 
 
 # ---------------------------------------------------------------------------

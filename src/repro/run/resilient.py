@@ -495,7 +495,7 @@ class _SingleHostLoop(_StageLoop):
             join = None
         elif plan.use_index and plan.use_kernel:
             from repro.kernels.stjoin import ops as stjoin_ops
-            join = stjoin_ops.subtrajectory_join(
+            join, _ = stjoin_ops.subtrajectory_join(
                 b, b, p.eps_sp, p.eps_t, p.delta_t, use_index=True)
             vote, masks = dsc_mod.run_stage_vote_from_join(b, p, join, plan)
         else:

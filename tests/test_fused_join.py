@@ -209,3 +209,126 @@ def test_fused_all_invalid_batch():
                                                rows=2, bc=2, bm=4)
     assert (np.asarray(vote) == 0).all()
     assert (np.asarray(words) == 0).all()
+
+
+# ---------------------------------------------------------------------------
+# The materializing join with DTJ's delta_t refine in the kernel
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("use_index", [False, True])
+@pytest.mark.parametrize("T,M,bp,bc,bm,delta_t", [
+    (5, 24, 256, 2, 8, 6.0),    # 24 does not divide 256: 10 rows, cut to T
+    (6, 16, 32, 2, 16, 4.0),    # two rows a block
+    (5, 16, 32, 4, 16, 9.0),    # a padded row in the last block
+    (5, 16, 16, 1, 16, 2.0),    # one row a block
+])
+def test_dtj_join_equals_filter_delta_t(T, M, bp, bc, bm, delta_t, use_index):
+    """The refined kernel, dense and pruned, is ``filter_delta_t`` of the
+    plain kernel bit for bit, and of the jnp join up to the weights'
+    rounding; its counts are the matches before and after."""
+    rng = np.random.default_rng(T * 100 + M + bp)
+    b = _rand_batch(rng, T, M, pad_row=1)
+    join, matches = stjoin_ops.subtrajectory_join(
+        b, b, 2.5, 12.0, delta_t, use_index=use_index, bp=bp, bc=bc, bm=bm)
+    plain, _ = stjoin_ops.best_match_join_kernel(b, b, 2.5, 12.0, bp=bp,
+                                                 bc=bc, bm=bm)
+    want = geometry.filter_delta_t(plain, b.t, delta_t)
+    assert (np.asarray(join.best_w) == np.asarray(want.best_w)).all()
+    assert (np.asarray(join.best_idx) == np.asarray(want.best_idx)).all()
+    jnp_join = geometry.filter_delta_t(
+        geometry.best_match_join(b, b, 2.5, 12.0), b.t, delta_t)
+    assert (np.asarray(join.best_idx) == np.asarray(jnp_join.best_idx)).all()
+    np.testing.assert_allclose(np.asarray(join.best_w),
+                               np.asarray(jnp_join.best_w), atol=1e-5)
+    before = int((np.asarray(plain.best_w) > 0).sum())
+    after = int((np.asarray(want.best_w) > 0).sum())
+    assert 0 < after < before
+    assert np.asarray(matches).tolist() == [before, after]
+
+
+def _hand_batch():
+    """Three tracks of 8 fixes, one a minute.  Track 1 runs 0.3 beside
+    track 0 the whole way; track 2 comes 0.3 to its other side at fixes 3
+    and 4 only (0.6 from track 1) and is far away otherwise."""
+    M = 8
+    x = np.tile(np.arange(M, dtype=np.float32), (3, 1))
+    y = np.zeros((3, M), np.float32)
+    y[1] = 0.3
+    y[2] = 50.0
+    y[2, 3:5] = -0.3
+    t = np.tile(np.arange(M, dtype=np.float32) * 60.0, (3, 1))
+    return TrajectoryBatch(x=jnp.asarray(x), y=jnp.asarray(y),
+                           t=jnp.asarray(t),
+                           valid=jnp.ones((3, M), bool),
+                           traj_id=jnp.arange(3, dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("use_index", [False, True])
+def test_dtj_join_matches_a_hand_count(use_index):
+    """Tracks 0 and 1 match at all 8 fixes each way, a run from the
+    block's first fix to its last (two rows a block), 420 s; tracks 0
+    and 2 at fixes 3-4 each way, 60 s.  Before the refine 8 + 8 + 2 + 2
+    matches, after it at 120 s the 16 of the long runs."""
+    b = _hand_batch()
+    join, matches = stjoin_ops.subtrajectory_join(
+        b, b, 0.5, 30.0, 120.0, use_index=use_index, bp=16, bc=1, bm=8)
+    assert np.asarray(matches).tolist() == [20, 16]
+    kept = np.asarray(join.best_w) > 0
+    assert kept[0, :, 1].all() and kept[1, :, 0].all()
+    assert not kept[:, :, 2].any() and not kept[2].any()
+    assert (np.asarray(join.best_idx)[0, :, 1] == np.arange(8)).all()
+
+
+def test_dtj_join_at_delta_t_zero_is_the_plain_kernel():
+    """At delta_t 0 the join compiles the plain kernel, returns no counts
+    and equals ``best_match_join_kernel`` bit for bit."""
+    import jax
+    b = _hand_batch()
+    join, matches = stjoin_ops.subtrajectory_join(
+        b, b, 0.5, 30.0, 0.0, bp=16, bc=1, bm=8)
+    assert matches is None
+    plain, _ = stjoin_ops.best_match_join_kernel(b, b, 0.5, 30.0, bp=16,
+                                                 bc=1, bm=8)
+    assert (np.asarray(join.best_w) == np.asarray(plain.best_w)).all()
+    assert (np.asarray(join.best_idx) == np.asarray(plain.best_idx)).all()
+    text = str(jax.make_jaxpr(
+        lambda b: stjoin_ops.subtrajectory_join(b, b, 0.5, 30.0, 0.0,
+                                                bp=16, bc=1, bm=8))(b))
+    assert "stjoin_pallas" in text and "stjoin_dtj_pallas" not in text
+    text = str(jax.make_jaxpr(
+        lambda b: stjoin_ops.subtrajectory_join(b, b, 0.5, 30.0, 1.0,
+                                                bp=16, bc=1, bm=8))(b))
+    assert "stjoin_dtj_pallas" in text
+
+
+def test_dtj_row_block_is_whole_rows():
+    assert stjoin_ops._row_block(2048, 128, 256, 120.0) == 256
+    assert stjoin_ops._row_block(2048, 24, 256, 1.0) == 240
+    assert stjoin_ops._row_block(3, 24, 256, 1.0) == 72
+    assert stjoin_ops._row_block(8, 300, 256, 1.0) == 300
+    assert stjoin_ops._row_block(8, 24, 256, 0.0) == 256
+
+
+def test_run_dsc_returns_the_join_counts():
+    """``DSCOutput.join_matches`` on the index-pruned kernel join: the
+    counts of ``subtrajectory_join``; None at delta_t 0 and on the jnp
+    join."""
+    from repro.core.plan import EnginePlan
+    rng = np.random.default_rng(5)
+    b = _rand_batch(rng, 8, 16)
+    params = DSCParams(eps_sp=2.5, eps_t=12.0, delta_t=6.0, w=3, tau=0.2,
+                       alpha_sigma=-1.0, k_sigma=-1.0, segmentation="tsa2")
+    plan = EnginePlan(mode="materialize", use_kernel=True, use_index=True,
+                      seg_use_kernel=True)
+    out = run_dsc(b, params, plan=plan)
+    _, want = stjoin_ops.subtrajectory_join(b, b, 2.5, 12.0, 6.0,
+                                            use_index=True)
+    assert np.asarray(out.join_matches).tolist() == np.asarray(want).tolist()
+    ref = run_dsc(b, params)
+    assert ref.join_matches is None
+    for f in ("member_of", "is_rep", "is_outlier"):
+        assert (np.asarray(getattr(out.result, f))
+                == np.asarray(getattr(ref.result, f))).all()
+    assert run_dsc(b, params.replace(delta_t=0.0),
+                   plan=plan).join_matches is None

@@ -93,8 +93,8 @@ def test_pruned_join_matches_dense(seed):
     rng = np.random.default_rng(seed)
     ref = _batch(rng, 8, 32)
     cand = _batch(rng, 8, 32)
-    dense = best_match_join_kernel(ref, cand, 2.0, 10.0, bp=32, bc=2, bm=16)
-    pruned = best_match_join_pruned(ref, cand, 2.0, 10.0, bp=32, bc=2, bm=16)
+    dense, _ = best_match_join_kernel(ref, cand, 2.0, 10.0, bp=32, bc=2, bm=16)
+    pruned, _ = best_match_join_pruned(ref, cand, 2.0, 10.0, bp=32, bc=2, bm=16)
     _assert_bitwise_equal(dense, pruned)
 
 
@@ -105,8 +105,8 @@ def test_pruned_join_matches_dense_without_cells(seed):
     rng = np.random.default_rng(seed)
     ref = _batch(rng, 4, 16)
     cand = _batch(rng, 8, 16)
-    dense = best_match_join_kernel(ref, cand, 3.0, 20.0, bp=16, bc=2, bm=16)
-    pruned = best_match_join_pruned(ref, cand, 3.0, 20.0, bp=16, bc=2, bm=16,
+    dense, _ = best_match_join_kernel(ref, cand, 3.0, 20.0, bp=16, bc=2, bm=16)
+    pruned, _ = best_match_join_pruned(ref, cand, 3.0, 20.0, bp=16, bc=2, bm=16,
                                     use_cells=False)
     _assert_bitwise_equal(dense, pruned)
 
@@ -123,15 +123,15 @@ def test_pruned_join_edge_cells():
     b = TrajectoryBatch(x=jnp.asarray(x), y=jnp.asarray(y), t=jnp.asarray(t),
                         valid=jnp.ones((T, M), bool),
                         traj_id=jnp.arange(T, dtype=jnp.int32))
-    dense = best_match_join_kernel(b, b, 2.0, 1.0, bp=16, bc=1, bm=16)
-    pruned = best_match_join_pruned(b, b, 2.0, 1.0, bp=16, bc=1, bm=16)
+    dense, _ = best_match_join_kernel(b, b, 2.0, 1.0, bp=16, bc=1, bm=16)
+    pruned, _ = best_match_join_pruned(b, b, 2.0, 1.0, bp=16, bc=1, bm=16)
     _assert_bitwise_equal(dense, pruned)
     # the eps-boundary pair really matches (w == 1 - eps/eps == 0 is culled;
     # nudge inside to see a positive weight)
     x[1] = 1.999
     b2 = b.replace(x=jnp.asarray(x))
-    dense2 = best_match_join_kernel(b2, b2, 2.0, 1.0, bp=16, bc=1, bm=16)
-    pruned2 = best_match_join_pruned(b2, b2, 2.0, 1.0, bp=16, bc=1, bm=16)
+    dense2, _ = best_match_join_kernel(b2, b2, 2.0, 1.0, bp=16, bc=1, bm=16)
+    pruned2, _ = best_match_join_pruned(b2, b2, 2.0, 1.0, bp=16, bc=1, bm=16)
     _assert_bitwise_equal(dense2, pruned2)
     assert float(np.asarray(pruned2.best_w).max()) > 0.0
 
@@ -140,8 +140,8 @@ def test_pruned_join_all_invalid_tiles():
     rng = np.random.default_rng(7)
     ref = _batch(rng, 8, 16, invalid_rows=(1, 2, 5))
     cand = _batch(rng, 8, 16, invalid_rows=(0, 3))
-    dense = best_match_join_kernel(ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16)
-    pruned = best_match_join_pruned(ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16)
+    dense, _ = best_match_join_kernel(ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16)
+    pruned, _ = best_match_join_pruned(ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16)
     _assert_bitwise_equal(dense, pruned)
 
 
@@ -149,8 +149,8 @@ def test_pruned_join_everything_invalid():
     rng = np.random.default_rng(8)
     ref = _batch(rng, 4, 16, invalid_rows=range(4))
     cand = _batch(rng, 4, 16, invalid_rows=range(4))
-    dense = best_match_join_kernel(ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16)
-    pruned, stats = best_match_join_pruned(
+    dense, _ = best_match_join_kernel(ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16)
+    (pruned, _), stats = best_match_join_pruned(
         ref, cand, 2.0, 10.0, bp=16, bc=2, bm=16, return_stats=True)
     _assert_bitwise_equal(dense, pruned)
     assert int(stats.kept_tiles) == 0
@@ -170,8 +170,8 @@ def test_pruned_join_prunes_separated_clusters():
         t=jnp.concatenate([near.t, far.t]),
         valid=jnp.concatenate([near.valid, far.valid]),
         traj_id=jnp.arange(8, dtype=jnp.int32))
-    dense = best_match_join_kernel(batch, batch, 2.0, 10.0, bp=16, bc=2, bm=16)
-    pruned, stats = best_match_join_pruned(
+    dense, _ = best_match_join_kernel(batch, batch, 2.0, 10.0, bp=16, bc=2, bm=16)
+    (pruned, _), stats = best_match_join_pruned(
         batch, batch, 2.0, 10.0, bp=16, bc=2, bm=16, return_stats=True)
     _assert_bitwise_equal(dense, pruned)
     assert int(stats.kept_tiles) < stats.dense_tiles
@@ -202,7 +202,7 @@ def test_kernel_subtrajectory_join_use_index():
     rng = np.random.default_rng(12)
     ref = _batch(rng, 4, 32)
     cand = _batch(rng, 4, 32)
-    base = k_join(ref, cand, 2.0, 10.0, delta_t=3.0, bp=32, bc=2, bm=16)
-    idx = k_join(ref, cand, 2.0, 10.0, delta_t=3.0, use_index=True,
-                 bp=32, bc=2, bm=16)
+    base, _ = k_join(ref, cand, 2.0, 10.0, delta_t=3.0, bp=32, bc=2, bm=16)
+    idx, _ = k_join(ref, cand, 2.0, 10.0, delta_t=3.0, use_index=True,
+                    bp=32, bc=2, bm=16)
     _assert_bitwise_equal(base, idx)

@@ -6,6 +6,8 @@ import jax
 import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
+from repro.core.segmentation import tsa2_signal
+from repro.kernels import RATIO_MAX_COUNT, exact_ratio, round_quotient
 from repro.kernels.jaccard.ops import window_jaccard
 from repro.kernels.jaccard.ref import jaccard_ref
 from repro.kernels.lcss.ops import lcss_scores, lcss_similarity
@@ -42,8 +44,8 @@ def test_stjoin_shapes(T, M, C, Mc, bp, bc, bm):
     cand_b = TrajectoryBatch(x=jnp.asarray(cx), y=jnp.asarray(cy),
                              t=jnp.asarray(ct), valid=jnp.asarray(cv),
                              traj_id=jnp.asarray(cid))
-    got = best_match_join_kernel(ref_b, cand_b, 2.0, 10.0,
-                                 bp=bp, bc=bc, bm=bm)
+    got, _ = best_match_join_kernel(ref_b, cand_b, 2.0, 10.0,
+                                    bp=bp, bc=bc, bm=bm)
     want_w, want_idx = stjoin_ref(
         jnp.asarray(rx.reshape(-1)), jnp.asarray(ry.reshape(-1)),
         jnp.asarray(rt.reshape(-1)),
@@ -64,7 +66,7 @@ def test_stjoin_symmetry_of_matching():
     from repro.core.types import TrajectoryBatch
     b = TrajectoryBatch(x=jnp.asarray(x), y=jnp.asarray(y), t=jnp.asarray(t),
                         valid=jnp.asarray(v), traj_id=jnp.asarray(ids))
-    got = best_match_join_kernel(b, b, 3.0, 10.0, bp=8, bc=2, bm=8)
+    got, _ = best_match_join_kernel(b, b, 3.0, 10.0, bp=8, bc=2, bm=8)
     w = np.asarray(got.best_w)          # [T, M, C]
     pair = w.sum(axis=1) > 0            # [T, C] r matched c somewhere
     assert (pair == pair.T).all()
@@ -76,7 +78,7 @@ def test_stjoin_excludes_self():
     from repro.core.types import TrajectoryBatch
     b = TrajectoryBatch(x=jnp.asarray(x), y=jnp.asarray(y), t=jnp.asarray(t),
                         valid=jnp.asarray(v), traj_id=jnp.asarray(ids))
-    got = best_match_join_kernel(b, b, 100.0, 1e9, bp=8, bc=2, bm=8)
+    got, _ = best_match_join_kernel(b, b, 100.0, 1e9, bp=8, bc=2, bm=8)
     w = np.asarray(got.best_w)
     for r in range(4):
         assert (w[r, :, r] == 0).all()
@@ -178,3 +180,70 @@ def test_jaccard_range_and_identical_windows(seed, w):
     d = np.asarray(window_jaccard(masks, valid, w=w))
     assert (d >= 0).all() and (d <= 1).all()
     assert np.allclose(d[:, w:M - w], 0.0, atol=1e-6)
+
+
+@pytest.mark.parametrize("off", [-2, -1, 0, 1, 2])
+def test_round_quotient_recovers_the_correctly_rounded_ratio(off):
+    """A quotient up to two ulps off (as the TPU's division can be) comes
+    back as the IEEE quotient, for every ``num <= den`` up to 1024, for a
+    sample of counts up to ``RATIO_MAX_COUNT``, and for the ratios of the
+    largest 128 denominators that lie nearest a midpoint between two
+    float32 values, where a residual off by an ulp would pick wrong."""
+    n = np.arange(0, 1025, dtype=np.float32)
+    num, den = np.meshgrid(n, n[1:], indexing="ij")
+    keep = num <= den
+    rng = np.random.default_rng(15)
+    big = rng.integers(1025, RATIO_MAX_COUNT + 1, 200_000).astype(np.float32)
+    part = np.floor(big * rng.uniform(0, 1, big.size)).astype(np.float32)
+    hd = np.arange(RATIO_MAX_COUNT - 127, RATIO_MAX_COUNT + 1)[:, None]
+    hn = np.arange(1, RATIO_MAX_COUNT + 1)[None, :]
+    v = hn / hd
+    q32 = v.astype(np.float32)
+    ulp = np.spacing(q32).astype(np.float64)
+    to_mid = np.where(hn <= hd, np.abs(np.abs(v - q32) - ulp / 2) / ulp,
+                      np.inf)
+    hard = np.unravel_index(np.argsort(to_mid, axis=None)[:20_000],
+                            to_mid.shape)
+    num = np.concatenate([num[keep], part,
+                          hn[0, hard[1]].astype(np.float32)])
+    den = np.concatenate([den[keep], big, hd[hard[0], 0].astype(np.float32)])
+    want = num / den
+    q = (want.view(np.int32) + off).view(np.float32)
+    q = np.where(num == 0, np.float32(0), q)
+    got = np.asarray(jax.jit(round_quotient)(num, den, q))
+    assert (got == want).all(), int((got != want).sum())
+
+
+def test_exact_ratio_is_the_plain_quotient_above_its_bound():
+    num = jnp.asarray([1.0, 7.0, 3.0], jnp.float32)
+    den = jnp.asarray([3.0, 9.0, 11.0], jnp.float32)
+    assert (np.asarray(exact_ratio(num, den, RATIO_MAX_COUNT + 32))
+            == np.asarray(num / den)).all()
+
+
+def test_jaccard_at_256_words_is_correctly_rounded():
+    """8192 candidate tracks (the ``dsc_sis`` width): the kernel and the
+    jnp engine give ``1 - q``, ``q`` the IEEE quotient of the counts."""
+    rng = np.random.default_rng(256)
+    T, M, W, w = 2, 24, 256, 3
+    masks = rng.integers(0, 2 ** 32, (T, M, W), dtype=np.uint64)
+    masks &= rng.integers(0, 2 ** 32, (T, M, W), dtype=np.uint64)
+    masks = masks.astype(np.uint32)
+    valid = np.ones((T, M), bool)
+    got = np.asarray(window_jaccard(jnp.asarray(masks), jnp.asarray(valid),
+                                    w=w))
+    eng = np.asarray(tsa2_signal(jnp.asarray(masks), w))
+    u1 = np.zeros_like(masks)
+    u2 = np.zeros_like(masks)
+    for k in range(1, w + 1):
+        u1[:, k:] |= masks[:, :M - k]
+    for k in range(w):
+        u2[:, :M - k] |= masks[:, k:]
+    inter = np.bitwise_count(u1 & u2).sum(-1).astype(np.float32)
+    union = np.bitwise_count(u1 | u2).sum(-1).astype(np.float32)
+    want = np.where(union > 0,
+                    np.float32(1) - inter / np.maximum(union, 1),
+                    np.float32(0))
+    assert union.max() > 4096
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(eng, want)

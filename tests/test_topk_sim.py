@@ -173,7 +173,7 @@ def _pipeline_pieces(seed=3):
     from repro.kernels.stjoin.ops import subtrajectory_join
     batch, _ = ais_like(n_vessels=8, max_points=24, seed=seed)
     eps_sp, eps_t, delta_t, maxS, w, tau = 3.0, 600.0, 0.0, 4, 4, 0.2
-    join = subtrajectory_join(batch, batch, eps_sp, eps_t, delta_t)
+    join, _ = subtrajectory_join(batch, batch, eps_sp, eps_t, delta_t)
     vote = voting.point_voting(join)
     masks = voting.neighbor_mask_packed(join)
     seg = tsa2(masks, batch.valid, w, tau, maxS)

@@ -3,7 +3,8 @@
 Interpret mode accepts layouts Mosaic refuses (rank-1 blocks that are not
 a multiple of 128, unsigned reductions, in-kernel gathers and scatters,
 cumulative scans), so each kernel the ``materialize`` and ``fused`` plans
-reach is compiled here with ``interpret=False`` at the widths of the
+reach (the join with and without the in-kernel delta_t refine) is
+compiled here with ``interpret=False`` at the widths of the
 ``dsc_brest`` deployment: M = 128 points per trajectory, 8 sub-slots per
 trajectory, w = 20, and T = 2048 trajectories (S = 16,384 slots).  No chip
 is needed: the topology is described, not attached, so nothing runs.
@@ -101,6 +102,14 @@ def _kernel_calls(spec):
             lambda *a: sk.stjoin_pallas(*a, bp=256, bc=128, bm=128,
                                         interpret=False),
             [*ref, *cand, e, e, join_tiles]),
+        "stjoin_dtj_dense": (
+            lambda *a: sk.stjoin_dtj_pallas(*a, rows=ROWS, M=M, bc=128,
+                                            bm=128, interpret=False),
+            [*ref, *cand, e, e, e]),
+        "stjoin_dtj_pruned": (
+            lambda *a: sk.stjoin_dtj_pallas(*a, rows=ROWS, M=M, bc=128,
+                                            bm=128, interpret=False),
+            [*ref, *cand, e, e, e, fused_tiles]),
         "vote_dense_tsa2": (
             lambda *a: sk.stjoin_vote_fused_flat(*a, **fused),
             [*ref, *cand, e, e, e]),
@@ -150,7 +159,8 @@ def _kernel_calls(spec):
     }
 
 
-KERNELS = ["stjoin_dense", "stjoin_pruned", "vote_dense_tsa2",
+KERNELS = ["stjoin_dense", "stjoin_pruned", "stjoin_dtj_dense",
+           "stjoin_dtj_pruned", "vote_dense_tsa2",
            "vote_dense_tsa1", "vote_pruned", "sim_dense", "sim_pruned",
            "sim_panel_dense", "sim_panel_pruned", "cluster_round_scan",
            "cluster_assign", "topk_round_scan", "topk_assign", "jaccard",
