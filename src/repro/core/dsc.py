@@ -11,7 +11,8 @@ Execution modes (``mode=``, see README §Execution modes / DESIGN.md §3):
 
 * ``"materialize"`` — the parity oracle: the DTJ join cube
   ``JoinResult [T, M, C]`` is built in HBM and re-read by each consumer
-  (voting, TSA2 masks, similarity scatter).
+  (voting, TSA2 masks, similarity scatter; on the kernel path the scatter
+  reads only the cube's kept tiles).
 * ``"fused"``       — streaming epilogue fusion: two Pallas sweeps
   accumulate the consumers' O(T*M + S^2) outputs directly; the cube never
   exists (``DSCOutput.join is None``).  Pass 2 recomputes the best-match
@@ -94,22 +95,34 @@ def _segment_body(batch, params, vote, masks, plan: EnginePlan):
     return seg, table
 
 
+def _pass2(plan: EnginePlan, has_cube: bool) -> str:
+    """Which pass 2 builds the raw similarity: ``"cube"``, a Pallas
+    kernel that reads the materialized join's kept tiles (the kernel join,
+    dense similarity); ``"sweep"``, a Pallas sweep that re-runs the pair
+    tests (no cube, or the top-K panels); ``"scatter"``, the XLA scatter
+    of the whole cube (``use_kernel=False``)."""
+    if not has_cube or (plan.use_kernel and plan.sim_mode == "topk"):
+        return "sweep"
+    return "cube" if plan.use_kernel else "scatter"
+
+
 def _similarity_body(batch, params, join, seg, table, plan: EnginePlan,
                      tile_ids=None):
     """SP relation: returns ``(sim, topk)`` — exactly one is non-None.
 
-    The Pallas pass-2 sweep (``kernels.stjoin``) builds the raw scatter
-    whenever the join runs on the kernels — fused mode, or the kernel
-    join in materialize mode — instead of an XLA scatter of all ``T*M*C``
-    cube entries, whose index operands alone are as large as the cube
-    (DESIGN.md §3).  Both accumulate every cell in the same order.
+    Pallas builds the raw scatter whenever the join runs on the kernels
+    (:func:`_pass2`): from the join's kept cube tiles in materialize mode,
+    by a sweep that recomputes them in fused mode (no cube) and for the
+    top-K panels, instead of an XLA scatter of all ``T*M*C`` cube
+    entries, whose index operands alone are as large as the cube
+    (DESIGN.md §3).  All accumulate every cell in the same order.
     ``tile_ids`` is the sweep's own index plan (:func:`plan_sweep_tiles`).
     """
     with jax.named_scope("dsc.similarity"):
-        sweep = join is None or plan.use_kernel
+        pass2 = _pass2(plan, join is not None)
         if plan.sim_mode == "topk":
             # sparse SP relation: panel-streamed top-K lists, never [S, S]
-            if sweep:
+            if pass2 == "sweep":
                 from repro.kernels.stjoin import ops as stjoin_ops
                 Sb = similarity.plan_panel(table.num_slots, plan.sim_panel)
 
@@ -129,7 +142,14 @@ def _similarity_body(batch, params, join, seg, table, plan: EnginePlan,
                     panel=plan.sim_panel)
             return None, topk
 
-        if sweep:
+        if pass2 == "cube":
+            from repro.kernels.stjoin import ops as stjoin_ops
+            raw = stjoin_ops.stjoin_sim_cube(
+                join, seg.sub_local, seg.sub_local,
+                params.max_subtrajs_per_traj, tile_ids=tile_ids,
+                **_tile_kwargs(plan.fused_tiles))
+            sim = similarity.finalize_sim(raw, table)
+        elif pass2 == "sweep":
             from repro.kernels.stjoin import ops as stjoin_ops
             raw = stjoin_ops.stjoin_sim_fused(
                 batch, batch, seg.sub_local, seg.sub_local,
@@ -423,7 +443,9 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
     keeps the legacy ``sim_topk_retry`` behavior; passing both raises.
 
     The call is the host span ``dsc.run``, with ``T``, ``M``, ``mode``,
-    the final ``k`` and the ``attempts`` the top-K widen loop made.
+    the final ``k``, the ``attempts`` the top-K widen loop made and
+    ``pass2``, the similarity's pass 2 (``"cube"``, ``"sweep"`` or
+    ``"scatter"``, :func:`_pass2`).
     """
     with TraceAnnotation("dsc.run") as span:
         plan = resolve_plan(plan, mode=mode, use_kernel=use_kernel,
@@ -480,7 +502,8 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
             attempts, out = attempts + 1, dispatch(k)
         if TraceAnnotation.is_enabled():
             span.set_metadata(T=batch.num_trajs, M=batch.x.shape[1],
-                              mode=plan.mode, k=k, attempts=attempts)
+                              mode=plan.mode, k=k, attempts=attempts,
+                              pass2=_pass2(plan, plan.mode == "materialize"))
         return out
 
 

@@ -14,6 +14,8 @@ These wrappers own the padding and the tile geometry, so the kernels in
   dense join (pruning is conservative).
 * the fused streaming passes (``stjoin_vote_fused`` / ``stjoin_sim_fused``
   / ``stjoin_sim_panel_fused``), which never build the ``[T, M, C]`` cube.
+* ``stjoin_sim_cube``  — pass 2 where the cube exists: the raw similarity
+  of ``stjoin_sim_fused``, bit for bit, read from the join's tiles.
 """
 from __future__ import annotations
 
@@ -32,6 +34,7 @@ from repro.kernels.stjoin.stjoin import (
     stjoin_dtj_pallas,
     stjoin_pallas,
     stjoin_sim_fused_flat,
+    stjoin_sim_fused_flat_cube,
     stjoin_sim_panel_flat,
     stjoin_vote_fused_flat,
 )
@@ -497,6 +500,51 @@ def stjoin_sim_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,
         cand.x, cand.y, cand.t, cand.valid, cand.traj_id, cand_sub_local,
         max_subs, eps_sp, eps_t, delta_t, rows=rows, bc=bc, bm=bm,
         tile_ids=tile_ids, interpret=interpret)
+
+
+def cand_starts(cand_sub, max_subs: int):
+    """``[C, Mc]`` candidate sub-slots (-1 on invalid points) -> ``[max_subs,
+    C]`` i32: row ``a`` holds each candidate's first point of sub-slot
+    ``a`` or more, ``Mc`` where it has none.  The boundaries from which
+    ``stjoin_sim_fused_flat_cube`` maps a matched point to its sub-slot."""
+    Mc = cand_sub.shape[1]
+    a = jnp.arange(max_subs, dtype=jnp.int32)[:, None, None]
+    m = jnp.arange(Mc, dtype=jnp.int32)
+    return jnp.min(jnp.where(cand_sub[None] >= a, m, Mc), axis=2).astype(
+        jnp.int32)
+
+
+def stjoin_sim_cube(join: JoinResult, ref_sub_local, cand_sub_local,
+                    max_subs: int, *, tile_ids=None,
+                    rows: int | None = None, bc: int = 128, bm: int = 128,
+                    interpret: bool | None = None):
+    """Pass 2 from a materialized join: ``stjoin_sim_fused``'s raw
+    ``[T * max_subs, C * max_subs]``, bit for bit, read from the kept tiles
+    of ``join`` (``[T, M, C]``, DTJ's refine applied, as
+    ``subtrajectory_join`` returns it) instead of re-running the pair
+    tests.  Sub-slots as ``segmentation`` builds them: nondecreasing
+    along each row's valid points, -1 elsewhere.  The geometry and
+    ``tile_ids`` are the sweep's (``plan_fused_tiles``).
+
+    The kernel reads the cube's ``[T * M, C]`` view, a bitcast; only a
+    batch whose ``T`` or ``C`` leaves a ragged last tile pads a copy.
+    """
+    T, M, C = join.best_w.shape
+    rows, bc, bm, _, _ = _fused_geometry(T, M, C, cand_sub_local.shape[1],
+                                         rows, bc, bm)
+
+    def view(a, fill):
+        return _pad_to(_pad_to(a.reshape(T * M, C), rows * M, 0, fill),
+                       bc, 1, fill)
+
+    rsub = _pad_to(ref_sub_local.astype(jnp.int32), rows, 0, -1).reshape(-1)
+    start = _pad_to(cand_starts(cand_sub_local, max_subs), bc, 1,
+                    cand_sub_local.shape[1])
+    raw = stjoin_sim_fused_flat_cube(
+        view(join.best_w, 0.0), view(join.best_idx, -1), rsub, start,
+        _resolve_plan(tile_ids, rows, bc, bm), rows=rows, M=M, ms=max_subs,
+        bc=bc, interpret=interpret)
+    return raw[:T * max_subs, :C * max_subs]
 
 
 def stjoin_sim_panel_fused(ref: TrajectoryBatch, cand: TrajectoryBatch,

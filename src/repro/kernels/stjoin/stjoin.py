@@ -58,6 +58,11 @@ Kernels
                              slots of its own rows and candidates).
 * ``stjoin_sim_panel_flat``  fused pass 2 restricted to one row panel, in
                              both orientations (top-K engine, DESIGN §8).
+* ``stjoin_sim_fused_flat_cube``
+                             pass 2 from a materialized join: the same raw
+                             blocks, read from the join's ``best_w`` and
+                             ``best_idx`` tiles instead of recomputed
+                             (materialize mode, dense similarity).
 """
 from __future__ import annotations
 
@@ -478,22 +483,31 @@ def _sim_kernel(codes, base, rx, ry, rt, rid, rok, rsub, cx, cy, ct, cid,
             n=cx.shape[0], csub=csub)
         w_scr[...] = _run_refine(w, rt[...], rows, M, eps[2])
         sub_scr[...] = sub
-        k = jax.lax.broadcasted_iota(jnp.int32, (ms, 1), 0)
+        _scatter(out, rsub, w_scr, sub_scr, rows=rows, M=M)
 
-        def row(r, carry):
-            def point(m, carry):
-                p = r * M + m
-                a = rsub[0, 0, p]
 
-                @pl.when(a >= 0)
-                def _add():
-                    hit = k == sub_scr[pl.ds(p, 1), :]           # [ms, bc]
-                    out[r * ms + a] += jnp.where(
-                        hit, w_scr[pl.ds(p, 1), :], 0.0)
-                return carry
-            return jax.lax.fori_loop(0, M, point, carry)
+def _scatter(out, rsub, w, sub, *, rows: int, M: int):
+    """Add the tile's ``[bp, bc]`` best weights ``w`` into the raw block
+    ``out [rows * ms, ms, bc]``: ref point ``p = r * M + m`` (sub-slot
+    ``rsub[0, 0, p]``, skipped when -1) into row ``r * ms + a``, candidate
+    sub-slot ``sub[p]`` (-1: nothing).  Rows, then points in ascending
+    order: the order of the materializing scatter-add."""
+    ms = out.shape[1]
+    k = jax.lax.broadcasted_iota(jnp.int32, (ms, 1), 0)
 
-        jax.lax.fori_loop(0, rows, row, 0)
+    def row(r, carry):
+        def point(m, carry):
+            p = r * M + m
+            a = rsub[0, 0, p]
+
+            @pl.when(a >= 0)
+            def _add():
+                hit = k == sub[pl.ds(p, 1), :]                   # [ms, bc]
+                out[r * ms + a] += jnp.where(hit, w[pl.ds(p, 1), :], 0.0)
+            return carry
+        return jax.lax.fori_loop(0, M, point, carry)
+
+    jax.lax.fori_loop(0, rows, row, 0)
 
 
 def _sim_call(ref_ops, rsub, cand_ops, csub, eps, codes, base, *, rows: int,
@@ -544,12 +558,11 @@ def _sim_call(ref_ops, rsub, cand_ops, csub, eps, codes, base, *, rows: int,
     return jnp.swapaxes(out, 1, 2).reshape(nI * R, -1)
 
 
-def _sim_plan(ref_ops, cand_ops, tile_ids, rows, M, bc):
-    """``(nRb, nCb, codes)`` for pass 2.  A plan built on pass 1's
-    words-padded candidate axis may list blocks past this one; those are
-    pure padding (no valid point, so no survivor), dropped all the same."""
-    P = ref_ops[0].shape[0]
-    C = cand_ops[0].shape[0]
+def _sim_plan(P, C, tile_ids, rows, M, bc):
+    """``(nRb, nCb, codes)`` for pass 2 over ``P`` reference points and
+    ``C`` candidates.  A plan built on pass 1's words-padded candidate
+    axis may list blocks past this one; those are pure padding (no valid
+    point, so no survivor), dropped all the same."""
     assert P % (rows * M) == 0 and C % bc == 0, (P, C, rows, M, bc)
     nRb, nCb = P // (rows * M), C // bc
     if tile_ids is None:
@@ -576,7 +589,8 @@ def stjoin_sim_fused_flat(ref_ops, ref_sub, cand_ops, cand_sub, eps_sp,
     """
     if interpret is None:
         interpret = default_interpret()
-    _, nCb, codes = _sim_plan(ref_ops, cand_ops, tile_ids, rows, M, bc)
+    _, nCb, codes = _sim_plan(ref_ops[0].shape[0], cand_ops[0].shape[0],
+                              tile_ids, rows, M, bc)
     return _sim_call(ref_ops, ref_sub, cand_ops, cand_sub,
                      _eps(eps_sp, eps_t, delta_t), codes,
                      jnp.zeros((2,), jnp.int32), rows=rows, M=M, ms=ms,
@@ -603,7 +617,8 @@ def stjoin_sim_panel_flat(ref_ops, ref_sub, cand_ops, cand_sub, eps_sp,
     """
     if interpret is None:
         interpret = default_interpret()
-    nRb, nCb, codes = _sim_plan(ref_ops, cand_ops, tile_ids, rows, M, bc)
+    nRb, nCb, codes = _sim_plan(ref_ops[0].shape[0], cand_ops[0].shape[0],
+                                tile_ids, rows, M, bc)
     eps = _eps(eps_sp, eps_t, delta_t)
     p0 = jnp.asarray(p0, jnp.int32)
     kw = dict(rows=rows, M=M, ms=ms, bc=bc, interpret=interpret)
@@ -624,3 +639,92 @@ def stjoin_sim_panel_flat(ref_ops, ref_sub, cand_ops, cand_sub, eps_sp,
                          n_out_blocks=nJ, **kw)
     rev = jax.lax.dynamic_slice_in_dim(cols_raw, p0 - j0 * Rc, panel, axis=1)
     return fwd, rev.T
+
+
+# ---------------------------------------------------------------------------
+# Pass 2 from the materialized join: the same scatter, read from the cube
+# ---------------------------------------------------------------------------
+
+
+def _cube_sub(idx, start):
+    """The sub-slot of each matched candidate point, without a gather.
+
+    ``idx [bp, bc]``: the join's best point index (-1: no match);
+    ``start [ms, bc]``: ``start[a, c]`` is candidate ``c``'s first valid
+    point of sub-slot ``a`` or more (``Mc`` if none).  Sub-slots do not
+    decrease along a row's valid points (``segmentation._finalize``), so a
+    matched point's sub-slot is the count of boundaries ``a >= 1`` at or
+    below it.
+    """
+    sub = jnp.zeros_like(idx)
+    for a in range(1, start.shape[0]):
+        sub += (idx >= start[a:a + 1, :]).astype(jnp.int32)
+    return jnp.where(idx < 0, -1, sub)
+
+
+def _sim_cube_kernel(codes, rsub, bw, bidx, start, o0, out, sub_scr, *,
+                     rows: int, M: int):
+    """``_sim_kernel``'s raw block from the join's tile ``(bw, bidx)``:
+    the same scatter, in the same order, of weights already refined."""
+    del o0                        # aliased zeros (unvisited blocks)
+    i, s = pl.program_id(0), pl.program_id(1)
+    live = codes[i, s] >= 0
+
+    @pl.when(live | (s == 0))
+    def _init():
+        out[...] = jnp.zeros_like(out)
+
+    @pl.when(live)
+    def _tile():
+        sub_scr[...] = _cube_sub(bidx[...], start[...])
+        _scatter(out, rsub, bw, sub_scr, rows=rows, M=M)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rows", "M", "ms", "bc", "interpret"))
+def stjoin_sim_fused_flat_cube(best_w, best_idx, ref_sub, cand_start,
+                               tile_ids=None, *, rows: int, M: int, ms: int,
+                               bc: int = 128, interpret: bool | None = None):
+    """Pass 2 from a materialized join: ``stjoin_sim_fused_flat``'s raw
+    scatter ``[P/M * ms, C * ms]``, bit for bit, read from the join's
+    tiles instead of re-running their pair tests.
+
+    ``best_w`` / ``best_idx`` ``[P, C]``: the join cube's ``[T * M, C]``
+    view, with DTJ's refine already applied (the join's kernels do; at
+    ``delta_t`` 0 it keeps every match), ``P % (rows * M) == 0`` and ``C
+    % bc == 0``.  ``ref_sub [P]``: each ref point's sub-slot (-1 skips
+    it); ``cand_start [ms, C]``: each candidate's sub-slot boundaries
+    (``ops.cand_starts``).  ``tile_ids`` reads only the listed candidate
+    blocks; the tiles it leaves out hold no match.
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    P, C = best_w.shape
+    _, _, codes = _sim_plan(P, C, tile_ids, rows, M, bc)
+    bp, R = rows * M, rows * ms
+    nI, K = codes.shape
+    f32 = jnp.float32
+    tile_spec = pl.BlockSpec((bp, bc), lambda i, s, c: (i, _block_of(c[i, s])))
+    rsub_spec = pl.BlockSpec((1, 1, bp), lambda i, s, c: (i, 0, 0),
+                             memory_space=pltpu.SMEM)
+    start_spec = pl.BlockSpec((ms, bc),
+                              lambda i, s, c: (0, _block_of(c[i, s])))
+    out_spec = pl.BlockSpec(
+        (R, ms, bc), lambda i, s, c: (i, 0, _block_of(c[i, s])))
+    out_shape = (nI * R, ms, C)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(nI, K),
+        in_specs=[rsub_spec, tile_spec, tile_spec, start_spec, _ANY],
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((bp, bc), jnp.int32)])
+    out = pl.pallas_call(
+        functools.partial(_sim_cube_kernel, rows=rows, M=M),
+        grid_spec=grid_spec, out_shape=jax.ShapeDtypeStruct(out_shape, f32),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(codes, ref_sub.astype(jnp.int32).reshape(-1, 1, bp),
+      best_w.astype(f32), best_idx.astype(jnp.int32),
+      cand_start.astype(jnp.int32), jnp.zeros(out_shape, f32))
+    return jnp.swapaxes(out, 1, 2).reshape(nI * R, -1)

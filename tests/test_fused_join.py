@@ -332,3 +332,198 @@ def test_run_dsc_returns_the_join_counts():
                 == np.asarray(getattr(ref.result, f))).all()
     assert run_dsc(b, params.replace(delta_t=0.0),
                    plan=plan).join_matches is None
+
+
+# ---------------------------------------------------------------------------
+# Pass 2 from the materialized cube: the sweep's raw scatter, bit for bit
+# ---------------------------------------------------------------------------
+
+
+def _random_seg(rng, valid, max_subs, p_cut):
+    """A segmentation as ``segmentation`` builds it, from random cuts:
+    sub-slots nondecreasing along each row's valid points, clipped at
+    ``max_subs - 1``, -1 on invalid points."""
+    cut = jnp.asarray(rng.uniform(0, 1, valid.shape) < p_cut)
+    return segmentation._finalize(cut, valid, jnp.zeros(valid.shape),
+                                  max_subs).sub_local
+
+
+def _cube(b, c, delta_t, source, use_index):
+    """The join cube of ``b`` against ``c``: ``"pallas"`` the plain
+    kernel, ``"filter"`` the plain kernel refined by ``filter_delta_t``,
+    ``"dtj"`` the kernel with the refine inside (also at ``delta_t`` 0)."""
+    from repro.core.types import JoinResult
+    from repro.kernels.stjoin.stjoin import stjoin_dtj_pallas
+    if source == "dtj" and delta_t == 0:
+        T, M = b.x.shape
+        C = c.x.shape[0]
+        bc, bm = stjoin_ops._join_geometry(C, c.x.shape[1], 128, 128)
+        bp = stjoin_ops._row_block(T, M, 256, 1.0)
+        ref_ops, cand_ops = stjoin_ops._padded_operands(b, c, bp, bc, bm)
+        w, idx, _ = stjoin_dtj_pallas(*ref_ops, *cand_ops, 2.5, 12.0, 0.0,
+                                      rows=bp // M, M=M, bc=bc, bm=bm)
+        return JoinResult(best_w=w[:T * M, :C].reshape(T, M, C),
+                          best_idx=idx[:T * M, :C].reshape(T, M, C))
+    join, _ = stjoin_ops.subtrajectory_join(
+        b, c, 2.5, 12.0, delta_t if source == "dtj" else 0.0,
+        use_index=use_index)
+    if source == "filter":
+        join = geometry.filter_delta_t(join, b.t, delta_t)
+    return join
+
+
+def _plant_ties(rng, c, n):
+    """Copy fix ``j`` of ``n`` candidate rows onto fix ``j + 1``, so that
+    both weigh the same against every ref point; returns the batch and
+    the ``(row, j)`` pairs."""
+    x, y, t = (np.array(a) for a in (c.x, c.y, c.t))
+    v = np.array(c.valid)
+    rows = rng.choice(c.x.shape[0], n, replace=False)
+    js = rng.integers(0, c.x.shape[1] - 1, n)
+    for r, j in zip(rows, js):
+        for a in (x, y, t):
+            a[r, j + 1] = a[r, j]
+        v[r, j] = v[r, j + 1] = True
+    return TrajectoryBatch(x=jnp.asarray(x), y=jnp.asarray(y),
+                           t=jnp.asarray(t), valid=jnp.asarray(v),
+                           traj_id=c.traj_id), list(zip(rows, js))
+
+
+@pytest.mark.parametrize("T,M,C,Mc,rows,bc,bm,delta_t,source,use_index", [
+    (4, 16, 8, 16, 2, 8, 16, 0.0, "pallas", False),  # whole tiles, dense
+    (4, 16, 8, 16, 2, 8, 16, 0.0, "pallas", True),   # an index plan
+    (5, 17, 7, 13, 3, 8, 8, 0.0, "pallas", True),    # T, C and Mc ragged
+    (5, 17, 7, 13, 3, 8, 8, 6.0, "dtj", False),
+    (5, 17, 7, 13, 3, 8, 8, 6.0, "dtj", True),
+    (6, 16, 9, 12, 2, 4, 8, 4.0, "filter", True),
+    (6, 16, 9, 12, 2, 4, 8, 0.0, "dtj", False),      # the refine keeps all
+    (3, 40, 35, 11, 2, 32, 128, 0.0, "pallas", True),  # bc 32, bm > Mc
+])
+def test_cube_pass2_equals_the_sweep(T, M, C, Mc, rows, bc, bm, delta_t,
+                                     source, use_index):
+    """``stjoin_sim_cube`` over the join's cube gives ``stjoin_sim_fused``'s
+    raw similarity bit for bit: plain and refined cubes, dense and index
+    plans, ragged geometries, invalid and unsegmented ref points, padded
+    rows, and exact weight ties across a sub-slot boundary."""
+    max_subs = 4
+    rng = np.random.default_rng(T * 1000 + C * 10 + int(delta_t))
+    b = _rand_batch(rng, T, M, pad_row=0)
+    c, ties = _plant_ties(rng, _rand_batch(rng, C, Mc), 3)
+    ref_sub = np.array(_random_seg(rng, b.valid, max_subs, 0.15))
+    ref_sub[rng.uniform(0, 1, ref_sub.shape) < 0.1] = -1  # unsegmented
+    ref_sub = jnp.asarray(ref_sub)
+    cut = rng.uniform(0, 1, c.valid.shape) < 0.2
+    for r, j in ties:
+        cut[r, j + 1] = True              # the tie spans a sub-slot cut
+    cand_sub = segmentation._finalize(jnp.asarray(cut), c.valid,
+                                      jnp.zeros(c.valid.shape),
+                                      max_subs).sub_local
+    join = _cube(b, c, delta_t, source, use_index)
+    idx = np.asarray(join.best_idx)
+    assert any((idx[:, :, r] == j).any() for r, j in ties)
+    kw = dict(rows=rows, bc=bc, bm=bm)
+    tiles = (stjoin_ops.plan_fused_tiles(b.x, b.y, b.t, b.valid, c.x, c.y,
+                                         c.t, c.valid, 2.5, 12.0, **kw)
+             if use_index else None)
+    want = np.asarray(stjoin_ops.stjoin_sim_fused(
+        b, c, ref_sub, cand_sub, max_subs, 2.5, 12.0, delta_t,
+        tile_ids=tiles, **kw))
+    got = np.asarray(stjoin_ops.stjoin_sim_cube(
+        join, ref_sub, cand_sub, max_subs, tile_ids=tiles, **kw))
+    assert want.sum() > 0
+    assert got.shape == want.shape
+    assert (got.view(np.uint32) == want.view(np.uint32)).all()
+
+
+@pytest.mark.parametrize("seed,max_subs,p_cut", [
+    (0, 4, 0.05),     # few cuts: candidates with fewer than max_subs slots
+    (1, 4, 0.6),      # many cuts: the last slot clipped at max_subs - 1
+    (2, 8, 0.2),
+    (3, 1, 0.3),      # one slot: every match is slot 0
+])
+def test_cube_sub_slot_map_equals_the_gather(seed, max_subs, p_cut):
+    """The sub-slot from ``cand_starts``'s boundaries is the sub-slot of
+    the matched point, ``take_along_axis`` of the candidate's sub-slots,
+    and -1 where nothing matched."""
+    from repro.kernels.stjoin.stjoin import _cube_sub
+    rng = np.random.default_rng(seed)
+    C, Mc, P = 24, 20, 64
+    valid = jnp.asarray(rng.uniform(0, 1, (C, Mc)) > 0.2)
+    cand_sub = _random_seg(rng, valid, max_subs, p_cut)
+    # a matched point is a valid one; -1 where nothing matched
+    pick = rng.integers(0, Mc, (P, C))
+    ok = np.asarray(valid)[np.arange(C)[None, :], pick]
+    idx = np.where(ok & (rng.uniform(0, 1, (P, C)) > 0.2), pick, -1)
+    got = np.asarray(_cube_sub(jnp.asarray(idx, jnp.int32),
+                               stjoin_ops.cand_starts(cand_sub, max_subs)))
+    want = np.take_along_axis(np.asarray(cand_sub).T,
+                              np.maximum(idx, 0), axis=0)
+    want = np.where(idx < 0, -1, want)
+    assert (idx < 0).any() and (got == want).all()
+    n_subs = np.asarray(cand_sub).max(axis=1) + 1
+    if p_cut < 0.1:
+        assert (n_subs < max_subs).any()
+    if p_cut > 0.5:
+        assert (want == max_subs - 1).any()
+
+
+@pytest.mark.parametrize("segmentation_,delta_t", [("tsa1", 0.0),
+                                                   ("tsa2", 0.3)])
+def test_run_dsc_cube_pass2_equals_fused(fig1, segmentation_, delta_t):
+    """``run_dsc`` on the kernel join in materialize mode (pass 2 from the
+    cube) and in fused mode (pass 2 by the sweep): the same segmentation,
+    similarity, labels and scores."""
+    from repro.core.plan import EnginePlan
+    batch, _ = fig1
+    params = DSCParams(eps_sp=0.42, eps_t=1.0, delta_t=delta_t, w=6,
+                       tau=0.15, alpha_sigma=-1.0, k_sigma=-1.0,
+                       segmentation=segmentation_)
+    cube = run_dsc(batch, params, plan=EnginePlan(
+        mode="materialize", use_kernel=True, use_index=True))
+    fused = run_dsc(batch, params, plan=EnginePlan(mode="fused",
+                                                   use_index=True))
+    assert cube.join is not None and fused.join is None
+    assert (np.asarray(cube.seg.sub_local)
+            == np.asarray(fused.seg.sub_local)).all()
+    assert (np.asarray(cube.sim) == np.asarray(fused.sim)).all()
+    for f in ("member_of", "is_rep", "is_outlier"):
+        assert (np.asarray(getattr(cube.result, f))
+                == np.asarray(getattr(fused.result, f))).all()
+    assert float(cube.sscr) == float(fused.sscr)
+    assert float(cube.rmse) == float(fused.rmse)
+
+
+@pytest.mark.parametrize("plan_kw,want", [
+    (dict(mode="materialize", use_kernel=True, use_index=True), "cube"),
+    (dict(mode="materialize", use_kernel=True), "cube"),
+    (dict(mode="fused", use_index=True), "sweep"),
+    (dict(mode="materialize", use_kernel=True, sim_mode="topk"), "sweep"),
+    (dict(mode="materialize"), "scatter"),
+])
+def test_run_dsc_span_names_its_pass2(tmp_path, plan_kw, want):
+    """The host span ``dsc.run`` carries ``pass2``: which pass 2 built
+    the similarity."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core.plan import EnginePlan
+    rng = np.random.default_rng(3)
+    b = _rand_batch(rng, 6, 16)
+    params = DSCParams(eps_sp=2.5, eps_t=12.0, w=3, tau=0.2,
+                       alpha_sigma=-1.0, k_sigma=-1.0)
+    plan = EnginePlan(**plan_kw)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(run_dsc(b, params, plan=plan).result)
+    finally:
+        jax.profiler.stop_trace()
+    f, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                   recursive=True)
+    host = next(p for p in ProfileData.from_file(f).planes
+                if p.name == "/host:CPU")
+    runs = [dict(ev.stats) for ln in host.lines for ev in ln.events
+            if ev.name == "dsc.run"]
+    assert [r["pass2"] for r in runs] == [want]

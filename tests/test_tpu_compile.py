@@ -91,6 +91,7 @@ def _kernel_calls(spec):
     fused_tiles = spec((P // (ROWS * M), K), i32)
     fused = dict(rows=ROWS, M=M, bc=128, interpret=False)
     sim = dict(fused, ms=MS)
+    start = spec((MS, C), i32)
     vecs = [spec((S,), i32), spec((S,), b), spec((S,), b)]
     lists = [spec((S, 33), i32), spec((S, 33), f32)]
     return {
@@ -136,6 +137,13 @@ def _kernel_calls(spec):
             lambda r, rs, c, cs, *a: sk.stjoin_sim_panel_flat(
                 r, rs, c, cs, *a, panel=128, **sim),
             [ref, sub_r, cand, sub_c, e, e, e, spec((), i32), fused_tiles]),
+        "sim_cube_dense": (
+            lambda *a: sk.stjoin_sim_fused_flat_cube(*a, **sim),
+            [spec((P, C), f32), spec((P, C), i32), sub_r, start]),
+        "sim_cube_pruned": (
+            lambda *a: sk.stjoin_sim_fused_flat_cube(*a, **sim),
+            [spec((P, C), f32), spec((P, C), i32), sub_r, start,
+             fused_tiles]),
         "cluster_round_scan": (
             lambda *a: ck.round_scan_pallas(*a, interpret=False),
             [spec((S, S), f32), *vecs, e]),
@@ -162,7 +170,8 @@ def _kernel_calls(spec):
 KERNELS = ["stjoin_dense", "stjoin_pruned", "stjoin_dtj_dense",
            "stjoin_dtj_pruned", "vote_dense_tsa2",
            "vote_dense_tsa1", "vote_pruned", "sim_dense", "sim_pruned",
-           "sim_panel_dense", "sim_panel_pruned", "cluster_round_scan",
+           "sim_panel_dense", "sim_panel_pruned", "sim_cube_dense",
+           "sim_cube_pruned", "cluster_round_scan",
            "cluster_assign", "topk_round_scan", "topk_assign", "jaccard",
            "stjoin_mesh_slab"]
 
