@@ -600,7 +600,7 @@ def bench_pipeline(smoke: bool = False, out_dir: str = ".") -> dict:
     # element count (bc == C), which would defeat the cube-fingerprint
     # check below — pin a geometry whose blocks cannot collide.
     fkw = dict(rows=8, bc=8, bm=16) if smoke else {}
-    ftiles = (fkw["rows"], fkw["bc"], fkw["bm"]) if fkw else None
+    fplan = {f"fused_{k}": v for k, v in fkw.items()}
 
     # ---- per-stage wall-clock ------------------------------------------
     # measure_compiled throughout: one compile, a warm replay excluded,
@@ -672,13 +672,11 @@ def bench_pipeline(smoke: bool = False, out_dir: str = ".") -> dict:
     # itself run eagerly (DESIGN.md §3)
     e2e_plans = {
         "materialize_jnp_us": EnginePlan(),
-        "materialize_kernel_us": EnginePlan.from_legacy(use_kernel=True),
-        "fused_us": EnginePlan.from_legacy(mode="fused",
-                                           fused_tiles=ftiles),
-        "seg_kernel_us": EnginePlan.from_legacy(seg_use_kernel=True),
-        "topk_us": EnginePlan.from_legacy(sim_mode="topk"),
-        "topk_fused_us": EnginePlan.from_legacy(
-            mode="fused", sim_mode="topk", fused_tiles=ftiles),
+        "materialize_kernel_us": EnginePlan(use_kernel=True),
+        "fused_us": EnginePlan(mode="fused", **fplan),
+        "seg_kernel_us": EnginePlan(seg_use_kernel=True),
+        "topk_us": EnginePlan(sim_mode="topk"),
+        "topk_fused_us": EnginePlan(mode="fused", sim_mode="topk", **fplan),
     }
     e2e, e2e_out = {}, {}
     for key, plan in e2e_plans.items():

@@ -147,13 +147,16 @@ def batch_phase(dev, plan_path: Path):
 
 def parity_phase(dev, plan_path: Path):
     import numpy as np
+    from repro.core.plan import EnginePlan
     from repro.launch import run_dsc
+    plain_path = plan_path.with_name("sequential_plan.json")
+    EnginePlan(cluster_engine="sequential").save(plain_path)
     for seg in ("tsa1", "tsa2"):
         t0 = time.perf_counter()
         got = run_dsc.main(launcher_args(PARITY_T, seg,
                                          ["--plan", str(plan_path)]))
         want = run_dsc.main(launcher_args(
-            PARITY_T, seg, ["--cluster-engine", "sequential"]))
+            PARITY_T, seg, ["--plan", str(plain_path)]))
         a, b = labels(got), labels(want)
         same = {f: bool(np.array_equal(a[f], b[f])) for f in a}
         reps, _, _ = check_labels(got, f"parity {seg}")

@@ -18,6 +18,7 @@ import jax
 
 from repro.core.distributed import run_dsc_distributed
 from repro.core.partitioning import partition_batch
+from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams
 from repro.data.synthetic import ais_like, default_dsc_params_for
 
@@ -33,7 +34,8 @@ def main():
 
     mesh = jax.make_mesh((4, 2), ("part", "model"))
     parts = partition_batch(batch, 4)
-    out = run_dsc_distributed(parts, params, mesh, use_kernel=True)
+    out = run_dsc_distributed(parts, params, mesh,
+                              plan=EnginePlan(use_kernel=True))
 
     res = out.result
     member_of = np.asarray(res.member_of)

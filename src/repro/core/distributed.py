@@ -51,7 +51,7 @@ from repro.core import segmentation as seg_mod
 from repro.core.clustering import cluster
 from repro.core.geometry import refine_join
 from repro.core.partitioning import PartitionedBatch
-from repro.core.plan import EnginePlan, resolve_plan
+from repro.core.plan import EnginePlan
 from repro.core.refine import refine_states
 from repro.core.similarity import (build_subtraj_table_arrays, finalize_sim,
                                    finalize_sim_cols,
@@ -120,14 +120,11 @@ def run_dsc_distributed(
     model_axis: str = "model",
     on_overflow: str = "raise",
     plan: EnginePlan | None = None,
-    **kw,
 ) -> DistributedDSCOutput:
     """Compile & run the full distributed pipeline on ``mesh``.
 
-    ``plan=`` takes one :class:`EnginePlan`; the remaining keyword
-    arguments are the deprecated per-stage aliases (``use_kernel``,
-    ``use_index``, ``mode``, ``sim_mode``, ... — see
-    ``build_dsc_program``) that materialize a plan when none is given.
+    ``plan`` holds every per-stage engine and tile choice (``None`` =
+    ``EnginePlan()``; :class:`EnginePlan` says what each field selects).
 
     Under ``sim_mode="topk"`` the per-partition exactness certificate is
     checked on the host and ``on_overflow`` names the policy
@@ -142,7 +139,7 @@ def run_dsc_distributed(
     if on_overflow not in ("raise", "widen", "degrade"):
         raise ValueError(f"on_overflow={on_overflow!r}: expected "
                          "'raise', 'widen', or 'degrade'")
-    plan = resolve_plan(plan, **kw)
+    plan = (plan or EnginePlan()).validate()
     S = parts.x.shape[1] * params.max_subtrajs_per_traj
     while True:
         fn = build_dsc_program(parts, params, mesh, part_axis=part_axis,
@@ -172,10 +169,10 @@ def run_dsc_distributed(
 
 
 def run_dsc_distributed_lowerable(parts: PartitionedBatch,
-                                  params: DSCParams, mesh: Mesh,
-                                  **kw):
+                                  params: DSCParams, mesh: Mesh, *,
+                                  plan: EnginePlan | None = None):
     """jit-friendly entry (parts as a pytree arg) for the dry-run."""
-    fn = build_dsc_program(parts, params, mesh, **kw)
+    fn = build_dsc_program(parts, params, mesh, plan=plan)
     return fn(parts.x, parts.y, parts.t, parts.valid, parts.traj_id,
               parts.ranges)
 
@@ -686,104 +683,19 @@ def build_dsc_program(
     *,
     part_axis: str = "part",
     model_axis: str = "model",
-    plan: EnginePlan | None = None,  # the one tuned surface (DESIGN.md §9)
-    use_kernel: bool = False,
-    use_index: bool = False,
-    mode: str = "materialize",      # "materialize" | "fused"
-    sim_strategy: str = "psum",     # "psum" | "allgather" (column-sharded)
-    sim_dtype: str = "f32",         # "f32" | "bf16" collective payload
-    cluster_engine: str = "rounds",  # "rounds" | "sequential" (oracle)
-    cluster_use_kernel: bool = False,  # Pallas tile kernels for phase 5
-    seg_use_kernel: bool = False,    # Pallas TSA2 Jaccard kernel, phase 3
-    sim_mode: str = "dense",        # "dense" | "topk" SP representation
-    sim_topk: int | None = None,    # K of the top-K neighbor lists (32)
-    halo_stream: str = "barrier",   # "barrier" | "ring" join halo schedule
-    sim_exchange: str = "allgather",  # "allgather" | "ring" sim schedule
+    plan: EnginePlan | None = None,
 ):
     """Build the shard_map program (not yet jitted) for ``parts`` shapes.
 
-    ``plan=`` carries every per-stage choice as one :class:`EnginePlan`;
-    the per-stage keywords below are **deprecated aliases** that
-    materialize a plan (``repro.core.plan.resolve_plan``) — passing both
-    a plan and a non-default alias raises.
-
-    ``mode="fused"`` streams the JOIN phase per halo slab: instead of
-    building the per-rank ``[T, Mp, Tc]`` join cube and re-reading it for
-    votes / TSA2 masks / the SP scatter, two fused Pallas sweeps accumulate
-    those outputs directly (pass 2 re-sweeps after segmentation).  The
-    collective payloads shrink with the buffers: votes psum as before, TSA2
-    neighbor sets all_gather as packed words (32x smaller than the bool
-    ``matched`` cube), and the SP accumulator follows ``sim_strategy``
-    unchanged.  ``use_index`` composes with it (the halo bbox bucketing is
-    join-free); the in-kernel delta_t refine matches ``filter_delta_t`` on
-    the partition slab exactly.
-
-    ``sim_strategy="allgather"`` exploits that each model rank's scatter
-    targets only ITS candidate-column block of the SP matrix: instead of a
-    dense [S, S] psum (2x bytes, 16x memory), each rank all_gathers its
-    [S, S/m] block — the §Perf optimization for the DSC cells.
-    ``sim_dtype="bf16"`` additionally halves the payload.
-
-    ``use_index=True`` turns on the spatiotemporal candidate-pruning index
-    (``repro.index.grid``) in the JOIN phase: partitions first exchange
-    their eps-expanded bounding boxes (6 floats) and tighten the validity
-    mask of the slab they ship to each neighbor down to the points that
-    neighbor can actually match (slab *bytes* are unchanged — fixed
-    shapes — but out-of-reach points never enter the join or any
-    downstream reduction), and the jnp join path additionally skips
-    (ref row, cand row) pairs whose bboxes are provably farther than eps
-    apart.  Both filters are conservative, so results are unchanged.
-
-    ``cluster_engine`` selects the phase-5 engine per partition:
-    ``"rounds"`` (round-parallel, default) or ``"sequential"`` (the O(S)
-    oracle); outputs are label-identical (DESIGN.md §6).
-    ``cluster_use_kernel=True`` backs the round engine with the Pallas
-    tile kernels (``repro.kernels.cluster``) inside each partition's
-    shard — the accelerator path; the jnp formulation is faster on
-    CPU.
-
-    ``seg_use_kernel=True`` runs phase 3's TSA2 Jaccard signal through
-    the fused Pallas segmentation kernel (``repro.kernels.jaccard``)
-    inside each shard instead of the jnp packed-word engine —
-    bit-identical cuts and labels (DESIGN.md §7); a no-op under
-    ``tsa1``.
-
-    ``sim_mode="topk"`` keeps the SP relation sparse end to end
-    (DESIGN.md §8): each model rank builds only its ``[S, S_loc]``
-    candidate-column block of the raw scatter (``S_loc = S / m``), an
-    all_to_all hands every rank the transpose-partner rows of its block
-    (each byte of the matrix moves once, vs. the dense ``[S, S]``
-    psum's 2x-all-reduce), rank-exact max-symmetrization + Eq. 2
-    normalization happen on the block, and the only replicated payload
-    is the all_gather of per-rank top-(K+1) candidate lists —
-    ``[S, K+1]`` ids+sims instead of ``[S, S]``.  Phase 5 clusters on
-    the merged ``TopKSim`` neighbor lists; labels are bit-identical to
-    ``sim_mode="dense"`` whenever the spill certificate holds (the
-    per-partition overflow count rides in ``sim_diag[:, 3]``; widen
-    ``sim_topk`` when nonzero — there is no in-graph retry).  Threshold
-    moments psum per-rank row partials in both modes, so dense and topk
-    resolve bit-identical alpha.  ``sim_strategy`` / ``sim_dtype`` only
-    shape the dense collective and are ignored under topk.
-
-    ``halo_stream="ring"`` / ``sim_exchange="ring"`` swap the phase
-    barriers for P-step ``ppermute`` ring schedules (DESIGN.md §12):
-    the materialize join folds one halo slab per step while the next is
-    in flight, the topk similarity exchange becomes a shifted-ppermute
-    transpose sweep plus a forwarding ring over the per-rank top-(K+1)
-    lists with a running canonical merge, and the dense ``allgather``
-    strategy assembles its column blocks around the forwarding ring.
-    Every ring schedule is bit-identical to its barrier twin; per-step
-    wire payloads shrink to 1/nM of the barrier gathers.  Fused mode
-    keeps the concatenated halo sweep (the in-kernel delta_t refine is
-    not slab-separable) and rings only its word/mask gathers; the dense
-    ``psum`` strategy is an all-reduce and ignores ``sim_exchange``."""
-    plan = resolve_plan(plan, use_kernel=use_kernel, use_index=use_index,
-                        mode=mode, sim_strategy=sim_strategy,
-                        sim_dtype=sim_dtype, cluster_engine=cluster_engine,
-                        cluster_use_kernel=cluster_use_kernel,
-                        seg_use_kernel=seg_use_kernel, sim_mode=sim_mode,
-                        sim_topk=sim_topk, halo_stream=halo_stream,
-                        sim_exchange=sim_exchange)
+    ``plan`` holds every per-stage engine and tile choice (``None`` =
+    ``EnginePlan()``); :class:`EnginePlan` says what each field selects
+    on a mesh, including the distributed-only collective shapes
+    (``sim_strategy``, ``sim_dtype``) and comm schedules
+    (``halo_stream``, ``sim_exchange``, DESIGN.md §12).  Under
+    ``sim_mode="topk"`` the per-partition overflow count rides in
+    ``sim_diag[:, 3]``; the program has no in-graph retry
+    (:func:`run_dsc_distributed` widens by rebuilding)."""
+    plan = (plan or EnginePlan()).validate()
     b = _DSCProgramBuilder(parts, params, mesh, part_axis, model_axis, plan)
 
     def body(px, py, pt, pv, traj_id, ranges):
@@ -851,7 +763,6 @@ def build_dsc_stage_programs(
     part_axis: str = "part",
     model_axis: str = "model",
     plan: EnginePlan | None = None,
-    **kw,
 ) -> dict:
     """One jitted program per checkpointable stage boundary.
 
@@ -877,7 +788,7 @@ def build_dsc_stage_programs(
     ``refine``      :func:`refine_stage` — a plain jit over the stacked
                     per-partition states; needs no mesh.
     """
-    plan = resolve_plan(plan, **kw)
+    plan = (plan or EnginePlan()).validate()
     b = _DSCProgramBuilder(parts, params, mesh, part_axis, model_axis, plan)
     part2 = P(part_axis, None, None)
     part3 = P(part_axis, None, None, None)

@@ -7,21 +7,10 @@ This is the semantic reference: the distributed pipeline
     subtrajectory join (Problem 1)  ->  voting  ->  segmentation (Problem 2)
     ->  ST / SP relations  ->  clustering + outliers (Problem 3)
 
-Execution modes (``mode=``, see README §Execution modes / DESIGN.md §3):
-
-* ``"materialize"`` — the parity oracle: the DTJ join cube
-  ``JoinResult [T, M, C]`` is built in HBM and re-read by each consumer
-  (voting, TSA2 masks, similarity scatter; on the kernel path the scatter
-  reads only the cube's kept tiles).
-* ``"fused"``       — streaming epilogue fusion: two Pallas sweeps
-  accumulate the consumers' O(T*M + S^2) outputs directly; the cube never
-  exists (``DSCOutput.join is None``).  Pass 2 recomputes the best-match
-  tiles after segmentation instead of re-reading them.
-
-``use_index=True`` prunes candidate tiles with the spatiotemporal grid
-(``repro.index.grid``) in every mode; pruning is conservative, so outputs
-are unchanged.  Index planning is host-driven, so that combination requires
-concrete (non-traced) inputs.
+Every engine and tile choice (join mode, kernels, grid index, similarity
+representation, clustering engine) comes from one
+:class:`repro.core.plan.EnginePlan`, whose module docstring says what
+each field selects and which path is the parity oracle.
 """
 from __future__ import annotations
 
@@ -35,7 +24,7 @@ from jax.profiler import TraceAnnotation
 from repro.core import geometry, segmentation, similarity, voting
 from repro.core.clustering import (cluster, rmse, rmse_from_result, sscr,
                                    sscr_from_result)
-from repro.core.plan import EnginePlan, resolve_plan
+from repro.core.plan import EnginePlan
 from repro.core.types import (ClusteringResult, DSCParams, JoinResult,
                               SubtrajSegmentation, SubtrajTable, TopKSim,
                               TrajectoryBatch)
@@ -373,7 +362,7 @@ def run_dsc_lowerable(batch: TrajectoryBatch, params: DSCParams,
     plan, and what anything embedding the pipeline inside a larger jit
     should call.
     """
-    plan = resolve_plan(plan)
+    plan = (plan or EnginePlan()).validate()
     if plan.use_index:
         raise ValueError("run_dsc_lowerable requires use_index=False "
                          "(index planning is host-driven); use run_dsc")
@@ -385,87 +374,34 @@ def run_dsc_lowerable(batch: TrajectoryBatch, params: DSCParams,
     return _run_dsc_materialize(batch, params, plan)
 
 
-def run_dsc(batch: TrajectoryBatch, params: DSCParams,
-            use_kernel: bool = False, *, use_index: bool = False,
-            mode: str = "materialize",
-            fused_tiles: tuple[int, int, int] | None = None,
-            cluster_engine: str = "rounds",
-            cluster_use_kernel: bool = False,
-            seg_use_kernel: bool = False,
-            sim_mode: str = "dense",
-            sim_topk: int | None = None,
-            sim_panel: int | None = None,
-            sim_topk_retry: bool = True,
-            on_overflow: str | None = None,
-            plan: EnginePlan | None = None) -> DSCOutput:
+def run_dsc(batch: TrajectoryBatch, params: DSCParams, *,
+            plan: EnginePlan | None = None,
+            on_overflow: str = "widen") -> DSCOutput:
     """Run the full DSC pipeline on one host / one partition.
 
-    ``plan=`` is the configuration surface: one :class:`EnginePlan`
-    holding every per-stage engine and tile choice (DESIGN.md §9).  The
-    per-stage keyword flags below are **deprecated aliases** that
-    materialize a plan via :func:`repro.core.plan.resolve_plan`; passing
-    both a plan and a non-default flag raises.
+    ``plan`` holds every per-stage engine and tile choice (``None`` =
+    ``EnginePlan()``); :class:`repro.core.plan.EnginePlan` says what each
+    field selects.  The call dispatches on it: the fused sweeps, the
+    grid-pruned Pallas join (host-side index planning, then the jitted
+    tail — concrete inputs only), or the materializing program.
 
-    ``mode="fused"`` streams the join (no ``[T, M, C]`` cube;
-    ``out.join is None``); ``mode="materialize"`` is the parity oracle.
-    ``use_index=True`` additionally prunes candidate tiles — host-driven
-    planning, so the inputs must be concrete in that case.
-    ``fused_tiles=(rows, bc, bm)`` overrides the fused kernels' tile
-    geometry (benchmarks use this to pin one inspected configuration).
-    ``cluster_engine`` selects the Problem 3 engine: ``"rounds"``
-    (round-parallel, default) or ``"sequential"`` (the O(S) parity
-    oracle) — label-identical outputs either way (DESIGN.md §6).
-    ``cluster_use_kernel=True`` runs the round engine's per-round scan
-    and claim-max through the fused Pallas tile kernels
-    (``repro.kernels.cluster``) — the accelerator path; the default jnp
-    formulation is faster on CPU, where the kernels run in interpret
-    mode.
-    ``seg_use_kernel=True`` computes the TSA2 Jaccard signal through the
-    fused Pallas segmentation kernel (``repro.kernels.jaccard``) instead
-    of the jnp packed-word engine — bit-identical cuts, segmentations,
-    and downstream labels (DESIGN.md §7); a no-op under ``tsa1``.
-
-    ``sim_mode="topk"`` replaces the dense ``[S, S]`` SP matrix with the
-    panel-streamed ``[S, K]`` neighbor-list representation (DESIGN.md §8):
-    similarity memory drops to O(S*K + Sb*S) and clustering consumes the
-    edge lists directly.  Labels are bit-identical to the dense path
-    whenever the per-row spill certificate holds (``out.sim_overflow ==
-    0``); on violation the run auto-retries with K doubled
-    (``sim_topk_retry``, host-level — requires concrete inputs) or raises.
-    ``sim_topk`` sets K (default 32, clamped to S); ``sim_panel`` bounds
-    the streaming panel height Sb (default 128, snapped to a divisor of
-    S).  ``out.sim`` is None in this mode (use ``out.sim_topk``).
-
-    ``on_overflow`` names the certificate-violation policy explicitly
-    (DESIGN.md §10): ``"widen"`` retries with K doubled, ``"raise"``
-    raises immediately, ``"degrade"`` returns the truncated result with
-    the violation recorded in ``out.sim_overflow``.  The default (None)
-    keeps the legacy ``sim_topk_retry`` behavior; passing both raises.
+    Under ``sim_mode="topk"`` the spill certificate ``out.sim_overflow``
+    is checked on the host after each run, and ``on_overflow`` names the
+    policy for a violation (DESIGN.md §10): ``"widen"`` reruns with K
+    doubled until it holds (K == S cannot spill), ``"raise"`` raises
+    ``RuntimeError`` at once, ``"degrade"`` returns the truncated result
+    with the violation recorded in ``out.sim_overflow``.
 
     The call is the host span ``dsc.run``, with ``T``, ``M``, ``mode``,
     the final ``k``, the ``attempts`` the top-K widen loop made and
     ``pass2``, the similarity's pass 2 (``"cube"``, ``"sweep"`` or
     ``"scatter"``, :func:`_pass2`).
     """
+    if on_overflow not in ("raise", "widen", "degrade"):
+        raise ValueError(f"on_overflow={on_overflow!r}: expected "
+                         "'raise', 'widen', or 'degrade'")
     with TraceAnnotation("dsc.run") as span:
-        plan = resolve_plan(plan, mode=mode, use_kernel=use_kernel,
-                            use_index=use_index, fused_tiles=fused_tiles,
-                            cluster_engine=cluster_engine,
-                            cluster_use_kernel=cluster_use_kernel,
-                            seg_use_kernel=seg_use_kernel, sim_mode=sim_mode,
-                            sim_topk=sim_topk, sim_panel=sim_panel)
-
-        if on_overflow is not None:
-            if on_overflow not in ("raise", "widen", "degrade"):
-                raise ValueError(f"on_overflow={on_overflow!r}: expected "
-                                 "'raise', 'widen', or 'degrade'")
-            if not sim_topk_retry:
-                raise ValueError("pass either on_overflow or "
-                                 "sim_topk_retry=False, not both")
-            policy = on_overflow
-        else:
-            policy = "widen" if sim_topk_retry else "raise"
-
+        plan = (plan or EnginePlan()).validate()
         S = batch.num_trajs * params.max_subtrajs_per_traj
         k = min(plan.sim_topk if plan.sim_topk is not None else 32, S)
 
@@ -489,15 +425,15 @@ def run_dsc(batch: TrajectoryBatch, params: DSCParams,
         attempts, out = 1, dispatch(k)
         while plan.sim_mode != "dense":
             overflow = int(out.sim_overflow)
-            if overflow == 0 or policy == "degrade":
+            if overflow == 0 or on_overflow == "degrade":
                 break
             if k >= S:                  # unreachable: K == S cannot spill
                 raise AssertionError("overflow with K == S")
-            if policy == "raise":
+            if on_overflow == "raise":
                 raise RuntimeError(
                     f"sim_topk={k} truncated a potential alpha-edge on "
                     f"{overflow} rows (spill >= alpha): labels would not be "
-                    "exact.  Raise sim_topk or enable sim_topk_retry.")
+                    "exact.  Raise sim_topk or use on_overflow='widen'.")
             k = min(2 * k, S)
             attempts, out = attempts + 1, dispatch(k)
         if TraceAnnotation.is_enabled():

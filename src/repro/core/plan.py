@@ -1,46 +1,75 @@
-"""EnginePlan: the single tuned surface for every per-stage engine choice.
+"""EnginePlan: the one configuration surface for every per-stage engine
+and tile choice of the DSC pipeline.
 
-PRs 1-5 grew five per-stage engine switches (join mode, join kernel/index,
-TSA2 kernel, clustering engine/kernel, similarity representation), each with
-its own tile/block geometry, threaded separately through ``run_dsc``,
-``build_dsc_program``, and the launcher CLI.  ``EnginePlan`` collapses that
-surface to one frozen, hashable, JSON-serializable dataclass:
+Every entry point — ``run_dsc``, ``run_dsc_lowerable``,
+``run_dsc_distributed``, ``build_dsc_program``, the resilient runners and
+the launcher's ``--plan PLAN.json`` — takes one frozen, hashable,
+JSON-serializable :class:`EnginePlan` (``None`` = ``EnginePlan()``).  A
+plan rides through ``jax.jit`` as one static argument: one plan == one
+trace.  The autotuner (``repro.tune.autotune``) sweeps candidate plans and
+caches winners per (shape-bucket, backend, jax version); a stored plan
+round-trips through JSON (``save`` / ``load``).  DESIGN.md §9 has the
+design; §§3-8 and §12 introduce each field.
 
-* every entry point accepts ``plan=`` (one object, one jit static key);
-* the legacy flags survive as **deprecated aliases** that materialize a
-  plan (``EnginePlan.from_legacy`` / ``resolve_plan``) — behavior is
-  unchanged, so every pre-plan test and CI gate passes as-is;
-* the autotuner (``repro.tune.autotune``) sweeps candidate plans and
-  caches winners per (shape-bucket, backend, jax version); a stored plan
-  round-trips through JSON (``save`` / ``load``).
+What each field selects, by stage (the oracle of each fork is named; every
+fork is label-identical to its oracle):
 
-Field-to-stage map (DESIGN.md §9; §§3-8 introduce each knob):
+join (Problem 1)
+    ``mode="materialize"`` (the parity oracle) builds the DTJ join cube
+    ``JoinResult [T, M, C]`` in HBM and re-reads it for voting, the TSA2
+    masks and the similarity scatter.  ``mode="fused"`` streams the join
+    through two Pallas sweeps that accumulate those outputs directly; the
+    cube never exists (``DSCOutput.join is None``) and pass 2 re-sweeps
+    after segmentation.  On a mesh, fused mode streams each halo slab and
+    all_gathers the TSA2 neighbor sets as packed words.  ``use_kernel``
+    runs the materialize-mode join and the similarity scatter through the
+    Pallas kernels (the jnp join is the oracle).  ``use_index`` prunes
+    candidate tiles with the spatiotemporal grid (``repro.index.grid``)
+    in every mode; pruning is conservative, so outputs are unchanged.
+    Index planning is host-driven, so it needs concrete inputs (not
+    ``run_dsc_lowerable``).  On a mesh it also exchanges eps-expanded
+    partition bboxes and masks each halo slab down to the points the
+    neighbor can match.  ``fused_rows`` / ``fused_bc`` / ``fused_bm``
+    pin the fused sweeps' tile geometry
+    (``kernels.stjoin.ops.plan_fused_tiles``).
+segmentation (Problem 2)
+    ``seg_use_kernel`` computes the TSA2 Jaccard signal with the fused
+    Pallas kernel (``repro.kernels.jaccard``) instead of the jnp
+    packed-word engine (the oracle) — bit-identical cuts; a no-op under
+    ``tsa1``.
+similarity (SP relation)
+    ``sim_mode="dense"`` (the oracle) builds the ``[S, S]`` SP matrix.
+    ``sim_mode="topk"`` streams ``[S, K]`` neighbor lists in panels of
+    ``sim_panel`` rows (DESIGN.md §8): O(S*K + Sb*S) memory, labels
+    bit-identical to dense whenever the spill certificate holds
+    (``sim_overflow == 0``; ``run_dsc``'s ``on_overflow`` names what
+    happens otherwise).  ``sim_topk`` is K.  On a mesh each model rank
+    builds its ``[S, S/m]`` column block, an all_to_all hands it the
+    transpose-partner rows, and only per-rank top-(K+1) lists are
+    gathered.  ``sim_strategy`` shapes the distributed dense collective:
+    ``"psum"`` all-reduces the ``[S, S]`` matrix, ``"allgather"`` gathers
+    each rank's column block; ``sim_dtype="bf16"`` halves its payload.
+    Both are ignored under ``topk``.
+communication (distributed only, DESIGN.md §12)
+    ``halo_stream="barrier"`` gathers every neighbor slab up front;
+    ``"ring"`` streams slabs around a ``ppermute`` ring and folds each
+    contribution while the next is in flight (fused mode rings only its
+    word/mask gathers).  ``sim_exchange="ring"`` turns the top-K list
+    gather (and the dense ``allgather`` strategy's block gather) into a
+    forwarding ring with a running merge; the ``psum`` strategy ignores
+    it.  Every ring is bit-identical to its barrier twin.
+clustering (Problem 3)
+    ``cluster_engine="rounds"`` is the round-parallel greedy engine;
+    ``"sequential"`` is the O(S) oracle (DESIGN.md §6).
+    ``cluster_use_kernel`` backs the round engine with the Pallas tile
+    kernels (``repro.kernels.cluster``) of tile ``cluster_bu`` x
+    ``cluster_bs``; the jnp formulation is faster on CPU, where the
+    kernels run in interpret mode.
 
-====================  =====================================================
-stage                 plan fields
-====================  =====================================================
-join (Problem 1)      ``mode`` ("materialize" | "fused"), ``use_kernel``,
-                      ``use_index``, fused tile geometry ``fused_rows`` /
-                      ``fused_bc`` / ``fused_bm``
-                      (``kernels.stjoin.ops.plan_fused_tiles``)
-segmentation (P2)     ``seg_use_kernel`` (packed jnp engine vs the fused
-                      Pallas Jaccard kernel — bit-identical cuts)
-similarity (SP)       ``sim_mode`` ("dense" | "topk"), ``sim_topk`` (K),
-                      ``sim_panel`` (Sb panel height); distributed-only:
-                      ``sim_strategy``, ``sim_dtype``, ``sim_exchange``
-                      ("allgather" barrier | "ring" streamed blocks)
-comm (DESIGN.md §12)  ``halo_stream`` ("barrier" gathers every neighbor
-                      slab up front | "ring" streams slabs and folds each
-                      contribution as it lands), ``sim_exchange`` (above)
-clustering (P3)       ``cluster_engine`` ("rounds" | "sequential"),
-                      ``cluster_use_kernel``, round-kernel tiles
-                      ``cluster_bu`` / ``cluster_bs``
-====================  =====================================================
-
-``None`` means "library default, resolved at run time" (e.g.
-``fused_rows=None`` lets ``_fused_geometry`` pick the fat-tile default,
-``sim_topk=None`` resolves to ``min(32, S)``).  Ints are concrete pins —
-what the tuner writes once a sweep has measured a winner.
+``None`` means "library default, resolved at run time" (``fused_rows=None``
+lets ``_fused_geometry`` pick the fat-tile default, ``sim_topk=None``
+resolves to ``min(32, S)``).  Ints are concrete pins — what the tuner
+writes once a sweep has measured a winner.
 """
 from __future__ import annotations
 
@@ -66,8 +95,8 @@ class EnginePlan:
     """
 
     # ---- join (Problem 1) -------------------------------------------------
-    mode: str = "materialize"          # "materialize" | "fused"
-    use_kernel: bool = False           # Pallas join + SP sweep (materialize)
+    mode: str = "materialize"          # "materialize" (oracle) | "fused"
+    use_kernel: bool = False           # Pallas join + SP scatter (materialize)
     use_index: bool = False            # grid candidate-tile pruning
     fused_rows: int | None = None      # fused ref-block rows (None = auto)
     fused_bc: int = 128                # fused candidate rows per block
@@ -75,16 +104,17 @@ class EnginePlan:
     # ---- segmentation (Problem 2) ----------------------------------------
     seg_use_kernel: bool = False       # Pallas TSA2 Jaccard kernel
     # ---- similarity (SP relation) ----------------------------------------
-    sim_mode: str = "dense"            # "dense" | "topk"
+    sim_mode: str = "dense"            # "dense" (oracle) | "topk"
     sim_topk: int | None = None        # K of the top-K lists (None = 32)
     sim_panel: int | None = None       # panel height Sb (None = 128-snap)
-    sim_strategy: str = "psum"         # distributed dense collective shape
-    sim_dtype: str = "f32"             # distributed dense payload dtype
+    sim_strategy: str = "psum"         # mesh dense collective: "psum" |
+                                       # "allgather" (column blocks)
+    sim_dtype: str = "f32"             # mesh dense payload: "f32" | "bf16"
     # ---- communication schedules (distributed-only) -----------------------
     halo_stream: str = "barrier"       # join halo slabs: "barrier" | "ring"
     sim_exchange: str = "allgather"    # similarity lists: "allgather" | "ring"
     # ---- clustering (Problem 3) ------------------------------------------
-    cluster_engine: str = "rounds"     # "rounds" | "sequential"
+    cluster_engine: str = "rounds"     # "rounds" | "sequential" (oracle)
     cluster_use_kernel: bool = False   # Pallas round-scan/claim-max kernels
     cluster_bu: int = 8                # row tile of the cluster kernels
     cluster_bs: int = 128              # column tile of the cluster kernels
@@ -93,9 +123,8 @@ class EnginePlan:
     def validate(self) -> "EnginePlan":
         """Raise ``ValueError`` on any inconsistent field; return ``self``.
 
-        The error messages for the three engine selectors are the exact
-        strings the pre-plan entry points raised, so existing error-path
-        tests keep passing unchanged.
+        A plan loaded from JSON is checked here too, so a malformed file
+        fails with the field's name before anything compiles.
         """
         if self.mode not in _MODES:
             raise ValueError(f"unknown mode {self.mode!r}")
@@ -130,8 +159,7 @@ class EnginePlan:
     def fused_tiles(self) -> tuple[int | None, int, int] | None:
         """``(rows, bc, bm)`` fused-kernel geometry, or ``None`` when every
         fused field still holds the library default — callers then pass no
-        overrides, which keeps jit cache keys (and therefore traces)
-        identical to the pre-plan flag surface."""
+        overrides, so the kernels' own defaults pick the geometry."""
         t = (self.fused_rows, self.fused_bc, self.fused_bm)
         return None if t == (None, 128, 128) else t
 
@@ -174,68 +202,3 @@ class EnginePlan:
     def load(cls, path) -> "EnginePlan":
         with open(path) as f:
             return cls.from_json(f.read())
-
-    # ------------------------------------------------------- legacy aliases
-    @classmethod
-    def from_legacy(cls, *, mode: str = "materialize",
-                    use_kernel: bool = False, use_index: bool = False,
-                    fused_tiles: tuple | None = None,
-                    seg_use_kernel: bool = False,
-                    cluster_engine: str = "rounds",
-                    cluster_use_kernel: bool = False,
-                    sim_mode: str = "dense", sim_topk: int | None = None,
-                    sim_panel: int | None = None,
-                    sim_strategy: str = "psum",
-                    sim_dtype: str = "f32",
-                    halo_stream: str = "barrier",
-                    sim_exchange: str = "allgather") -> "EnginePlan":
-        """Materialize a plan from the deprecated per-stage flag set.
-
-        This is the compatibility contract: every legacy flag combination
-        maps onto exactly one plan, and running that plan is behaviorally
-        identical to the pre-plan entry points (pinned by
-        ``tests/test_plan.py``).
-        """
-        rows, bc, bm = (None, 128, 128) if fused_tiles is None else fused_tiles
-        return cls(mode=mode, use_kernel=use_kernel, use_index=use_index,
-                   fused_rows=rows, fused_bc=bc, fused_bm=bm,
-                   seg_use_kernel=seg_use_kernel,
-                   cluster_engine=cluster_engine,
-                   cluster_use_kernel=cluster_use_kernel,
-                   sim_mode=sim_mode, sim_topk=sim_topk, sim_panel=sim_panel,
-                   sim_strategy=sim_strategy,
-                   sim_dtype=sim_dtype, halo_stream=halo_stream,
-                   sim_exchange=sim_exchange).validate()
-
-
-_LEGACY_DEFAULTS = {
-    "mode": "materialize", "use_kernel": False, "use_index": False,
-    "fused_tiles": None, "seg_use_kernel": False,
-    "cluster_engine": "rounds", "cluster_use_kernel": False,
-    "sim_mode": "dense", "sim_topk": None, "sim_panel": None,
-    "sim_strategy": "psum", "sim_dtype": "f32",
-    "halo_stream": "barrier", "sim_exchange": "allgather",
-}
-
-
-def resolve_plan(plan: EnginePlan | None = None, **legacy) -> EnginePlan:
-    """The one entry-point rule: a plan, or legacy flags — never both.
-
-    ``plan=None`` materializes a plan from the legacy flags (all current
-    callers).  With an explicit plan, any legacy flag still at a
-    non-default value raises: silently preferring one surface over the
-    other would make ``--plan`` + a stray ``--sim-mode`` ambiguous.
-    """
-    unknown = set(legacy) - set(_LEGACY_DEFAULTS)
-    if unknown:
-        raise TypeError(f"unknown legacy plan flags {sorted(unknown)}")
-    if plan is None:
-        return EnginePlan.from_legacy(**legacy)
-    clash = {k: v for k, v in legacy.items()
-             if v != _LEGACY_DEFAULTS[k] and v is not None}
-    if clash:
-        raise ValueError(
-            f"both plan= and legacy per-stage flags were given ({clash}); "
-            "the deprecated flags only exist to materialize a plan — "
-            "set the fields on the plan instead")
-    return plan.validate()

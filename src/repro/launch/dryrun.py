@@ -222,6 +222,7 @@ def run_dsc_cell(name: str, multi_pod: bool, sim_strategy: str = "psum",
     """Dry-run the paper's own pipeline on the production mesh."""
     from repro.core.distributed import run_dsc_distributed
     from repro.core.partitioning import PartitionedBatch
+    from repro.core.plan import EnginePlan
     from repro.core.types import DSCParams
 
     rc = get_dsc_config(name)
@@ -250,8 +251,8 @@ def run_dsc_cell(name: str, multi_pod: bool, sim_strategy: str = "psum",
     lowered = jax.jit(
         functools.partial(dsc_dist.run_dsc_distributed_lowerable,
                           params=params, mesh=mesh,
-                          sim_strategy=sim_strategy,
-                          sim_dtype=sim_dtype)).lower(parts)
+                          plan=EnginePlan(sim_strategy=sim_strategy,
+                                          sim_dtype=sim_dtype))).lower(parts)
     compiled = lowered.compile()
     t1 = time.time()
     text = compiled.as_text()

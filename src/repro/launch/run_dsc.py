@@ -2,12 +2,10 @@
 
 ``python -m repro.launch.run_dsc --config dsc_synth [--distributed P]``
 
-Engine configuration rides on ONE surface: ``--plan plan.json``, an
+Engine configuration rides on one surface: ``--plan PLAN.json``, an
 :class:`repro.core.plan.EnginePlan` (DESIGN.md §9; write one with
 ``EnginePlan(...).save(path)`` or take a tuned one from the autotuner's
-plan store).  The per-stage flags (``--mode``, ``--sim-mode``, ...) are
-**deprecated aliases** that materialize a plan; combining them with
-``--plan`` is an error.
+plan store).  Without it the launcher runs ``EnginePlan()``.
 
 The README's CLI reference table is generated from this parser
 (``--print-cli-table``) and checked for drift in CI (``--check-readme``).
@@ -46,21 +44,6 @@ def make_dataset(name: str, n_trajs: int, max_points: int, seed: int = 0):
                     n_lanes=8, seed=seed)[0]
 
 
-# legacy per-stage flags -> (EnginePlan resolve_plan keyword, plan field)
-_LEGACY_FLAGS = {
-    "use_kernel": "use_kernel",
-    "use_index": "use_index",
-    "mode": "mode",
-    "cluster_engine": "cluster_engine",
-    "cluster_use_kernel": "cluster_use_kernel",
-    "seg_use_kernel": "seg_use_kernel",
-    "sim_mode": "sim_mode",
-    "sim_topk": "sim_topk",
-    "halo_stream": "halo_stream",
-    "sim_exchange": "sim_exchange",
-}
-
-
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.run_dsc",
@@ -74,66 +57,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--model-par", type=int, default=1,
                     help="model-axis width (candidate-column sharding)")
     ap.add_argument("--plan", default=None, metavar="PLAN.json",
-                    help="EnginePlan JSON: the single surface owning every "
+                    help="EnginePlan JSON: the one surface owning every "
                          "per-stage engine and tile choice (DESIGN.md "
-                         "section 9); mutually exclusive with the "
-                         "deprecated per-stage flags below")
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="[deprecated alias: plan.use_kernel] Pallas join "
-                         "kernel in materialize mode")
-    ap.add_argument("--use-index", action="store_true",
-                    help="[deprecated alias: plan.use_index] prune the "
-                         "JOIN phase with the spatiotemporal grid index "
-                         "(lossless; single-host and distributed)")
-    ap.add_argument("--mode", default="materialize",
-                    choices=["materialize", "fused"],
-                    help="[deprecated alias: plan.mode] join execution "
-                         "mode: materialize the JoinResult cube (parity "
-                         "oracle) or stream it through the fused Pallas "
-                         "epilogues (no [T, M, C] buffer)")
-    ap.add_argument("--cluster-engine", default="rounds",
-                    choices=["rounds", "sequential"],
-                    help="[deprecated alias: plan.cluster_engine] Problem "
-                         "3 engine: round-parallel greedy (O(rounds) "
-                         "iterations) or the O(S) sequential oracle — "
-                         "label-identical outputs")
-    ap.add_argument("--cluster-use-kernel", action="store_true",
-                    help="[deprecated alias: plan.cluster_use_kernel] back "
-                         "the round engine with the Pallas tile kernels "
-                         "(accelerator path; interpret mode on CPU)")
-    ap.add_argument("--seg-use-kernel", action="store_true",
-                    help="[deprecated alias: plan.seg_use_kernel] compute "
-                         "the TSA2 Jaccard signal with the fused Pallas "
-                         "segmentation kernel (bit-identical cuts; "
-                         "interpret mode on CPU; no-op under tsa1)")
-    ap.add_argument("--sim-mode", default="dense",
-                    choices=["dense", "topk"],
-                    help="[deprecated alias: plan.sim_mode] SP "
-                         "representation: the dense [S, S] similarity "
-                         "matrix (parity oracle) or panel-streamed top-K "
-                         "neighbor lists — O(S*K) memory, bit-identical "
-                         "labels whenever the overflow certificate is "
-                         "zero (single-host runs auto-widen K; "
-                         "distributed runs fail loudly)")
-    ap.add_argument("--sim-topk", type=int, default=None,
-                    help="[deprecated alias: plan.sim_topk] K of the "
-                         "top-K neighbor lists (default 32, clamped to "
-                         "S); only with --sim-mode topk")
-    ap.add_argument("--halo-stream", default="barrier",
-                    choices=["barrier", "ring"],
-                    help="[deprecated alias: plan.halo_stream] JOIN halo "
-                         "schedule: gather every neighbor slab up front, "
-                         "or stream slabs around a ppermute ring and fold "
-                         "each contribution while the next is in flight "
-                         "(bit-identical labels; distributed only)")
-    ap.add_argument("--sim-exchange", default="allgather",
-                    choices=["allgather", "ring"],
-                    help="[deprecated alias: plan.sim_exchange] similarity "
-                         "exchange schedule: barrier all-gathers, or "
-                         "ring-streamed column blocks with a running "
-                         "top-K merge — constant per-step payload, "
-                         "bit-identical labels (distributed only; the "
-                         "dense psum strategy ignores it)")
+                         "section 9; default: EnginePlan())")
     ap.add_argument("--segmentation", default=None,
                     help="segmentation algorithm override (tsa1 or tsa2)")
     ap.add_argument("--seed", type=int, default=0,
@@ -309,24 +235,9 @@ def check_readme_cli_table(readme_path: str) -> list[str]:
                                      tofile="--print-cli-table", lineterm=""))
 
 
-def plan_from_args(args, parser: argparse.ArgumentParser) -> EnginePlan:
-    """One plan from the CLI: ``--plan`` JSON, or the deprecated aliases.
-
-    Mixing both is a parser error — same rule as
-    :func:`repro.core.plan.resolve_plan`, surfaced with flag spellings.
-    """
-    legacy = {kw: getattr(args, attr)
-              for attr, kw in _LEGACY_FLAGS.items()}
-    if args.plan is None:
-        return EnginePlan.from_legacy(**legacy)
-    defaults = {a.dest: a.default for a in parser._actions}
-    stray = [f"--{attr.replace('_', '-')}" for attr in _LEGACY_FLAGS
-             if getattr(args, attr) != defaults[attr]]
-    if stray:
-        parser.error(f"--plan cannot be combined with the deprecated "
-                     f"per-stage flags ({', '.join(stray)}); set those "
-                     "fields in the plan JSON instead")
-    return EnginePlan.load(args.plan)
+def plan_from_args(args) -> EnginePlan:
+    """The run's plan: the ``--plan`` JSON, else ``EnginePlan()``."""
+    return EnginePlan() if args.plan is None else EnginePlan.load(args.plan)
 
 
 def _exit_code_for(exc) -> int | None:
@@ -388,7 +299,7 @@ def main(argv=None):
                 "regenerate it with --print-cli-table:\n" + "\n".join(diff))
         print(f"{args.check_readme}: CLI table in sync")
         return None
-    plan = plan_from_args(args, ap)
+    plan = plan_from_args(args)
     enable_compile_cache()
     batch, params, mean_dt = dataset_and_params(args)
 
@@ -424,7 +335,7 @@ def main(argv=None):
             mesh = jax.make_mesh((P, args.model_par), ("part", "model"))
             parts = partition_batch(batch, P)
             # the distributed program has no in-graph K widening: keep
-            # the legacy default of 32 explicit in the plan
+            # the default K of 32 explicit in the plan
             if plan.sim_mode == "topk" and plan.sim_topk is None:
                 plan = plan.replace(sim_topk=32)
             if resilient:

@@ -69,7 +69,7 @@ from repro.core import dsc as dsc_mod
 from repro.core.clustering import rmse_from_result, sscr_from_result
 from repro.core.partitioning import (PointLayout, repartition,
                                      repartition_batch)
-from repro.core.plan import EnginePlan, resolve_plan
+from repro.core.plan import EnginePlan
 from repro.core.types import (ClusteringResult, JoinResult,
                               SubtrajSegmentation, SubtrajTable, TopKSim)
 from repro.distributed.straggler import (StragglerMonitor,
@@ -606,15 +606,14 @@ def run_resilient(batch, params, *, plan: EnginePlan | None = None,
                   max_retries: int = 3, sleep=None, clock=None,
                   monitor: StragglerMonitor | None = None,
                   rebalance: RebalancePolicy | None = None,
-                  sync_saves: bool = False,
-                  **legacy) -> ResilientResult:
+                  sync_saves: bool = False) -> ResilientResult:
     """Single-host resilient run; see the module docstring.
 
     ``checkpoint_dir=None`` runs the stage graph without persistence
-    (faults still inject; resume is impossible).  ``**legacy`` accepts
-    the same deprecated per-stage flags as :func:`repro.core.dsc.run_dsc`.
+    (faults still inject; resume is impossible).  ``plan`` is the same
+    :class:`EnginePlan` :func:`repro.core.dsc.run_dsc` takes.
     """
-    plan = resolve_plan(plan, **legacy)
+    plan = (plan or EnginePlan()).validate()
     loop = _SingleHostLoop(batch, params, plan=plan,
                            checkpoint_dir=checkpoint_dir,
                            on_overflow=on_overflow,
@@ -892,8 +891,8 @@ def run_resilient_distributed(parts, params, mesh, *,
                               monitor: StragglerMonitor | None = None,
                               rebalance: RebalancePolicy | None = None,
                               sync_saves: bool = False,
-                              elastic_resume: bool = False,
-                              **legacy) -> ResilientResult:
+                              elastic_resume: bool = False
+                              ) -> ResilientResult:
     """Distributed resilient run over ``mesh``; see the module docstring.
 
     Stage programs come from ``build_dsc_stage_programs`` — the same
@@ -903,7 +902,7 @@ def run_resilient_distributed(parts, params, mesh, *,
     ``on_overflow="widen"`` (which rebuilds and re-runs everything), the
     stage-level widen here restarts from the checkpointed segment state.
     """
-    plan = resolve_plan(plan, **legacy)
+    plan = (plan or EnginePlan()).validate()
     loop = _DistributedLoop(parts, params, mesh, part_axis, model_axis,
                             plan=plan, checkpoint_dir=checkpoint_dir,
                             on_overflow=on_overflow,

@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.clustering import (cluster, cluster_rounds,
                                    cluster_sequential, visit_order)
+from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams, SubtrajTable
 from repro.kernels.cluster.ops import (cluster_assign, cluster_round_scan,
                                        plan_tiles)
@@ -207,18 +208,19 @@ def test_dispatcher_engines():
 
 
 def test_engine_parity_through_pipeline(fig1, fig1_params):
-    """run_dsc with cluster_engine="rounds" (default) equals the
+    """run_dsc with plan.cluster_engine="rounds" (default) equals the
     sequential-engine run end to end, single host."""
     from repro.core.dsc import run_dsc
     batch, _ = fig1
     out_r = run_dsc(batch, fig1_params)
-    out_s = run_dsc(batch, fig1_params, cluster_engine="sequential")
+    out_s = run_dsc(batch, fig1_params,
+                    plan=EnginePlan(cluster_engine="sequential"))
     _assert_identical(out_r.result, out_s.result)
     assert float(out_r.sscr) == float(out_s.sscr)
 
 
 def test_kernel_cluster_through_pipeline():
-    """run_dsc(cluster_use_kernel=True) — the production entry to the
+    """run_dsc with plan.cluster_use_kernel — the production entry to the
     Pallas cluster kernels — matches the jnp engine end to end (small
     instance: interpret mode pays per program instance)."""
     from repro.core.dsc import run_dsc
@@ -228,7 +230,7 @@ def test_kernel_cluster_through_pipeline():
                        alpha_sigma=0.0, k_sigma=0.0,
                        max_subtrajs_per_traj=4)
     out = run_dsc(batch, params)
-    out_k = run_dsc(batch, params, cluster_use_kernel=True)
+    out_k = run_dsc(batch, params, plan=EnginePlan(cluster_use_kernel=True))
     _assert_identical(out.result, out_k.result)
 
 
@@ -244,5 +246,5 @@ def test_engine_parity_distributed_single_device(fig1, fig1_params):
     parts = partition_batch(batch, 1)
     out_r = run_dsc_distributed(parts, fig1_params, mesh)
     out_s = run_dsc_distributed(parts, fig1_params, mesh,
-                                cluster_engine="sequential")
+                                plan=EnginePlan(cluster_engine="sequential"))
     _assert_identical(out_r.result, out_s.result)

@@ -27,6 +27,7 @@ _DRIVER = textwrap.dedent("""
     from repro.core.partitioning import partition_batch
     from repro.core.distributed import run_dsc_distributed
     from repro.core.dsc import run_dsc
+    from repro.core.plan import EnginePlan
 
     batch, labels = figure1_scenario(n_per_route=4, points_per_leg=24, seed=0)
     params = DSCParams(eps_sp=0.42, eps_t=1.0, delta_t=0.0, w=6, tau=0.15,
@@ -71,17 +72,20 @@ _DRIVER = textwrap.dedent("""
     report["p4_state_partition"] = bool((state[seen] == 1).all())
 
     # kernel-backed join agrees
-    out_k = run_dsc_distributed(parts, params, mesh, use_kernel=True)
+    out_k = run_dsc_distributed(parts, params, mesh,
+                                plan=EnginePlan(use_kernel=True))
     report["p4_kernel_agree"] = float(
         (np.asarray(out_k.result.member_of) == member_of).mean())
 
     # spatiotemporal-index-pruned join agrees exactly
-    out_i = run_dsc_distributed(parts, params, mesh, use_index=True)
+    out_i = run_dsc_distributed(parts, params, mesh,
+                                plan=EnginePlan(use_index=True))
     report["p4_index_agree"] = float(
         (np.asarray(out_i.result.member_of) == member_of).mean())
 
     # fused streaming mode: identical clusters with no per-rank join cube
-    out_f = run_dsc_distributed(parts, params, mesh, mode="fused")
+    out_f = run_dsc_distributed(parts, params, mesh,
+                                plan=EnginePlan(mode="fused"))
     report["p4_fused_agree"] = float(
         (np.asarray(out_f.result.member_of) == member_of).mean())
     report["p4_fused_vote_close"] = bool(np.allclose(
@@ -89,7 +93,8 @@ _DRIVER = textwrap.dedent("""
 
     # fused Pallas TSA2 segmentation kernel: bit-identical labels to the
     # jnp packed-word engine (tsa2 is the params' segmentation)
-    out_sk = run_dsc_distributed(parts, params, mesh, seg_use_kernel=True)
+    out_sk = run_dsc_distributed(parts, params, mesh,
+                                 plan=EnginePlan(seg_use_kernel=True))
     report["p4_seg_kernel_agree"] = bool(
         (np.asarray(out_sk.result.member_of) == member_of).all()
         and (np.asarray(out_sk.result.is_rep) == is_rep).all()
@@ -98,7 +103,7 @@ _DRIVER = textwrap.dedent("""
     # sequential clustering oracle: the round-parallel per-partition
     # engine (the default above) must be label-identical
     out_s = run_dsc_distributed(parts, params, mesh,
-                                cluster_engine="sequential")
+                                plan=EnginePlan(cluster_engine="sequential"))
     report["p4_cluster_engine_agree"] = bool(
         (np.asarray(out_s.result.member_of) == member_of).all()
         and (np.asarray(out_s.result.is_rep) == is_rep).all()
@@ -109,8 +114,9 @@ _DRIVER = textwrap.dedent("""
     # global labels whenever the spill certificate is clean, in both
     # execution modes
     for key, kw in (("p4_topk", {}), ("p4_topk_fused", {"mode": "fused"})):
-        out_t = run_dsc_distributed(parts, params, mesh, sim_mode="topk",
-                                    sim_topk=48, **kw)
+        out_t = run_dsc_distributed(
+            parts, params, mesh,
+            plan=EnginePlan(sim_mode="topk", sim_topk=48, **kw))
         report[key + "_overflow"] = int(
             np.asarray(out_t.sim_diag)[:, 3].sum())
         report[key + "_agree"] = bool(
@@ -133,7 +139,8 @@ _DRIVER = textwrap.dedent("""
         ("p4_ring_fused", dict(mode="fused", halo_stream="ring"), out_f),
     )
     for key, kw, twin in ring_cells:
-        out_r = run_dsc_distributed(parts, params, mesh, **kw)
+        out_r = run_dsc_distributed(parts, params, mesh,
+                                    plan=EnginePlan(**kw))
         report[key + "_agree"] = bool(all(
             (np.asarray(getattr(out_r.result, f))
              == np.asarray(getattr(twin.result, f))).all()

@@ -39,6 +39,7 @@ _DRIVER = textwrap.dedent("""
     from repro.data.synthetic import figure1_scenario
     from repro.core.types import DSCParams
     from repro.core.partitioning import partition_batch, repartition_batch
+    from repro.core.plan import EnginePlan
     from repro.run import (FaultPlan, InjectedCrash, RebalancePolicy,
                            read_telemetry, run_resilient_distributed)
     from repro.run.resilient import STAGES
@@ -147,24 +148,23 @@ _DRIVER = textwrap.dedent("""
     # (2, 2) — the ring similarity exchange reruns on the new mesh — and
     # stays bit-identical to the barrier plan's straight-through run;
     # telemetry events carry the active comm schedule
-    ring_kw = dict(sim_mode="topk", sim_topk=48, halo_stream="ring",
-                   sim_exchange="ring")
+    barrier = EnginePlan(sim_mode="topk", sim_topk=48)
+    ring = barrier.replace(halo_stream="ring", sim_exchange="ring")
     mesh42 = jax.make_mesh((4, 2), ("part", "model"))
     mesh22 = jax.make_mesh((2, 2), ("part", "model"))
     oracle_ring = sig(run_resilient_distributed(
-        partition_batch(batch, 2), params, mesh22,
-        sim_mode="topk", sim_topk=48))          # barrier twin
+        partition_batch(batch, 2), params, mesh22, plan=barrier))
     ringroot = f"{tmp}/ring"
     try:
         run_resilient_distributed(
             partition_batch(batch, 4), params, mesh42,
             checkpoint_dir=ringroot,
-            fault_plan=FaultPlan(crash_at="similarity"), **ring_kw)
+            fault_plan=FaultPlan(crash_at="similarity"), plan=ring)
     except InjectedCrash:
         pass
     res_ring = run_resilient_distributed(
         partition_batch(batch, 2), params, mesh22,
-        checkpoint_dir=ringroot, elastic_resume=True, **ring_kw)
+        checkpoint_dir=ringroot, elastic_resume=True, plan=ring)
     report["ring_elastic_agree"] = same(sig(res_ring), oracle_ring)
     report["ring_elastic_from"] = res_ring.resumed_from
     done = [e for e in read_telemetry(ringroot + "/telemetry.jsonl")

@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import geometry, segmentation, similarity, voting
 from repro.core.dsc import run_dsc
+from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams, TrajectoryBatch
 from repro.kernels.stjoin import ops as stjoin_ops
 
@@ -132,7 +133,7 @@ def test_run_dsc_fused_matches_materializing(fig1, fig1_params, kw):
     """Acceptance: identical clustering output, sim allclose, no join cube."""
     batch, _ = fig1
     a = run_dsc(batch, fig1_params)
-    b = run_dsc(batch, fig1_params, **kw)
+    b = run_dsc(batch, fig1_params, plan=EnginePlan(**kw))
     assert b.join is None
     assert (np.asarray(a.result.member_of)
             == np.asarray(b.result.member_of)).all()
@@ -151,7 +152,7 @@ def test_run_dsc_fused_tsa1_delta_t(fig1):
     params = DSCParams(eps_sp=0.42, eps_t=1.0, delta_t=0.3, w=6, tau=0.15,
                        alpha_sigma=-1.0, k_sigma=-1.0, segmentation="tsa1")
     a = run_dsc(batch, params)
-    b = run_dsc(batch, params, mode="fused")
+    b = run_dsc(batch, params, plan=EnginePlan(mode="fused"))
     assert (np.asarray(a.result.member_of)
             == np.asarray(b.result.member_of)).all()
     np.testing.assert_allclose(np.asarray(a.sim), np.asarray(b.sim),
@@ -314,7 +315,6 @@ def test_run_dsc_returns_the_join_counts():
     """``DSCOutput.join_matches`` on the index-pruned kernel join: the
     counts of ``subtrajectory_join``; None at delta_t 0 and on the jnp
     join."""
-    from repro.core.plan import EnginePlan
     rng = np.random.default_rng(5)
     b = _rand_batch(rng, 8, 16)
     params = DSCParams(eps_sp=2.5, eps_t=12.0, delta_t=6.0, w=3, tau=0.2,
@@ -473,7 +473,6 @@ def test_run_dsc_cube_pass2_equals_fused(fig1, segmentation_, delta_t):
     """``run_dsc`` on the kernel join in materialize mode (pass 2 from the
     cube) and in fused mode (pass 2 by the sweep): the same segmentation,
     similarity, labels and scores."""
-    from repro.core.plan import EnginePlan
     batch, _ = fig1
     params = DSCParams(eps_sp=0.42, eps_t=1.0, delta_t=delta_t, w=6,
                        tau=0.15, alpha_sigma=-1.0, k_sigma=-1.0,
@@ -509,7 +508,6 @@ def test_run_dsc_span_names_its_pass2(tmp_path, plan_kw, want):
     import jax
     from jax.profiler import ProfileData
 
-    from repro.core.plan import EnginePlan
     rng = np.random.default_rng(3)
     b = _rand_batch(rng, 6, 16)
     params = DSCParams(eps_sp=2.5, eps_t=12.0, w=3, tau=0.2,

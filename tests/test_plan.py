@@ -1,5 +1,5 @@
-"""EnginePlan surface + autotuner: serialization, legacy-alias
-equivalence, sweep determinism, and bit-identity rejection."""
+"""EnginePlan surface + autotuner: serialization, the one-surface rule
+at every entry point, sweep determinism, and bit-identity rejection."""
 import json
 import os
 import sys
@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.plan import EnginePlan, resolve_plan
+from repro.core.plan import EnginePlan
 from repro.tune.autotune import (PlanStore, plan_cache_key, shape_bucket,
                                  sweep, tune_cluster_tiles, tune_join)
 
@@ -64,30 +64,30 @@ def test_fused_tiles_collapse_to_none_at_defaults():
     assert EnginePlan().cluster_tiles == (8, 128)
 
 
-def test_resolve_plan_legacy_and_conflicts():
-    legacy = resolve_plan(None, mode="fused", sim_mode="topk", sim_topk=16)
-    assert legacy == EnginePlan(mode="fused", sim_mode="topk", sim_topk=16)
-    with pytest.raises(ValueError, match="both plan= and legacy"):
-        resolve_plan(EnginePlan(), mode="fused")
-    with pytest.raises(TypeError, match="unknown legacy plan flags"):
-        resolve_plan(None, warp_speed=9)
-    # a plan plus all-default flags is fine (how run_dsc forwards kwargs)
-    assert resolve_plan(EnginePlan(mode="fused"),
-                        mode="materialize") == EnginePlan(mode="fused")
+def _entry_point_call(entry, batch, params):
+    """``(fn, args)`` for one public entry point, on a 1x1 CPU mesh where
+    it needs one."""
+    from repro.core import distributed, dsc
+    from repro.core.partitioning import partition_batch
+    from repro.run import resilient
+    if entry in ("run_dsc", "run_resilient"):
+        mod = dsc if entry == "run_dsc" else resilient
+        return getattr(mod, entry), (batch, params)
+    mesh = jax.make_mesh((1, 1), ("part", "model"))
+    mod = (resilient if entry == "run_resilient_distributed"
+           else distributed)
+    return getattr(mod, entry), (partition_batch(batch, 1), params, mesh)
 
 
-def test_legacy_flags_and_plan_produce_identical_labels(fig1, fig1_params):
-    from repro.core.dsc import run_dsc
-    fig1, _ = fig1
-    out_legacy = run_dsc(fig1, fig1_params, mode="fused",
-                         fused_tiles=(2, 8, 16))
-    out_plan = run_dsc(fig1, fig1_params,
-                       plan=EnginePlan(mode="fused", fused_rows=2,
-                                       fused_bc=8, fused_bm=16))
-    for f in ("member_of", "is_rep", "is_outlier"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(out_legacy.result, f)),
-            np.asarray(getattr(out_plan.result, f)))
+@pytest.mark.parametrize("entry", [
+    "run_dsc", "build_dsc_program", "run_dsc_distributed",
+    "run_resilient", "run_resilient_distributed"])
+def test_entry_points_reject_per_stage_keywords(entry, fig1, fig1_params):
+    # the plan is the only engine surface: a per-stage keyword fails the
+    # call's binding, before anything is traced or compiled
+    fn, args = _entry_point_call(entry, fig1[0], fig1_params)
+    with pytest.raises(TypeError, match="unexpected keyword.*'mode'"):
+        fn(*args, mode="fused")
 
 
 # ----------------------------------------------------- cache keys + store
@@ -244,12 +244,17 @@ def test_readme_cli_table_in_sync():
 
 
 def test_launcher_rejects_plan_plus_legacy_flag(tmp_path):
+    # --plan JSON is the launcher's one engine surface: it resolves to the
+    # saved plan, no flag means EnginePlan(), and the removed per-stage
+    # flags are refused by the parser
     from repro.launch.run_dsc import build_parser, plan_from_args
     p = tmp_path / "plan.json"
-    EnginePlan(mode="fused").save(p)
+    EnginePlan(mode="fused", sim_mode="topk", sim_topk=16).save(p)
     ap = build_parser()
-    args = ap.parse_args(["--plan", str(p)])
-    assert plan_from_args(args, ap) == EnginePlan(mode="fused")
-    args = ap.parse_args(["--plan", str(p), "--sim-mode", "topk"])
+    assert plan_from_args(ap.parse_args(["--plan", str(p)])) == EnginePlan(
+        mode="fused", sim_mode="topk", sim_topk=16)
+    assert plan_from_args(ap.parse_args([])) == EnginePlan()
     with pytest.raises(SystemExit):
-        plan_from_args(args, ap)
+        ap.parse_args(["--plan", str(p), "--sim-mode", "topk"])
+    with pytest.raises(SystemExit):
+        ap.parse_args(["--sim-mode", "topk"])

@@ -17,6 +17,7 @@ import pytest
 from repro.checkpoint import (CheckpointManager, load_checkpoint,
                               load_checkpoint_flat, save_checkpoint)
 from repro.core.dsc import run_dsc
+from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams
 from repro.data.synthetic import figure1_scenario
 from repro.distributed.straggler import (StragglerMonitor,
@@ -29,6 +30,9 @@ from repro.run.resilient import EXIT_CODES, STAGES, OverflowViolation
 pytestmark = pytest.mark.faults
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+
+# a K small enough to overflow the certificate on the scenario
+TOPK2 = EnginePlan(sim_mode="topk", sim_topk=2)
 
 
 @pytest.fixture(scope="module")
@@ -100,8 +104,7 @@ def test_resume_bit_identity_after_crash(scenario, reference, tmp_path,
 
 def test_overflow_widen_recovers_dense_labels(scenario, reference):
     batch, params = scenario
-    res = run_resilient(batch, params, sim_mode="topk", sim_topk=2,
-                        on_overflow="widen")
+    res = run_resilient(batch, params, plan=TOPK2, on_overflow="widen")
     assert res.widen_count >= 1
     r, q = res.output.result, reference.result
     assert (np.asarray(r.member_of) == np.asarray(q.member_of)).all()
@@ -111,8 +114,7 @@ def test_overflow_widen_recovers_dense_labels(scenario, reference):
 
 def test_overflow_degrade_records_certificate(scenario):
     batch, params = scenario
-    res = run_resilient(batch, params, sim_mode="topk", sim_topk=2,
-                        on_overflow="degrade")
+    res = run_resilient(batch, params, plan=TOPK2, on_overflow="degrade")
     assert res.widen_count == 0
     assert int(res.output.sim_overflow) > 0
     assert any(e["event"] == "overflow_degraded" for e in res.events)
@@ -121,8 +123,7 @@ def test_overflow_degrade_records_certificate(scenario):
 def test_overflow_raise(scenario):
     batch, params = scenario
     with pytest.raises(OverflowViolation, match="sim_topk"):
-        run_resilient(batch, params, sim_mode="topk", sim_topk=2,
-                      on_overflow="raise")
+        run_resilient(batch, params, plan=TOPK2, on_overflow="raise")
 
 
 def test_overflow_widen_applies_to_restored_state(scenario, reference,
@@ -133,12 +134,11 @@ def test_overflow_widen_applies_to_restored_state(scenario, reference,
     not only to freshly-computed cluster output."""
     batch, params = scenario
     root = tmp_path / "ckpt"
-    res0 = run_resilient(batch, params, checkpoint_dir=root,
-                         sim_mode="topk", sim_topk=2,
+    res0 = run_resilient(batch, params, checkpoint_dir=root, plan=TOPK2,
                          on_overflow="degrade")
     assert int(res0.output.sim_overflow) > 0
-    res = run_resilient(batch, params, checkpoint_dir=root,
-                        sim_mode="topk", sim_topk=2, on_overflow="widen")
+    res = run_resilient(batch, params, checkpoint_dir=root, plan=TOPK2,
+                        on_overflow="widen")
     assert res.widen_count >= 1
     r, q = res.output.result, reference.result
     assert (np.asarray(r.member_of) == np.asarray(q.member_of)).all()
@@ -399,6 +399,8 @@ def launcher_codes(tmp_path_factory):
     FaultPlan(transient_at="segment", transient_count=9).save(transient)
     corrupt = tmp / "corrupt.json"
     FaultPlan(corrupt_stage="segment", crash_at="similarity").save(corrupt)
+    topk2 = tmp / "topk2.json"
+    TOPK2.save(topk2)
 
     codes = {}
     codes["crash"] = run(["--resume-dir", str(tmp / "c1"),
@@ -411,9 +413,9 @@ def launcher_codes(tmp_path_factory):
     codes["resume_fail"] = run(["--resume-dir", str(tmp / "c2"),
                                 "--on-corruption", "fail"])
     codes["resume_fallback"] = run(["--resume-dir", str(tmp / "c2")])
-    codes["overflow_raise"] = run(["--sim-mode", "topk", "--sim-topk", "2",
+    codes["overflow_raise"] = run(["--plan", str(topk2),
                                    "--on-overflow", "raise"])
-    codes["overflow_widen"] = run(["--sim-mode", "topk", "--sim-topk", "2",
+    codes["overflow_widen"] = run(["--plan", str(topk2),
                                    "--on-overflow", "widen"])
     return codes
 

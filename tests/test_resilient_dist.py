@@ -26,6 +26,7 @@ _DRIVER = textwrap.dedent("""
     from repro.core.types import DSCParams
     from repro.core.partitioning import partition_batch
     from repro.core.distributed import run_dsc_distributed
+    from repro.core.plan import EnginePlan
     from repro.run import FaultPlan, InjectedCrash, run_resilient_distributed
     from repro.run.resilient import STAGES
 
@@ -71,23 +72,24 @@ _DRIVER = textwrap.dedent("""
         report[f"resume_{stage}_agree"] = agrees(r2.output)
 
     # stage-level widen from a spilling K recovers the dense labels
-    rw = run_resilient_distributed(parts, params, mesh, sim_mode="topk",
-                                   sim_topk=4, on_overflow="widen")
+    topk4 = EnginePlan(sim_mode="topk", sim_topk=4)
+    rw = run_resilient_distributed(parts, params, mesh, plan=topk4,
+                                   on_overflow="widen")
     report["widen_count"] = rw.widen_count
     report["widen_agree"] = agrees(rw.output)
     report["widen_overflow"] = int(
         np.asarray(rw.output.sim_diag)[:, 3].sum())
 
     # degrade completes and records the nonzero certificate
-    rd = run_resilient_distributed(parts, params, mesh, sim_mode="topk",
-                                   sim_topk=4, on_overflow="degrade")
+    rd = run_resilient_distributed(parts, params, mesh, plan=topk4,
+                                   on_overflow="degrade")
     report["degrade_overflow"] = int(
         np.asarray(rd.output.sim_diag)[:, 3].sum())
 
     # the monolithic driver's on_overflow="widen" completes too
     # (acceptance criterion: no raise, clean certificate, same labels)
-    om = run_dsc_distributed(parts, params, mesh, sim_mode="topk",
-                             sim_topk=4, on_overflow="widen")
+    om = run_dsc_distributed(parts, params, mesh, plan=topk4,
+                             on_overflow="widen")
     report["monolith_widen_agree"] = agrees(om)
     report["monolith_widen_overflow"] = int(
         np.asarray(om.sim_diag)[:, 3].sum())

@@ -170,12 +170,14 @@ def test_tsa2_kernel_matches_jnp_engine(seed):
 
 @pytest.mark.parametrize("mode", ["materialize", "fused"])
 def test_tsa2_end_to_end_seg_kernel_parity(fig1, fig1_params, mode):
-    """run_dsc with seg_use_kernel=True: bit-identical TSA2 cut masks,
+    """run_dsc with plan.seg_use_kernel: bit-identical TSA2 cut masks,
     segmentations, and downstream cluster labels in both join modes."""
     from repro.core.dsc import run_dsc
+    from repro.core.plan import EnginePlan
     batch, _ = fig1
-    a = run_dsc(batch, fig1_params, mode=mode)
-    b = run_dsc(batch, fig1_params, mode=mode, seg_use_kernel=True)
+    a = run_dsc(batch, fig1_params, plan=EnginePlan(mode=mode))
+    b = run_dsc(batch, fig1_params,
+                plan=EnginePlan(mode=mode, seg_use_kernel=True))
     for f in ("cut", "sub_local", "num_subs"):
         assert np.array_equal(np.asarray(getattr(a.seg, f)),
                               np.asarray(getattr(b.seg, f))), (mode, f)

@@ -205,8 +205,10 @@ def drive(cfg, batches, **svc_kw):
 def assert_matches_batch_oracle(drv):
     """Standing lists, spill and labels == run_dsc over the same window."""
     from repro.core.dsc import run_dsc
-    out = run_dsc(drv.window_batch(), drv.config.params, sim_mode="topk",
-                  sim_topk=drv.config.k, on_overflow="degrade")
+    from repro.core.plan import EnginePlan
+    out = run_dsc(drv.window_batch(), drv.config.params,
+                  plan=EnginePlan(sim_mode="topk", sim_topk=drv.config.k),
+                  on_overflow="degrade")
     K = drv.config.k
     np.testing.assert_array_equal(np.asarray(out.sim_topk.ids),
                                   drv.standing_ids[:, :K])

@@ -10,6 +10,7 @@ import pytest
 
 from repro.core.dsc import cluster_summary, run_dsc
 from repro.core.evaluation import cluster_purity, leg_labels, pairwise_f1
+from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams
 from repro.data.synthetic import figure1_scenario, route_origins_dests
 
@@ -113,8 +114,8 @@ def test_figure1_outlier_variant():
 def test_kernel_path_matches_reference(fig1, fig1_params):
     """The Pallas stjoin-backed pipeline reproduces the reference output."""
     batch, _ = fig1
-    a = run_dsc(batch, fig1_params, use_kernel=False)
-    b = run_dsc(batch, fig1_params, use_kernel=True)
+    a = run_dsc(batch, fig1_params, plan=EnginePlan(use_kernel=False))
+    b = run_dsc(batch, fig1_params, plan=EnginePlan(use_kernel=True))
     np.testing.assert_allclose(np.asarray(a.vote), np.asarray(b.vote),
                                atol=1e-4)
     assert (np.asarray(a.result.member_of) ==
@@ -132,10 +133,11 @@ def test_kernel_index_plan_runs_recorded_pruned_programs(fig1, fig1_params,
     batch, _ = fig1
     params = fig1_params.replace(delta_t=delta_t)
     with record_programs() as programs:
-        got = run_dsc(batch, params, use_kernel=True, use_index=True)
+        got = run_dsc(batch, params,
+                      plan=EnginePlan(use_kernel=True, use_index=True))
     assert [name for name, _ in programs] == ["_join_sweep",
                                               "_run_dsc_from_join"]
-    want = run_dsc(batch, params, use_kernel=True)
+    want = run_dsc(batch, params, plan=EnginePlan(use_kernel=True))
     np.testing.assert_array_equal(np.asarray(got.sim), np.asarray(want.sim))
     for f in ("member_of", "is_rep", "is_outlier"):
         np.testing.assert_array_equal(np.asarray(getattr(got.result, f)),

@@ -18,6 +18,7 @@ from repro.core.clustering import (cluster, cluster_rounds_topk,
                                    resolve_thresholds_from_moments)
 from repro.core.similarity import (similarity_topk, topk_from_dense,
                                    topk_overflow)
+from repro.core.plan import EnginePlan
 from repro.core.types import DSCParams, SubtrajTable
 
 FIELDS = ("member_of", "member_sim", "is_rep", "is_outlier")
@@ -293,7 +294,8 @@ def test_run_dsc_topk_bit_identical(fig1, fig1_params):
                dict(mode="fused", use_index=True),
                dict(cluster_engine="sequential"),
                dict(cluster_use_kernel=True)):
-        out = run_dsc(batch, fig1_params, sim_mode="topk", **kw)
+        out = run_dsc(batch, fig1_params,
+                      plan=EnginePlan(sim_mode="topk", **kw))
         assert out.sim is None and out.sim_topk is not None
         assert int(out.sim_overflow) == 0
         _assert_identical(ref.result, out.result, str(kw))
@@ -303,14 +305,16 @@ def test_run_dsc_topk_bit_identical(fig1, fig1_params):
 
 def test_run_dsc_topk_auto_widens_or_raises(fig1, fig1_params):
     """An undersized K either auto-widens to the certified fixed point
-    (default) or raises loudly when retries are disabled."""
+    (default) or raises loudly under on_overflow="raise"."""
     from repro.core.dsc import run_dsc
     batch, _ = fig1
     ref = run_dsc(batch, fig1_params)
-    out = run_dsc(batch, fig1_params, sim_mode="topk", sim_topk=2)
+    out = run_dsc(batch, fig1_params,
+                  plan=EnginePlan(sim_mode="topk", sim_topk=2))
     assert int(out.sim_overflow) == 0
     assert out.sim_topk.k > 2                        # widened
     _assert_identical(ref.result, out.result)
     with pytest.raises(RuntimeError, match="sim_topk"):
-        run_dsc(batch, fig1_params, sim_mode="topk", sim_topk=2,
-                sim_topk_retry=False)
+        run_dsc(batch, fig1_params,
+                plan=EnginePlan(sim_mode="topk", sim_topk=2),
+                on_overflow="raise")
